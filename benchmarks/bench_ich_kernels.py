@@ -15,6 +15,7 @@ simulator layer — the same `Schedule` object drives both. Run standalone:
 """
 import numpy as np
 
+from repro import compile_cache
 from repro.core import workloads as WL
 from repro.sched import ExplicitCosts, LoopScheduler
 from repro.sched.api import Schedule
@@ -74,4 +75,5 @@ def main(n: int = 20_000) -> float:
 
 
 if __name__ == "__main__":
+    compile_cache.configure()
     main()

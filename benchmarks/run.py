@@ -24,6 +24,8 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
+
 from . import bench_paper as B
 from . import common as C
 
@@ -110,4 +112,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.configure()
     main()

@@ -60,7 +60,7 @@ def fetch_double_buffered(streams, blkid_ref, w, j, *, B: int) -> list:
     """Return grid step (w, j)'s payload blocks, next step's DMA in flight.
 
     `streams` is a list of `(hbm_ref, buf_ref, sem_ref)` triples: the
-    whole payload left in `pltpu.ANY` memory space, its ``(2, B, ...)``
+    whole payload left in `pl.ANY` memory space, its ``(2, B, ...)``
     VMEM scratch, and its ``(2,)`` DMA semaphore (`double_buffer_scratch`).
     `blkid_ref` is the prefetched ``(p * S_B,)`` block-id stream; padding
     steps carry a clamped id (block 0) exactly as the single-buffered

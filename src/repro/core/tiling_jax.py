@@ -17,7 +17,7 @@ arithmetic: LPT partitioning compares f64 partial sums, so a one-ulp
 difference in `tile_cost` can flip a worker assignment. Two rules keep
 it exact:
 
-* all cost arithmetic runs in float64 (`jax.experimental.enable_x64`
+* all cost arithmetic runs in float64 (`jax.enable_x64`
   scopes the flip to this module's traces — nothing else in the repo
   sees x64);
 * reductions replicate numpy's exact association order:
@@ -55,7 +55,6 @@ from .tiling import TileSchedule, WorkerShards, _check_width, ich_tile_width
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
 
 def _i32(x):
@@ -212,7 +211,7 @@ def ich_tile_width_jax(sizes: jax.Array, eps: float = ICH_EPS,
                        min_w: int = 8, max_w: int = 512) -> jax.Array:
     """Traceable twin of `ich_tile_width` (device scalar; the pipeline
     itself resolves W host-side because tile shapes must be static)."""
-    with enable_x64():
+    with jax.enable_x64():
         sizes = jnp.asarray(sizes)
         mu = (jnp.mean(sizes.astype(jnp.float64)) if sizes.size
               else jnp.float64(0.0))
@@ -430,7 +429,7 @@ def split_items_jax(sizes: np.ndarray,
     if plan.n_items == 0:
         z = jnp.zeros(0, jnp.int32)
         return z, z, z
-    with enable_x64():
+    with jax.enable_x64():
         item, start, length = _jit_build(plan.width, plan.total_segs,
                                          plan.n_tiles, 1)(jnp.asarray(sizes))
     t = plan.total_segs
@@ -447,7 +446,7 @@ def build_schedule_jax(sizes: np.ndarray, *, rows_per_tile: int = 8,
     if plan.n_items == 0:
         z = jnp.zeros((0, R), jnp.int32)
         return DeviceSchedule(z, z, z, plan.width, 0)
-    with enable_x64():
+    with jax.enable_x64():
         item_id, seg_start, seg_len = _jit_build(
             plan.width, plan.total_segs, plan.n_tiles, R)(jnp.asarray(sizes))
     return DeviceSchedule(item_id, seg_start, seg_len, plan.width,
@@ -461,11 +460,11 @@ def pack_csr_jax(indptr, indices, data, schedule, *,
         raise ValueError(f"pad_tiles_to must be positive, got {pad_tiles_to}")
     T, R, W = schedule.n_tiles, schedule.rows_per_tile, schedule.width
     T_pad = -(-T // int(pad_tiles_to)) * int(pad_tiles_to)
-    data = jnp.asarray(data)
-    if data.shape[0] == 0:  # no payload: every slot is padding
-        return (jnp.zeros((T_pad, R, W), data.dtype),
-                jnp.zeros((T_pad, R, W), jnp.int32))
-    with enable_x64():
+    with jax.enable_x64():  # keeps a float64 payload float64
+        data = jnp.asarray(data)
+        if data.shape[0] == 0:  # no payload: every slot is padding
+            return (jnp.zeros((T_pad, R, W), data.dtype),
+                    jnp.zeros((T_pad, R, W), jnp.int32))
         return _jit_pack(W, int(pad_tiles_to))(
             jnp.asarray(np.asarray(indptr)), jnp.asarray(np.asarray(indices)),
             data, jnp.asarray(schedule.item_id),
@@ -486,7 +485,7 @@ def partition_tiles_jax(tile_cost, item_id, p: int,
         return jnp.zeros(0, jnp.int32)
     if p == 1:
         return jnp.zeros(T, jnp.int32)
-    with enable_x64():
+    with jax.enable_x64():
         return _jit_partition(p, blk)(
             jnp.asarray(np.asarray(tile_cost, np.float64)),
             jnp.asarray(item_id))
@@ -518,7 +517,7 @@ def lower_schedule_jax(sizes: np.ndarray, costs: np.ndarray, *, p: int,
         z2 = jnp.zeros((0, R), jnp.int32)
         dev = DeviceSchedule(z2, z2, z2, plan.width, 0)
         S_B = max(int(n_steps or 0), 1)
-        with enable_x64():
+        with jax.enable_x64():
             empty_cost = jnp.zeros(0, jnp.float64)
         return DeviceLowering(
             schedule=dev, tile_cost=empty_cost,
@@ -527,7 +526,7 @@ def lower_schedule_jax(sizes: np.ndarray, costs: np.ndarray, *, p: int,
             rowid=jnp.full((p * S_B * B, R), -1, jnp.int32),
             blkid=jnp.zeros(p * S_B, jnp.int32),
             slot_cost=jnp.zeros((0, R), jnp.float32), superstep=B)
-    with enable_x64():
+    with jax.enable_x64():
         (item_id, seg_start, seg_len, slot_cost, tile_cost, worker,
          counts) = _jit_construct(plan.width, plan.total_segs, plan.n_tiles,
                                   R, p, B)(

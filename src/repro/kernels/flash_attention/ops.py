@@ -5,6 +5,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from .flash_attention import flash_attention
 
 
@@ -12,8 +13,7 @@ from .flash_attention import flash_attention
                                              "interpret"))
 def flash_attention_op(q, k, v, *, causal: bool = True, q_block: int = 256,
                        kv_block: int = 256, interpret: bool | None = None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = default_interpret(interpret)
     B, Sq, Hq, dh = q.shape
     Skv = k.shape[1]
     qb = min(q_block, max(8, Sq))

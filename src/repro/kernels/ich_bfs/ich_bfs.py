@@ -11,62 +11,32 @@ split across W-wide segments, segments greedily packed into (T, R) slots.
 `mask` is the all-ones CSR payload from `pack_csr` — 1.0 on real edge slots,
 0.0 on padding — so a padded slot can never observe frontier[cols==0].
 
-Two kernel realizations share the body (see ich_spmv for the pattern):
+Two grids run the same segmented reduction (`core/segmented.py`; see
+ich_spmv for the pattern):
 
-* `ich_bfs_step` — sequential reference grid (T,): each step gathers
-  frontier[cols] (R, W), reduces with max over W, and max-accumulates into
-  the per-vertex output (split rows OR together across tiles), masked by
-  `visited`; grid steps run in order on one core, so the RMW is safe.
+* `ich_bfs_step` — sequential reference grid (T,): each step takes the max
+  of its slots' frontier indicators over W and max-folds them into the
+  per-vertex output (split rows OR together across tiles).
 * `ich_bfs_step_sharded` — worker-sharded 2D grid (p, S_B) (DESIGN.md
   §2.6): tiles are cost-partitioned across p workers at superstep-block
   granularity (item-closed — no vertex spans workers), each grid step
-  fetches a superstep of B tiles as one aligned (B, R, W) block straight
-  from the FLAT payload via a prefetched data-dependent block index
-  (no payload reorder) — DOUBLE-BUFFERED through 2-slot VMEM scratch so
-  step j+1's blocks stream in while step j computes (core/pipelining.py)
-  — every worker max-accumulates into its own row of
-  a (p, n) block, and a pairwise tree max (`core.segmented.worker_reduce`)
-  folds the accumulators — bit-identical to the sequential grid: each
-  vertex is owned by one worker and all others contribute exact zeros
-  (the max identity for the 0/1 frontier indicators).
+  fetches a superstep of B tiles as one block straight from the FLAT
+  payload via a prefetched data-dependent block index, DOUBLE-BUFFERED
+  (core/pipelining.py), every worker max-accumulates into its own
+  lane-dense accumulator, and a pairwise tree max
+  (`core.segmented.worker_reduce`) folds the accumulators — bit-identical
+  to the sequential grid: each vertex is owned by one worker and all
+  others contribute exact zeros (the max identity for the 0/1 frontier
+  indicators).
 
-The max-accumulation routes through the shared segmented-reduction layer
-(`core/segmented.py`): one windowed read-modify-write per tile instead of R
-scalar ones.
+The gathers the TPU compiler cannot lower run in XLA around the kernel:
+mask * frontier[cols] is formed before it and streamed as the payload,
+and the visited mask is applied to the folded hits after it (exact: the
+indicators are 0/1 and a vertex's mask is the same for all its slots).
 """
 from __future__ import annotations
 
-import functools
-
-import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-from repro.core.pipelining import (double_buffer_scratch,
-                                   fetch_double_buffered)
-from repro.core.segmented import (emit_step_cost, segmented_apply,
-                                  segmented_apply_batch, worker_reduce)
-
-
-def _bfs_kernel(rowid_ref, mask_ref, cols_ref, frontier_ref, visited_ref,
-                out_ref, *, n_vertices: int):
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    mask = mask_ref[0]      # (R, W) 1.0 on real edge slots
-    cols = cols_ref[0]      # (R, W) in-neighbor ids
-    frontier = frontier_ref[...]  # (n,) 1.0 = on current frontier
-    visited = visited_ref[...]    # (n,) 1.0 = already visited
-    hit = jnp.max(mask * frontier[cols], axis=1)  # (R,) any frontier nbr?
-    rows = rowid_ref[t]     # (R,) SMEM scalars: vertex per slot, -1 pad
-    inc = hit * (1.0 - visited[jnp.clip(rows, 0, n_vertices - 1)])
-    # split adjacency lists OR together across tiles: max-accumulate through
-    # the shared segmented epilogue (padding slots masked by its one-hot)
-    segmented_apply(out_ref, rows, inc, combine="max")
+from repro.core.segmented import segmented_reduce, segmented_reduce_sharded
 
 
 def ich_bfs_step(mask, cols, rowid, frontier, visited, n_vertices: int,
@@ -74,74 +44,9 @@ def ich_bfs_step(mask, cols, rowid, frontier, visited, n_vertices: int,
     """One frontier expansion on the sequential reference grid. mask/cols
     (T,R,W); rowid (T,R); frontier and visited (n,) float32 indicators.
     Returns the next frontier (n,)."""
-    T, R, W = mask.shape
-    kernel = functools.partial(_bfs_kernel, n_vertices=n_vertices)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # rowid prefetched to SMEM (the schedule)
-        grid=(T,),
-        in_specs=[
-            pl.BlockSpec((1, R, W), lambda t, rowid: (t, 0, 0)),
-            pl.BlockSpec((1, R, W), lambda t, rowid: (t, 0, 0)),
-            pl.BlockSpec(frontier.shape, lambda t, rowid: (0,)),
-            pl.BlockSpec(visited.shape, lambda t, rowid: (0,)),
-        ],
-        out_specs=pl.BlockSpec((n_vertices,), lambda t, rowid: (0,)),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_vertices,), frontier.dtype),
-        interpret=interpret,
-    )(rowid, mask, cols, frontier, visited)
-
-
-def _bfs_sharded_body(rowid_ref, blkid_ref, mask_hbm, cols_hbm, slotc_hbm,
-                      frontier_ref, visited_ref, out_ref, cost_ref, bufs,
-                      sems, *, n_vertices: int, S: int, B: int):
-    w, j = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-        if cost_ref is not None:
-            cost_ref[...] = jnp.zeros_like(cost_ref)
-
-    # double-buffered data-dependent fetch (core/pipelining.py): same
-    # block bytes in the same order, so bit-identity to the sequential
-    # grid is preserved
-    hbm = (mask_hbm, cols_hbm) if slotc_hbm is None \
-        else (mask_hbm, cols_hbm, slotc_hbm)
-    blocks = fetch_double_buffered(list(zip(hbm, bufs, sems)),
-                                   blkid_ref, w, j, B=B)
-    mask = blocks[0]  # (B, R, W): one superstep of this worker's shard
-    cols = blocks[1]
-    frontier = frontier_ref[...]
-    visited = visited_ref[...]
-    hit = jnp.max(mask * frontier[cols], axis=2)  # (B, R)
-    rows = rowid_ref[pl.ds(w * S + j * B, B)]  # (B, R) SMEM scalars
-    inc = hit * (1.0 - visited[jnp.clip(rows, 0, n_vertices - 1)])
-    segmented_apply_batch(out_ref, rows, inc, combine="max")
-    if cost_ref is not None:
-        emit_step_cost(cost_ref, rows, blocks[2], j)
-
-
-def _bfs_kernel_sharded(rowid_ref, blkid_ref, mask_hbm, cols_hbm,
-                        frontier_ref, visited_ref, out_ref, mbuf, cbuf,
-                        msem, csem, *, n_vertices: int, S: int, B: int):
-    _bfs_sharded_body(rowid_ref, blkid_ref, mask_hbm, cols_hbm, None,
-                      frontier_ref, visited_ref, out_ref, None,
-                      (mbuf, cbuf), (msem, csem),
-                      n_vertices=n_vertices, S=S, B=B)
-
-
-def _bfs_kernel_sharded_cost(rowid_ref, blkid_ref, mask_hbm, cols_hbm,
-                             slotc_hbm, frontier_ref, visited_ref, out_ref,
-                             cost_ref, mbuf, cbuf, sbuf, msem, csem, ssem,
-                             *, n_vertices: int, S: int, B: int):
-    _bfs_sharded_body(rowid_ref, blkid_ref, mask_hbm, cols_hbm, slotc_hbm,
-                      frontier_ref, visited_ref, out_ref, cost_ref,
-                      (mbuf, cbuf, sbuf), (msem, csem, ssem),
-                      n_vertices=n_vertices, S=S, B=B)
+    hit = segmented_reduce(mask * frontier[cols], rowid, n_vertices,
+                           combine="max", interpret=interpret)
+    return hit * (1.0 - visited)
 
 
 def ich_bfs_step_sharded(mask, cols, rowid, blkid, frontier, visited,
@@ -157,61 +62,9 @@ def ich_bfs_step_sharded(mask, cols, rowid, blkid, frontier, visited,
     additionally emits the per-worker, per-superstep cost output and
     returns (next_frontier, costs) — the measured-cost feedback stream
     (DESIGN.md §2.7)."""
-    T_pad, R, W = mask.shape
-    p, B = int(p), int(superstep)
-    n_steps = int(blkid.shape[0]) // p
-    S = n_steps * B
-    if blkid.shape[0] != p * n_steps or rowid.shape[0] != p * S or T_pad % B:
-        raise ValueError(f"shard layout mismatch: blkid {blkid.shape}, "
-                         f"rowid {rowid.shape}, T_pad={T_pad}, p={p}, B={B}")
-    emit = slot_cost is not None
-    # payloads stay whole in ANY memory; the kernel double-buffers the
-    # data-dependent superstep blocks through 2-slot VMEM scratch
-    # (core/pipelining.py)
-    in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),  # mask (T_pad, R, W)
-        pl.BlockSpec(memory_space=pltpu.ANY),  # cols (T_pad, R, W)
-    ]
-    db_streams = [((R, W), mask.dtype), ((R, W), jnp.int32)]
-    out_specs = pl.BlockSpec((1, n_vertices),
-                             lambda w, j, rowid, blk: (w, 0))
-    out_shape = jax.ShapeDtypeStruct((p, n_vertices), frontier.dtype)
-    if emit:
-        kernel = functools.partial(_bfs_kernel_sharded_cost,
-                                   n_vertices=n_vertices, S=S, B=B)
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))  # slot costs
-        db_streams.append(((R,), jnp.float32))
-        out_specs = [out_specs, pl.BlockSpec(
-            (1, n_steps), lambda w, j, rowid, blk: (w, 0))]
-        out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((p, n_steps), jnp.float32)]
-    else:
-        kernel = functools.partial(_bfs_kernel_sharded,
-                                   n_vertices=n_vertices, S=S, B=B)
-    in_specs += [
-        pl.BlockSpec(frontier.shape, lambda w, j, rowid, blk: (0,)),
-        pl.BlockSpec(visited.shape, lambda w, j, rowid, blk: (0,)),
-    ]
-    call = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,  # sharded rowid + block ids to SMEM
-            grid=(p, n_steps),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            scratch_shapes=double_buffer_scratch(B, db_streams),
-        ),
-        out_shape=out_shape,
-        # workers are independent (item-closed partition): the shard
-        # dimension may run concurrently across TPU cores / megacore
-        compiler_params=None if interpret else pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )
-    if emit:
-        acc, costs = call(rowid, blkid, mask, cols,
-                          jnp.asarray(slot_cost, jnp.float32),
-                          frontier, visited)
-        return worker_reduce(acc, "max"), costs
-    acc = call(rowid, blkid, mask, cols, frontier, visited)
-    return worker_reduce(acc, "max")
+    out = segmented_reduce_sharded(mask * frontier[cols], rowid, blkid,
+                                   n_vertices, p, superstep, combine="max",
+                                   slot_cost=slot_cost, interpret=interpret)
+    if slot_cost is None:
+        return out * (1.0 - visited)
+    return out[0] * (1.0 - visited), out[1]

@@ -11,28 +11,29 @@ scheduled point is recomputed once per slot; the assignment write is
 idempotent (same argmin), so correctness is unaffected — redundant compute
 is the price a static grid pays where the runtime would have stolen.
 
-Two kernel realizations share the body (see ich_spmv for the pattern):
+Two grids share the body (see ich_spmv for the pattern):
 
-* `ich_kmeans_assign` — sequential reference grid (T,): each step gathers
-  its R scheduled points from the (n, D) point table in VMEM, computes
-  squared distances to the (K, D) centroids, and writes per-point argmin
-  through the prefetched item-id schedule ("store" mode: uncovered window
-  rows keep their previously written assignment).
+* `ich_kmeans_assign` — sequential reference grid (T,): each step takes
+  its R scheduled points, computes squared distances to the (K, D)
+  centroids, and writes per-point argmin through the item-id schedule
+  ("store" mode: uncovered window rows keep their previously written
+  assignment).
 * `ich_kmeans_assign_sharded` — worker-sharded 2D grid (p, S/B)
   (DESIGN.md §2.6): tiles are cost-partitioned across p workers
   (item-closed — no point spans workers), each grid step computes a
-  superstep of B tiles ((B*R, D) point gather), every worker stores into
-  its own row of a (p, n) block, and a pairwise tree max
-  (`core.segmented.worker_reduce`) folds the accumulators — bit-identical
-  to the sequential grid: assignments are >= 0, each point is stored by
-  exactly one worker, and every other worker holds the zero-initialized
-  identity.
+  superstep of B tiles, every worker stores into its own lane-dense
+  accumulator, and a pairwise tree max (`core.segmented.worker_reduce`)
+  folds the accumulators — bit-identical to the sequential grid:
+  assignments are >= 0, each point is stored by exactly one worker, and
+  every other worker holds the zero-initialized identity.
 
-Unlike the SpMV/BFS/MoE sharded kernels, this one needs no manual
-double-buffering (`core/pipelining.py`): its block streams are AFFINE in
-the grid step (the whole point/centroid tables sit in VMEM; the point
-gather indexes through SMEM scalars, not a data-dependent payload block),
-so Mosaic's automatic pipeliner already overlaps fetch and compute.
+The TPU compiler cannot gather rows of a VMEM table by an index vector,
+so the scheduled points are gathered in XLA before the kernel, one
+(D, B*R) block per superstep with the slots on the lane axis
+(`core.segmented.slots_on_lanes`): the distance to each centroid is a
+sublane reduction over D, and the argmin a running compare over the K
+centroids (first minimum wins, as `jnp.argmin`). Every stream is affine in
+the grid step, so Mosaic's automatic pipeliner double-buffers them.
 """
 from __future__ import annotations
 
@@ -43,84 +44,93 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.segmented import (emit_step_cost, segmented_apply,
-                                  segmented_apply_batch, worker_reduce)
+from repro.core.segmented import (LANES, acc_rows, add_step_cost,
+                                  compiler_params, cost_rows, fold_tiles,
+                                  slots_on_lanes, unpack_acc, window_starts,
+                                  worker_reduce)
 
 
-def _kmeans_kernel(rowid_ref, pts_ref, cent_ref, out_ref, *, n_points: int):
+def _assign(pts, cent):
+    """(D, N) points, (D, K) centroids -> (1, N) int32 nearest centroid."""
+    best, best_d = None, None
+    for k in range(cent.shape[1]):
+        d2 = jnp.sum((pts - cent[:, k:k + 1]) ** 2, axis=0, keepdims=True)
+        if best is None:
+            best, best_d = jnp.zeros(d2.shape, jnp.int32), d2
+            continue
+        closer = d2 < best_d
+        best = jnp.where(closer, k, best)
+        best_d = jnp.where(closer, d2, best_d)
+    return best
+
+
+def _gather_points(points, ids, tiles: int):
+    """Scheduled points of an (G*tiles, R) id stream, as (G, D, tiles*R)
+    superstep blocks (padding slots read point 0 and are never stored)."""
+    n = points.shape[0]
+    sel = jnp.asarray(points, jnp.float32)[jnp.clip(ids, 0, n - 1)]
+    return slots_on_lanes(sel, tiles)
+
+
+def _kmeans_kernel(starts_ref, rows_ref, pts_ref, cent_ref, out_ref, *,
+                   R: int):
     t = pl.program_id(0)
 
     @pl.when(t == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    pts = pts_ref[...]    # (n, D)
-    cent = cent_ref[...]  # (K, D)
-    ids = rowid_ref[t]    # (R,) SMEM scalars: point per slot, -1 pad
-    sel = pts[jnp.clip(ids, 0, n_points - 1)]  # (R, D)
-    d2 = jnp.sum((sel[:, None, :] - cent[None, :, :]) ** 2, axis=-1)  # (R, K)
-    assign = jnp.argmin(d2, axis=1).astype(jnp.int32)  # (R,)
     # duplicate slots of a split point carry the same argmin, so the
     # segmented "store" (any-wins within the window) is exact
-    segmented_apply(out_ref, ids, assign, combine="store")
+    fold_tiles(out_ref, starts_ref[t], rows_ref[...],
+               _assign(pts_ref[...], cent_ref[...]), rows_per_tile=R,
+               combine="store")
 
 
 def ich_kmeans_assign(points, centroids, rowid, *, interpret: bool = False):
     """Sequential reference grid. points (n, D); centroids (K, D);
     rowid (T, R) schedule. Returns assignments (n,) int32."""
-    n = points.shape[0]
+    n, D = points.shape
     T, R = rowid.shape
-    kernel = functools.partial(_kmeans_kernel, n_points=n)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # rowid prefetched to SMEM (the schedule)
-        grid=(T,),
-        in_specs=[
-            pl.BlockSpec(points.shape, lambda t, rowid: (0, 0)),
-            pl.BlockSpec(centroids.shape, lambda t, rowid: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((n,), lambda t, rowid: (0,)),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
+    n_acc = acc_rows(n, R)
+    acc = pl.pallas_call(
+        functools.partial(_kmeans_kernel, R=R),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # window start per tile, to SMEM
+            grid=(T,),
+            in_specs=[
+                pl.BlockSpec((None, 1, R), lambda t, st: (t, 0, 0)),
+                pl.BlockSpec((None, D, R), lambda t, st: (t, 0, 0)),
+                pl.BlockSpec((D, centroids.shape[0]), lambda t, st: (0, 0)),
+            ],
+            out_specs=pl.BlockSpec((None, n_acc, LANES),
+                                   lambda t, st: (0, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((1, n_acc, LANES), jnp.int32),
         interpret=interpret,
-    )(rowid, points, centroids)
+    )(window_starts(rowid, R), slots_on_lanes(rowid, 1),
+      _gather_points(points, rowid, 1),
+      jnp.asarray(centroids, jnp.float32).T)
+    return unpack_acc(acc, n)[0]
 
 
-def _kmeans_sharded_body(rowid_ref, pts_ref, cent_ref, out_ref, slotc_ref,
-                         cost_ref, *, n_points: int, S: int, B: int):
+def _kmeans_sharded_kernel(starts_ref, rows_ref, pts_ref, cent_ref, *refs,
+                           R: int, emit: bool):
     w, j = pl.program_id(0), pl.program_id(1)
+    slotc_ref = refs[0] if emit else None
+    outs = refs[1:] if emit else refs
 
     @pl.when(j == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-        if cost_ref is not None:
-            cost_ref[...] = jnp.zeros_like(cost_ref)
+        for o in outs:
+            o[...] = jnp.zeros_like(o)
 
-    pts = pts_ref[...]    # (n, D)
-    cent = cent_ref[...]  # (K, D)
-    ids = rowid_ref[pl.ds(w * S + j * B, B)]  # (B, R) SMEM scalars
-    flat = ids.reshape(-1)  # (B*R,)
-    sel = pts[jnp.clip(flat, 0, n_points - 1)]  # (B*R, D)
-    d2 = jnp.sum((sel[:, None, :] - cent[None, :, :]) ** 2, axis=-1)
-    assign = jnp.argmin(d2, axis=1).astype(jnp.int32).reshape(ids.shape)
-    segmented_apply_batch(out_ref, ids, assign, combine="store")
-    if cost_ref is not None:
-        emit_step_cost(cost_ref, ids, slotc_ref[...], j)
-
-
-def _kmeans_kernel_sharded(rowid_ref, pts_ref, cent_ref, out_ref, *,
-                           n_points: int, S: int, B: int):
-    _kmeans_sharded_body(rowid_ref, pts_ref, cent_ref, out_ref, None, None,
-                         n_points=n_points, S=S, B=B)
-
-
-def _kmeans_kernel_sharded_cost(rowid_ref, pts_ref, cent_ref, slotc_ref,
-                                out_ref, cost_ref, *, n_points: int,
-                                S: int, B: int):
-    _kmeans_sharded_body(rowid_ref, pts_ref, cent_ref, out_ref, slotc_ref,
-                         cost_ref, n_points=n_points, S=S, B=B)
+    rows = rows_ref[...]  # (1, B*R)
+    fold_tiles(outs[0], starts_ref[w * pl.num_programs(1) + j], rows,
+               _assign(pts_ref[...], cent_ref[...]), rows_per_tile=R,
+               combine="store")
+    if emit:
+        add_step_cost(outs[1], rows, slotc_ref[...], j)
 
 
 def ich_kmeans_assign_sharded(points, centroids, rowid, p: int,
@@ -134,50 +144,53 @@ def ich_kmeans_assign_sharded(points, centroids, rowid, p: int,
     `rowid`, since this kernel has no flat-payload indirection — the
     kernel additionally emits the per-worker, per-superstep cost output
     and returns (assignments, costs) (DESIGN.md §2.7)."""
-    n = points.shape[0]
+    n, D = points.shape
     PS, R = rowid.shape
     p, B = int(p), int(superstep)
     S = PS // p
     if PS != p * S or S % B:
         raise ValueError(f"shard layout mismatch: {PS} rows, p={p}, B={B}")
     n_steps = S // B
+    K = B * R
     emit = slot_cost is not None
-    in_specs = [
-        pl.BlockSpec(points.shape, lambda w, j, rowid: (0, 0)),
-        pl.BlockSpec(centroids.shape, lambda w, j, rowid: (0, 0)),
-    ]
-    out_specs = pl.BlockSpec((1, n), lambda w, j, rowid: (w, 0))
-    out_shape = jax.ShapeDtypeStruct((p, n), jnp.int32)
+
+    def step_block(shape):  # this worker's superstep j of a shard stream
+        return pl.BlockSpec((None,) + shape,
+                            lambda w, j, st: (w * n_steps + j, 0, 0))
+
+    in_specs = [step_block((1, K)), step_block((D, K)),
+                pl.BlockSpec((D, centroids.shape[0]),
+                             lambda w, j, st: (0, 0))]
+    n_acc = acc_rows(n, K)
+    out_specs = [pl.BlockSpec((None, n_acc, LANES),
+                              lambda w, j, st: (w, 0, 0))]
+    out_shape = [jax.ShapeDtypeStruct((p, n_acc, LANES), jnp.int32)]
+    args = [window_starts(rowid, K), slots_on_lanes(rowid, B),
+            _gather_points(points, rowid, B),
+            jnp.asarray(centroids, jnp.float32).T]
+    resident = n_acc * LANES * 4
     if emit:
-        kernel = functools.partial(_kmeans_kernel_sharded_cost, n_points=n,
-                                   S=S, B=B)
-        in_specs.append(pl.BlockSpec(
-            (B, R), lambda w, j, rowid: (w * (S // B) + j, 0)))
-        out_specs = [out_specs, pl.BlockSpec(
-            (1, n_steps), lambda w, j, rowid: (w, 0))]
-        out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((p, n_steps), jnp.float32)]
-    else:
-        kernel = functools.partial(_kmeans_kernel_sharded, n_points=n,
-                                   S=S, B=B)
-    call = pl.pallas_call(
-        kernel,
+        in_specs.append(step_block((1, K)))
+        args.append(slots_on_lanes(jnp.asarray(slot_cost, jnp.float32), B))
+        n_cost = cost_rows(n_steps)
+        out_specs.append(pl.BlockSpec((None, n_cost, LANES),
+                                      lambda w, j, st: (w, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((p, n_cost, LANES),
+                                              jnp.float32))
+        resident += n_cost * LANES * 4
+    outs = pl.pallas_call(
+        functools.partial(_kmeans_sharded_kernel, R=R, emit=emit),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,  # sharded rowid prefetched to SMEM
+            num_scalar_prefetch=1,  # window start per superstep, to SMEM
             grid=(p, n_steps),
             in_specs=in_specs,
             out_specs=out_specs,
         ),
         out_shape=out_shape,
-        # workers are independent (item-closed partition): the shard
-        # dimension may run concurrently across TPU cores / megacore
-        compiler_params=None if interpret else pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=None if interpret else compiler_params(resident),
         interpret=interpret,
-    )
+    )(*args)
+    assign = worker_reduce(unpack_acc(outs[0], n), "store")
     if emit:
-        acc, costs = call(rowid, points, centroids,
-                          jnp.asarray(slot_cost, jnp.float32))
-        return worker_reduce(acc, "store"), costs
-    acc = call(rowid, points, centroids)
-    return worker_reduce(acc, "store")
+        return assign, unpack_acc(outs[1], n_steps)
+    return assign

@@ -159,8 +159,8 @@ def ich_moe_sharded(vals, cols, rowid, blkid, x, wi, wg, wo, p: int,
     # data-dependent superstep blocks through 2-slot VMEM scratch
     # (core/pipelining.py)
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),  # vals (T_pad, R, W)
-        pl.BlockSpec(memory_space=pltpu.ANY),  # cols (T_pad, R, W)
+        pl.BlockSpec(memory_space=pl.ANY),  # vals (T_pad, R, W)
+        pl.BlockSpec(memory_space=pl.ANY),  # cols (T_pad, R, W)
     ]
     db_streams = [((R, W), vals.dtype), ((R, W), jnp.int32)]
     out_specs = pl.BlockSpec((1, n_tokens, D),
@@ -168,7 +168,7 @@ def ich_moe_sharded(vals, cols, rowid, blkid, x, wi, wg, wo, p: int,
     out_shape = jax.ShapeDtypeStruct((p, n_tokens, D), jnp.float32)
     if emit:
         kernel = functools.partial(_moe_kernel_sharded_cost, S=S, B=B)
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))  # slot costs
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))  # slot costs
         db_streams.append(((R,), jnp.float32))
         out_specs = [out_specs,
                      pl.BlockSpec((1, n_steps),
@@ -200,7 +200,7 @@ def ich_moe_sharded(vals, cols, rowid, blkid, x, wi, wg, wo, p: int,
         out_shape=out_shape,
         # workers accumulate into private rows; the shard dimension may
         # run concurrently across TPU cores / megacore
-        compiler_params=None if interpret else pltpu.TPUCompilerParams(
+        compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )

@@ -8,50 +8,39 @@ chosen by the paper's band classification over the row-nnz distribution
 tiles — the work-stealing analogue: no tile (chunk) can be overloaded, heavy
 rows' overflow migrates to later tiles exactly like stolen iterations.
 
-Two kernel realizations share the body:
+Two grids run the same segmented reduction (`core/segmented.py`):
 
 * `ich_spmv` — the sequential reference grid: grid = (T,), one tile per
-  step, read-modify-write accumulation into the single output vector (grid
-  steps execute in order on one TPU core, so the RMW is safe).
+  step, folded into the single output accumulator (grid steps execute in
+  order on one TPU core, so the read-modify-write is safe).
 * `ich_spmv_sharded` — the production 2D grid (DESIGN.md §2.6): the
   schedule's parallelism p is lowered onto the accelerator as a
   worker-major grid (p, S_B). Tiles are cost-partitioned across p workers
   at superstep-block granularity (`core.tiling.partition_tiles`,
   item-closed so no row spans workers) and each grid step processes a
-  SUPERSTEP of B tiles — fetched as one aligned (B, R, W) block straight
-  out of the FLAT payload via a prefetched data-dependent block index
-  (`WorkerShards.kernel_block_ids`; lowering moves no payload bytes) —
-  with B in-order windowed RMWs, amortizing per-step dispatch/prefetch
-  overhead. The payload fetch is DOUBLE-BUFFERED (`core/pipelining.py`):
-  step j+1's blocks DMA into the spare VMEM slot while step j computes,
-  restoring the fetch/compute overlap Mosaic cannot derive for a
-  data-dependent block index. Every worker accumulates into its own row of a (p, n_rows)
-  output block (no cross-worker races; the worker dimension is declared
-  "parallel" so Mosaic may split it across TPU cores), and a host-side
-  pairwise tree reduce (`core.segmented.worker_reduce`) folds the
-  accumulators — bit-identical to the sequential grid because each row is
-  owned by exactly one worker and all others contribute exact zeros.
+  SUPERSTEP of B tiles, fetched as one block straight out of the FLAT
+  payload via a prefetched data-dependent block index
+  (`WorkerShards.kernel_block_ids`) and DOUBLE-BUFFERED
+  (`core/pipelining.py`): step j+1's block streams into the spare VMEM
+  slot while step j computes. Every worker accumulates into its own
+  lane-dense block of a (p, rows, 128) output (no cross-worker races; the
+  worker dimension is declared "parallel"), and a pairwise tree reduce
+  (`core.segmented.worker_reduce`) folds the accumulators — bit-identical
+  to the sequential grid because each row is owned by exactly one worker
+  and all others contribute exact zeros.
 
-x is kept whole in VMEM (fits for n <= ~1M fp32). The per-tile
-accumulation routes through the shared segmented-reduction layer
-(`core/segmented.py`): a one-hot matmul folds the R partial sums into one
-length-R output window instead of R scalar read-modify-writes.
+The TPU compiler has no gather of a vector by an index array, so the
+products vals * x[cols] are formed in XLA before the kernel and streamed
+as the payload; the kernel sums each slot's W products and folds the
+partial sums into the output rows. The whole output accumulator stays in
+VMEM (`core.segmented.compiler_params` sizes the limit): about 14M rows
+fit a v5e core.
 """
 from __future__ import annotations
 
-import functools
-
 import numpy as np
 
-import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-from repro.core.pipelining import (double_buffer_scratch,
-                                   fetch_double_buffered)
-from repro.core.segmented import (emit_step_cost, segmented_apply,
-                                  segmented_apply_batch, worker_reduce)
+from repro.core.segmented import segmented_reduce, segmented_reduce_sharded
 from repro.core.tiling import build_schedule, ich_tile_width, pack_csr
 from repro.sched.defaults import ICH_EPS
 
@@ -75,90 +64,11 @@ def pack_tiles(indptr: np.ndarray, indices: np.ndarray, data: np.ndarray,
     return vals, cols, sched.item_id, sched.width
 
 
-def _spmv_kernel(rowid_ref, vals_ref, cols_ref, x_ref, out_ref):
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    vals = vals_ref[0]  # (R, W)
-    cols = cols_ref[0]
-    x = x_ref[...]  # (n,)
-    partial = jnp.sum(vals * x[cols], axis=1)  # (R,)
-    rows = rowid_ref[t]  # (R,) SMEM scalars for this tile
-    # rows may repeat across tiles (split rows): sum-accumulate through the
-    # shared segmented epilogue (one windowed RMW, padding masked inside)
-    segmented_apply(out_ref, rows, partial, combine="add")
-
-
 def ich_spmv(vals, cols, rowid, x, n_rows: int, *, interpret: bool = False):
     """Sequential reference grid. vals/cols (T,R,W); rowid (T,R); x (n,).
     Returns y (n_rows,)."""
-    T, R, W = vals.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # rowid prefetched to SMEM (the schedule)
-        grid=(T,),
-        in_specs=[
-            pl.BlockSpec((1, R, W), lambda t, rowid: (t, 0, 0)),
-            pl.BlockSpec((1, R, W), lambda t, rowid: (t, 0, 0)),
-            pl.BlockSpec(x.shape, lambda t, rowid: (0,)),  # x whole in VMEM
-        ],
-        out_specs=pl.BlockSpec((n_rows,), lambda t, rowid: (0,)),
-    )
-    return pl.pallas_call(
-        _spmv_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_rows,), x.dtype),
-        interpret=interpret,
-    )(rowid, vals, cols, x)
-
-
-def _spmv_sharded_body(rowid_ref, blkid_ref, vals_hbm, cols_hbm, slotc_hbm,
-                       x_ref, out_ref, cost_ref, bufs, sems, *, S: int,
-                       B: int):
-    w, j = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-        if cost_ref is not None:
-            cost_ref[...] = jnp.zeros_like(cost_ref)
-
-    # double-buffered data-dependent fetch: superstep s+1's blocks stream
-    # in while s computes (core/pipelining.py); same block bytes in the
-    # same order as the single-buffered lowering, so results are
-    # bit-identical to the sequential grid
-    hbm = (vals_hbm, cols_hbm) if slotc_hbm is None \
-        else (vals_hbm, cols_hbm, slotc_hbm)
-    blocks = fetch_double_buffered(list(zip(hbm, bufs, sems)),
-                                   blkid_ref, w, j, B=B)
-    vals = blocks[0]  # (B, R, W): one superstep of this worker's shard
-    cols = blocks[1]
-    x = x_ref[...]  # (n,)
-    partial = jnp.sum(vals * x[cols], axis=2)  # (B, R)
-    rows = rowid_ref[pl.ds(w * S + j * B, B)]  # (B, R) SMEM scalars
-    # B in-order windowed RMWs into THIS worker's accumulator row — the
-    # same fold order the sequential grid uses for these tiles
-    segmented_apply_batch(out_ref, rows, partial, combine="add")
-    if cost_ref is not None:
-        emit_step_cost(cost_ref, rows, blocks[2], j)
-
-
-def _spmv_kernel_sharded(rowid_ref, blkid_ref, vals_hbm, cols_hbm, x_ref,
-                         out_ref, vbuf, cbuf, vsem, csem, *, S: int, B: int):
-    _spmv_sharded_body(rowid_ref, blkid_ref, vals_hbm, cols_hbm, None,
-                       x_ref, out_ref, None, (vbuf, cbuf), (vsem, csem),
-                       S=S, B=B)
-
-
-def _spmv_kernel_sharded_cost(rowid_ref, blkid_ref, vals_hbm, cols_hbm,
-                              slotc_hbm, x_ref, out_ref, cost_ref, vbuf,
-                              cbuf, sbuf, vsem, csem, ssem, *, S: int,
-                              B: int):
-    _spmv_sharded_body(rowid_ref, blkid_ref, vals_hbm, cols_hbm, slotc_hbm,
-                       x_ref, out_ref, cost_ref, (vbuf, cbuf, sbuf),
-                       (vsem, csem, ssem), S=S, B=B)
+    return segmented_reduce(vals * x[cols], rowid, n_rows, combine="add",
+                            interpret=interpret)
 
 
 def ich_spmv_sharded(vals, cols, rowid, blkid, x, n_rows: int, p: int,
@@ -176,55 +86,6 @@ def ich_spmv_sharded(vals, cols, rowid, blkid, x, n_rows: int, p: int,
     (y, costs): the measured-cost feedback the refiner folds back into
     per-item estimates (DESIGN.md §2.7). Padding steps emit 0, so per-
     worker sums account exactly the schedule's tile costs."""
-    T_pad, R, W = vals.shape
-    p, B = int(p), int(superstep)
-    n_steps = int(blkid.shape[0]) // p
-    S = n_steps * B
-    if blkid.shape[0] != p * n_steps or rowid.shape[0] != p * S or T_pad % B:
-        raise ValueError(f"shard layout mismatch: blkid {blkid.shape}, "
-                         f"rowid {rowid.shape}, T_pad={T_pad}, p={p}, B={B}")
-    emit = slot_cost is not None
-    # data-dependent superstep payloads stay whole in ANY memory; the
-    # kernel double-buffers them through 2-slot VMEM scratch so step j+1's
-    # blocks stream in while step j computes (core/pipelining.py)
-    in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),  # vals (T_pad, R, W)
-        pl.BlockSpec(memory_space=pltpu.ANY),  # cols (T_pad, R, W)
-    ]
-    db_streams = [((R, W), vals.dtype), ((R, W), jnp.int32)]
-    out_specs = pl.BlockSpec((1, n_rows), lambda w, j, rowid, blk: (w, 0))
-    out_shape = jax.ShapeDtypeStruct((p, n_rows), x.dtype)
-    if emit:
-        kernel = functools.partial(_spmv_kernel_sharded_cost, S=S, B=B)
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))  # slot costs
-        db_streams.append(((R,), jnp.float32))
-        out_specs = [out_specs, pl.BlockSpec(
-            (1, n_steps), lambda w, j, rowid, blk: (w, 0))]
-        out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((p, n_steps), jnp.float32)]
-    else:
-        kernel = functools.partial(_spmv_kernel_sharded, S=S, B=B)
-    in_specs.append(pl.BlockSpec(x.shape, lambda w, j, rowid, blk: (0,)))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # sharded rowid + block ids to SMEM
-        grid=(p, n_steps),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=double_buffer_scratch(B, db_streams),
-    )
-    call = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        # workers are independent (item-closed partition): the shard
-        # dimension may run concurrently across TPU cores / megacore
-        compiler_params=None if interpret else pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )
-    if emit:
-        acc, costs = call(rowid, blkid, vals, cols,
-                          jnp.asarray(slot_cost, jnp.float32), x)
-        return worker_reduce(acc, "add"), costs
-    acc = call(rowid, blkid, vals, cols, x)
-    return worker_reduce(acc, "add")
+    return segmented_reduce_sharded(vals * x[cols], rowid, blkid, n_rows, p,
+                                    superstep, combine="add",
+                                    slot_cost=slot_cost, interpret=interpret)

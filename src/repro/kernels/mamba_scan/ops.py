@@ -4,13 +4,13 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from .mamba_scan import mamba_scan
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def mamba_scan_op(q, k, v, log_a, *, chunk: int = 128, interpret: bool | None = None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = default_interpret(interpret)
     S = q.shape[1]
     pad = (-S) % chunk
     if pad:

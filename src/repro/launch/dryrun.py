@@ -149,14 +149,12 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool = False,
         rec["compile_s"] = round(time.time() - t0, 1)
         mem = compiled.memory_analysis()
         rec["memory"] = {
-            "argument_bytes": int(getattr(mem, "argument_size_in_bytes", 0)),
-            "output_bytes": int(getattr(mem, "output_size_in_bytes", 0)),
-            "temp_bytes": int(getattr(mem, "temp_size_in_bytes", 0)),
-            "alias_bytes": int(getattr(mem, "alias_size_in_bytes", 0)),
+            "argument_bytes": int(mem.argument_size_in_bytes),
+            "output_bytes": int(mem.output_size_in_bytes),
+            "temp_bytes": int(mem.temp_size_in_bytes),
+            "alias_bytes": int(mem.alias_size_in_bytes),
         }
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jaxlib: one dict per device
-            cost = cost[0] if cost else {}
         rec["cost"] = {k: float(v) for k, v in cost.items()
                        if isinstance(v, (int, float)) and k in
                        ("flops", "bytes accessed", "transcendentals",

@@ -36,6 +36,7 @@ import functools
 import numpy as np
 
 from repro.core.tiling import pack_csr
+from repro.kernels import default_interpret
 
 from .api import Schedule
 from .costs import (DegreeCosts, ExpertLoadCosts, ExplicitCosts, NnzCosts,
@@ -89,13 +90,6 @@ class _ObservableOp:
                                      shards=self.shards)
 
 
-def _default_interpret(interpret):
-    if interpret is None:
-        import jax
-        return jax.default_backend() != "tpu"
-    return interpret
-
-
 class SpmvOp(_ObservableOp):
     """iCh-scheduled segmented CSR SpMV: pack once, apply many times."""
 
@@ -126,7 +120,7 @@ class SpmvOp(_ObservableOp):
         if self.schedule.n_tiles == 0:
             self.last_costs = self._empty_costs()
             return jnp.zeros((self.n_rows,), jnp.float32)
-        interpret = _default_interpret(interpret)
+        interpret = default_interpret(interpret)
         if interpret not in self._jitted:
             self._jitted[interpret] = jax.jit(functools.partial(
                 ich_spmv_sharded, n_rows=self.n_rows, p=self.p,
@@ -168,7 +162,7 @@ class BfsOp(_ObservableOp):
         if self.schedule.n_tiles == 0:
             self.last_costs = self._empty_costs()
             return jnp.zeros((self.n,), jnp.float32)
-        interpret = _default_interpret(interpret)
+        interpret = default_interpret(interpret)
         if interpret not in self._jitted:
             self._jitted[interpret] = jax.jit(functools.partial(
                 ich_bfs_step_sharded, n_vertices=self.n, p=self.p,
@@ -221,7 +215,7 @@ class KMeansOp(_ObservableOp):
         if self.schedule.n_tiles == 0:
             self.last_costs = self._empty_costs()
             return jnp.zeros((self.n,), jnp.int32)
-        interpret = _default_interpret(interpret)
+        interpret = default_interpret(interpret)
         if interpret not in self._jitted:
             self._jitted[interpret] = jax.jit(functools.partial(
                 ich_kmeans_assign_sharded, p=self.p,
@@ -283,7 +277,7 @@ class MoeDispatchOp(_ObservableOp):
             self.last_expert_costs = jnp.zeros(
                 (self.p, self.n_experts), jnp.float32)
             return jnp.zeros((self.n_tokens, x.shape[-1]), x.dtype)
-        interpret = _default_interpret(interpret)
+        interpret = default_interpret(interpret)
         if interpret not in self._jitted:
             self._jitted[interpret] = jax.jit(functools.partial(
                 ich_moe_sharded, p=self.p, superstep=self.superstep,

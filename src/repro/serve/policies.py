@@ -266,10 +266,7 @@ class IChAdaptive:
             [(begin, begin + covered)], np.array([covered * rel]))
         self._observed += 1
         if self._observed % self.refine_every == 0:
-            try:
-                self._schedule = self._schedule.refine()
-            except Exception:
-                self._schedule = None  # rebuild lazily on next choose()
+            self._schedule = self._schedule.refine()
 
 
 def default_policies(chunk: int = 64) -> list:

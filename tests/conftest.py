@@ -1,5 +1,5 @@
 """Shared test helpers (importable as `from conftest import ...` — pytest
-puts this directory on sys.path, same mechanism as _hypothesis_compat)."""
+puts this directory on sys.path)."""
 import numpy as np
 
 

@@ -7,7 +7,7 @@ import threading
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from conftest import random_csr as _random_csr
 
 from repro.core import policies as P

@@ -3,7 +3,7 @@ import threading
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     HIGH, LOW, NORMAL, SimParams, Welford, adapt_d, classify, dynamic,

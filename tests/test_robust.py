@@ -16,7 +16,7 @@ import time
 import numpy as np
 import pytest
 
-from _hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
+from hypothesis import given, settings, strategies as st
 from conftest import random_csr
 
 from repro.core import executor as E
@@ -422,7 +422,6 @@ def test_chaos_smoke_matrix(seed):
 
 # ------------------------------------------------ hypothesis properties
 
-@pytest.mark.skipif(not HAVE_HYPOTHESIS, reason="hypothesis not installed")
 class TestRecoveryProperties:
     """Satellite 3: recovery invariants over random workloads + plans."""
 
@@ -497,7 +496,6 @@ class TestFaultPlanSerialization:
             FaultPlan.from_json(bad)
 
 
-@pytest.mark.skipif(not HAVE_HYPOTHESIS, reason="hypothesis not installed")
 class TestFaultPlanJsonProperties:
     """Satellite (PR 9): to_json/from_json is the identity over the full
     plan space, and the fingerprint is a function of plan VALUE only."""
@@ -514,7 +512,7 @@ class TestFaultPlanJsonProperties:
         flaky_failures=st.integers(1, 5),
         poison=st.lists(st.integers(0, 1000), max_size=4).map(tuple),
         cost_noise=st.floats(0.0, 3.0),
-    ) if HAVE_HYPOTHESIS else None
+    )
 
     @settings(max_examples=60, deadline=None)
     @given(plan=plans)

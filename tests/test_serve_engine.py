@@ -18,6 +18,11 @@ from repro.serve.metrics import ServeMetrics
 from repro.serve.queue import Request, RequestState
 
 ECFG = dict(max_seq=64, min_chunk=4)
+# Chunked vs one-shot prefill logits: the same sums in a different order.
+# On the CPU the largest difference is ~3e-7 on logits of order 1, so
+# 1e-5 leaves headroom for reassociation yet fails a wrong mask, position
+# or cache slot, which moves logits by O(0.1).
+CHUNK_TOL = dict(rtol=1e-5, atol=1e-5)
 
 
 @pytest.fixture(scope="module")
@@ -42,16 +47,20 @@ def prompts_for(cfg, B=2, S=24, seed=2):
 
 class TestChunkedPrefill:
     def test_matches_one_shot_bit_identical(self, tiny_model, engine):
-        """Chunked prefill's final logits must equal a one-shot prefill of
-        the same prompt bit-for-bit: the last chunk runs the FULL prompt
-        through the same jitted prefill, so chunking affects scheduling
-        (and the iCh divisor), never the math."""
+        """Chunked prefill's final logits must match a one-shot prefill of
+        the same prompt. Each chunk feeds only its own tokens into the
+        growing KV cache (`models.prefill_extend`), so the attention and
+        projection sums run over different splits than the one-shot
+        program and XLA orders them differently — on the CPU and on the
+        TPU alike. Chunking may move the logits by float32 rounding
+        (CHUNK_TOL), never by a logic error."""
         cfg, params = tiny_model
         toks = prompts_for(cfg)
         logits, _, log = engine.prefill_chunked(toks)
         one_shot = Engine(cfg, params, EngineConfig(**ECFG))
         ref, _ = one_shot._prefill(params, {"tokens": np.asarray(toks)})
-        np.testing.assert_array_equal(np.asarray(logits), np.asarray(ref))
+        np.testing.assert_allclose(np.asarray(logits), np.asarray(ref),
+                                   **CHUNK_TOL)
         assert len(log) > 1  # S=24 with d_0=4, min_chunk=4 -> chunked
 
     def test_chunk_log_covers_prompt_exactly(self, tiny_model, engine):
@@ -65,8 +74,9 @@ class TestChunkedPrefill:
     def test_outputs_independent_of_chunk_count(self, tiny_model):
         """Incremental prefill feeds each chunk into the growing cache
         (O(chunk) work per chunk, engine no longer re-runs the prefix);
-        the final logits must be bit-identical however the prompt is cut.
-        Divisors 1/3/8 produce genuinely different chunk sequences."""
+        the final logits must agree within float32 summation-order
+        rounding (CHUNK_TOL) however the prompt is cut. Divisors 1/3/8
+        produce genuinely different chunk sequences."""
         cfg, params = tiny_model
         toks = prompts_for(cfg, B=2, S=24)
         logits, counts = [], []
@@ -79,7 +89,7 @@ class TestChunkedPrefill:
             counts.append(len(log))
         assert len(set(counts)) > 1  # the splits really differed
         for lg in logits[1:]:
-            np.testing.assert_array_equal(lg, logits[0])
+            np.testing.assert_allclose(lg, logits[0], **CHUNK_TOL)
 
 
 # ------------------------------------------------ iCh divisor adaptation

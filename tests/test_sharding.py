@@ -6,7 +6,7 @@ shard layout, superstep-padded CSR packing, the simulator cross-check
 workloads."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from conftest import random_csr as _random_csr
 
 from repro.core import policies as P

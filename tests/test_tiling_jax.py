@@ -18,7 +18,7 @@ order (`_pairwise_rowsum`, segment sums). Three layers of evidence:
 """
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import tiling as T
 from repro.core import tiling_jax as TJ

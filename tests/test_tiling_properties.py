@@ -4,7 +4,7 @@ construction: the array programs in core/tiling.py must match the
 still replay chunk-for-chunk through the discrete-event simulator, for
 arbitrary sizes / rows_per_tile / width."""
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import policies as P
 from repro.core.simulator import simulate
