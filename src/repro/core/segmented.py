@@ -448,24 +448,26 @@ def segmented_reduce_sharded(payload: jax.Array, rowid: jax.Array,
     emit = slot_cost is not None
     # payload streams stay whole in HBM as (T_pad // B, W, B*R) superstep
     # blocks, fetched by the prefetched block ids
-    hbm = [slots_on_lanes(payload, B, whole_lanes=True)]
-    streams = [(hbm[0].shape[1:], payload.dtype)]
+    with jax.named_scope("ich.relayout"):
+        hbm = [slots_on_lanes(payload, B, whole_lanes=True)]
+        if emit:
+            hbm.append(slots_on_lanes(jnp.asarray(slot_cost, jnp.float32),
+                                      B, whole_lanes=True))
+        starts, rows = window_starts(rowid, K), slots_on_lanes(rowid, B)
+    streams = [(h.shape[1:], h.dtype) for h in hbm]
     n_acc = acc_rows(n_out, K)
     out_specs = [pl.BlockSpec((None, n_acc, LANES),
                               lambda w, j, st, blk: (w, 0, 0))]
     out_shape = [jax.ShapeDtypeStruct((p, n_acc, LANES), payload.dtype)]
     resident = n_acc * LANES * payload.dtype.itemsize
     if emit:
-        hbm.append(slots_on_lanes(jnp.asarray(slot_cost, jnp.float32), B,
-                                  whole_lanes=True))
-        streams.append((hbm[1].shape[1:], jnp.float32))
         n_cost = cost_rows(n_steps)
         out_specs.append(pl.BlockSpec((None, n_cost, LANES),
                                       lambda w, j, st, blk: (w, 0, 0)))
         out_shape.append(jax.ShapeDtypeStruct((p, n_cost, LANES),
                                               jnp.float32))
         resident += n_cost * LANES * 4
-    outs = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_payload_sharded_kernel, R=R, combine=combine,
                           emit=emit),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -481,8 +483,12 @@ def segmented_reduce_sharded(payload: jax.Array, rowid: jax.Array,
         out_shape=out_shape,
         compiler_params=None if interpret else compiler_params(resident),
         interpret=interpret,
-    )(window_starts(rowid, K), blkid, slots_on_lanes(rowid, B), *hbm)
-    out = worker_reduce(unpack_acc(outs[0], n_out), combine)
-    if emit:
-        return out, unpack_acc(outs[1], n_steps)
-    return out
+        name=f"ich_reduce_{combine}",
+    )
+    with jax.named_scope("ich.kernel"):
+        outs = call(starts, blkid, rows, *hbm)
+    with jax.named_scope("ich.fold"):
+        out = worker_reduce(unpack_acc(outs[0], n_out), combine)
+        if emit:
+            return out, unpack_acc(outs[1], n_steps)
+        return out

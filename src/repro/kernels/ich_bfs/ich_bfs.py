@@ -36,6 +36,8 @@ indicators are 0/1 and a vertex's mask is the same for all its slots).
 """
 from __future__ import annotations
 
+import jax
+
 from repro.core.segmented import segmented_reduce, segmented_reduce_sharded
 
 
@@ -62,9 +64,14 @@ def ich_bfs_step_sharded(mask, cols, rowid, blkid, frontier, visited,
     additionally emits the per-worker, per-superstep cost output and
     returns (next_frontier, costs) — the measured-cost feedback stream
     (DESIGN.md §2.7)."""
-    out = segmented_reduce_sharded(mask * frontier[cols], rowid, blkid,
-                                   n_vertices, p, superstep, combine="max",
+    with jax.named_scope("ich.gather"):
+        fs = frontier[cols]
+    with jax.named_scope("ich.payload"):
+        payload = mask * fs
+    out = segmented_reduce_sharded(payload, rowid, blkid, n_vertices, p,
+                                   superstep, combine="max",
                                    slot_cost=slot_cost, interpret=interpret)
-    if slot_cost is None:
-        return out * (1.0 - visited)
-    return out[0] * (1.0 - visited), out[1]
+    with jax.named_scope("ich.fold"):
+        if slot_cost is None:
+            return out * (1.0 - visited)
+        return out[0] * (1.0 - visited), out[1]

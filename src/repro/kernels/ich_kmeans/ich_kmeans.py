@@ -189,6 +189,7 @@ def ich_kmeans_assign_sharded(points, centroids, rowid, p: int,
         out_shape=out_shape,
         compiler_params=None if interpret else compiler_params(resident),
         interpret=interpret,
+        name="ich_kmeans_assign",
     )(*args)
     assign = worker_reduce(unpack_acc(outs[0], n), "store")
     if emit:

@@ -203,6 +203,7 @@ def ich_moe_sharded(vals, cols, rowid, blkid, x, wi, wg, wo, p: int,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="ich_moe",
     )
     if emit:
         acc, costs, ecosts = call(rowid, blkid, vals, cols,

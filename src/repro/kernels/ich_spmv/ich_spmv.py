@@ -38,6 +38,7 @@ fit a v5e core.
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 from repro.core.segmented import segmented_reduce, segmented_reduce_sharded
@@ -86,6 +87,10 @@ def ich_spmv_sharded(vals, cols, rowid, blkid, x, n_rows: int, p: int,
     (y, costs): the measured-cost feedback the refiner folds back into
     per-item estimates (DESIGN.md §2.7). Padding steps emit 0, so per-
     worker sums account exactly the schedule's tile costs."""
-    return segmented_reduce_sharded(vals * x[cols], rowid, blkid, n_rows, p,
+    with jax.named_scope("ich.gather"):
+        xs = x[cols]
+    with jax.named_scope("ich.payload"):
+        payload = vals * xs
+    return segmented_reduce_sharded(payload, rowid, blkid, n_rows, p,
                                     superstep, combine="add",
                                     slot_cost=slot_cost, interpret=interpret)

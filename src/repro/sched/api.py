@@ -27,6 +27,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core import executor as E
 from repro.core import policies as P
 from repro.core import simulator as S
@@ -641,7 +642,13 @@ class LoopScheduler:
         schedule's lowerings are always freshly keyed, never a stale
         entry's (sched/cache.py).
         """
-        provider = as_cost_provider(costs)
+        with obs.span("sched.schedule"):
+            return self._schedule(as_cost_provider(costs), policy, p,
+                                  rows_per_tile, width, eps, superstep,
+                                  _generation)
+
+    def _schedule(self, provider: CostProvider, policy, p, rows_per_tile,
+                  width, eps, superstep, _generation) -> Schedule:
         pol = policy if policy is not None else self.policy
         pp = int(p if p is not None else self.p)
         rpt = int(rows_per_tile if rows_per_tile is not None
@@ -665,22 +672,25 @@ class LoopScheduler:
                band_eps, self.min_w, self.max_w, sstep, gen, self.backend)
 
         def build() -> Schedule:
-            sizes = provider.sizes()
-            if self.backend == "jax":
-                from repro.core import tiling_jax as TJ
-                tiles = TJ.build_schedule_jax(
-                    sizes, rows_per_tile=rpt, width=width, eps=band_eps,
-                    min_w=self.min_w, max_w=self.max_w).to_host()
-            else:
-                tiles = T.build_schedule(sizes, rows_per_tile=rpt,
-                                         width=width, eps=band_eps,
-                                         min_w=self.min_w, max_w=self.max_w)
-            return Schedule(sizes=sizes, costs=provider.costs(), policy=pol,
-                            p=pp, tiles=tiles, sim_params=self.sim_params,
-                            superstep=sstep, generation=gen,
-                            structural_sizes=structural, width_arg=width,
-                            band_eps=band_eps, backend=self.backend,
-                            _scheduler=self)
+            with obs.span("sched.construct"):
+                sizes = provider.sizes()
+                if self.backend == "jax":
+                    from repro.core import tiling_jax as TJ
+                    tiles = TJ.build_schedule_jax(
+                        sizes, rows_per_tile=rpt, width=width,
+                        eps=band_eps, min_w=self.min_w,
+                        max_w=self.max_w).to_host()
+                else:
+                    tiles = T.build_schedule(
+                        sizes, rows_per_tile=rpt, width=width,
+                        eps=band_eps, min_w=self.min_w, max_w=self.max_w)
+                return Schedule(
+                    sizes=sizes, costs=provider.costs(), policy=pol, p=pp,
+                    tiles=tiles, sim_params=self.sim_params,
+                    superstep=sstep, generation=gen,
+                    structural_sizes=structural, width_arg=width,
+                    band_eps=band_eps, backend=self.backend,
+                    _scheduler=self)
 
         if self.cache is None:
             return build()
@@ -699,12 +709,14 @@ class LoopScheduler:
         schedule through the cache, and hands both to the entry's builder.
         """
         from . import registry
-        entry = registry.get(workload)
-        provider = entry.costs(*inputs)
-        s = self.schedule(provider, policy=policy, p=p,
-                          rows_per_tile=rows_per_tile, width=width, eps=eps,
-                          superstep=superstep)
-        return entry.build(s, *inputs)
+        with obs.span("sched.build", workload=workload):
+            entry = registry.get(workload)
+            # the provider hashes its inputs as it is made: part of the
+            # schedule's cost, so inside its span
+            with obs.span("sched.schedule"):
+                s = self._schedule(entry.costs(*inputs), policy, p,
+                                   rows_per_tile, width, eps, superstep, 0)
+            return entry.build(s, *inputs)
 
     # --------------------------------------------- direct backend shortcuts
     def simulate(self, costs, *, policy: Optional[P.Policy] = None,

@@ -25,6 +25,12 @@ under a fresh cache generation. Per-worker sums of the emitted stream
 equal the schedule's tile-cost totals exactly — the routing proof in
 tests/test_adaptive_properties.py.
 
+Each op makes its one jitted program in `_ObservableOp._run`, under a
+stable name (`ich_spmv`, `ich_bfs_step`, `ich_kmeans_assign`, `ich_moe`)
+that traces and HLO show, and runs its host work in `repro.obs` spans:
+`op.shard`, `op.pack`, `op.upload` at construction, `op.compile` on the
+first call, `op.dispatch` on every later one.
+
 jax is imported inside the op constructors: deriving costs and constructing
 schedules is numpy-only, and the registry must be listable without paying
 the jax import.
@@ -35,6 +41,7 @@ import functools
 
 import numpy as np
 
+from repro import obs
 from repro.core.tiling import pack_csr
 from repro.kernels import default_interpret
 
@@ -64,11 +71,65 @@ def _sharded_slot_cost(schedule: Schedule, shards) -> np.ndarray:
 
 
 class _ObservableOp:
-    """Shared feedback plumbing: stash the kernel's latest cost stream and
-    route it into the schedule's refiner on demand."""
+    """Shared plumbing of the kernel ops: shard, pack and upload a
+    schedule's payload; make and call the op's one jitted program; stash
+    the kernel's latest cost stream and route it into the schedule's
+    refiner on demand. Each stretch of host work runs in an `obs.span`."""
 
+    program: str  # the jitted program's name, as traces and HLO show it
     schedule: Schedule
     last_costs = None  # (p, S_B) device array from the latest invocation
+
+    def __init__(self, schedule: Schedule):
+        self.schedule = schedule
+        self._jitted = {}  # interpret mode -> jitted program (compile once)
+
+    def _bind_csr(self, indptr, indices, data):
+        """Shard, pack and upload a CSR payload in the FLAT layout the
+        SpMV, BFS and MoE kernels fetch blockwise. Sets the shard streams
+        `rowid`, `blkid` and `slot_cost`; returns the device (vals,
+        cols)."""
+        import jax.numpy as jnp
+        schedule = self.schedule
+        with obs.span("op.shard"):
+            shards = self.shards = schedule.shard()
+            streams = (shards.shard_item_id(schedule.tiles),
+                       shards.kernel_block_ids(),
+                       _flat_slot_cost(schedule, shards.n_tiles_padded))
+        with obs.span("op.pack"):
+            vals, cols = pack_csr(np.asarray(indptr), np.asarray(indices),
+                                  np.asarray(data), schedule.tiles,
+                                  pad_tiles_to=shards.superstep)
+        with obs.span("op.upload"):
+            vals, cols, self.rowid, self.blkid, self.slot_cost = (
+                jnp.asarray(a) for a in (vals, cols, *streams))
+        self.p = shards.p
+        self.superstep = shards.superstep
+        return vals, cols
+
+    def _kernel(self):
+        """The sharded kernel with this op's static arguments bound."""
+        raise NotImplementedError
+
+    def _program(self, interpret: bool):
+        """The kernel as a jitted program named `self.program`."""
+        import jax
+        fn = functools.partial(self._kernel(), interpret=interpret)
+        fn.__name__ = self.program
+        return jax.jit(fn)
+
+    def _run(self, interpret: bool | None, *args, **kwargs):
+        """Call the op's program. The first call per interpret mode makes
+        it (trace, lower, compile or persistent-cache hit) under
+        `op.compile`; later calls dispatch under `op.dispatch`."""
+        interpret = default_interpret(interpret)
+        prog = self._jitted.get(interpret)
+        if prog is None:
+            with obs.span("op.compile", program=self.program):
+                prog = self._jitted[interpret] = self._program(interpret)
+                return prog(*args, **kwargs)
+        with obs.span("op.dispatch", program=self.program):
+            return prog(*args, **kwargs)
 
     def _empty_costs(self):
         """Zero (p, S_B) cost stream for a 0-tile schedule: an empty
@@ -93,40 +154,26 @@ class _ObservableOp:
 class SpmvOp(_ObservableOp):
     """iCh-scheduled segmented CSR SpMV: pack once, apply many times."""
 
+    program = "ich_spmv"
+
     def __init__(self, schedule: Schedule, indptr, indices, data):
-        import jax.numpy as jnp
-        self.schedule = schedule
+        super().__init__(schedule)
         self.n_rows = len(indptr) - 1
-        shards = self.shards = schedule.shard()
-        vals, cols = pack_csr(np.asarray(indptr), np.asarray(indices),
-                              np.asarray(data), schedule.tiles,
-                              pad_tiles_to=shards.superstep)
         self.width = schedule.width
-        self.p = shards.p
-        self.superstep = shards.superstep
-        self.vals = jnp.asarray(vals)
-        self.cols = jnp.asarray(cols)
-        self.rowid = jnp.asarray(shards.shard_item_id(schedule.tiles))
-        self.blkid = jnp.asarray(shards.kernel_block_ids())
-        self.slot_cost = jnp.asarray(
-            _flat_slot_cost(schedule, shards.n_tiles_padded))
-        self.last_costs = None
-        self._jitted = {}  # interpret mode -> jitted spmv (compile once)
+        self.vals, self.cols = self._bind_csr(indptr, indices, data)
+
+    def _kernel(self):
+        from repro.kernels.ich_spmv.ich_spmv import ich_spmv_sharded
+        return functools.partial(ich_spmv_sharded, n_rows=self.n_rows,
+                                 p=self.p, superstep=self.superstep)
 
     def __call__(self, x, interpret: bool | None = None):
-        import jax
         import jax.numpy as jnp
-        from repro.kernels.ich_spmv.ich_spmv import ich_spmv_sharded
         if self.schedule.n_tiles == 0:
             self.last_costs = self._empty_costs()
             return jnp.zeros((self.n_rows,), jnp.float32)
-        interpret = default_interpret(interpret)
-        if interpret not in self._jitted:
-            self._jitted[interpret] = jax.jit(functools.partial(
-                ich_spmv_sharded, n_rows=self.n_rows, p=self.p,
-                superstep=self.superstep, interpret=interpret))
-        y, self.last_costs = self._jitted[interpret](
-            self.vals, self.cols, self.rowid, self.blkid, x,
+        y, self.last_costs = self._run(
+            interpret, self.vals, self.cols, self.rowid, self.blkid, x,
             slot_cost=self.slot_cost)
         return y
 
@@ -134,94 +181,91 @@ class SpmvOp(_ObservableOp):
 class BfsOp(_ObservableOp):
     """iCh-scheduled BFS: pack the graph once, expand frontiers many times."""
 
+    program = "ich_bfs_step"
+
     def __init__(self, schedule: Schedule, indptr, indices):
-        import jax.numpy as jnp
-        self.schedule = schedule
+        super().__init__(schedule)
         self.n = len(indptr) - 1
-        shards = self.shards = schedule.shard()
-        mask, cols = pack_csr(np.asarray(indptr), np.asarray(indices),
-                              np.ones(len(indices), np.float32),
-                              schedule.tiles,
-                              pad_tiles_to=shards.superstep)
-        self.p = shards.p
-        self.superstep = shards.superstep
-        self.mask = jnp.asarray(mask)
-        self.cols = jnp.asarray(cols)
-        self.rowid = jnp.asarray(shards.shard_item_id(schedule.tiles))
-        self.blkid = jnp.asarray(shards.kernel_block_ids())
-        self.slot_cost = jnp.asarray(
-            _flat_slot_cost(schedule, shards.n_tiles_padded))
-        self.last_costs = None
-        self._jitted = {}  # interpret mode -> jitted step (compile once)
+        self.mask, self.cols = self._bind_csr(
+            indptr, indices, np.ones(len(indices), np.float32))
+
+    def _kernel(self):
+        from repro.kernels.ich_bfs.ich_bfs import ich_bfs_step_sharded
+        return functools.partial(ich_bfs_step_sharded, n_vertices=self.n,
+                                 p=self.p, superstep=self.superstep)
 
     def step(self, frontier, visited, interpret: bool | None = None):
         """One frontier expansion; indicator in, indicator out."""
-        import jax
         import jax.numpy as jnp
-        from repro.kernels.ich_bfs.ich_bfs import ich_bfs_step_sharded
         if self.schedule.n_tiles == 0:
             self.last_costs = self._empty_costs()
             return jnp.zeros((self.n,), jnp.float32)
-        interpret = default_interpret(interpret)
-        if interpret not in self._jitted:
-            self._jitted[interpret] = jax.jit(functools.partial(
-                ich_bfs_step_sharded, n_vertices=self.n, p=self.p,
-                superstep=self.superstep, interpret=interpret))
-        nxt, self.last_costs = self._jitted[interpret](
-            self.mask, self.cols, self.rowid, self.blkid,
-            jnp.asarray(frontier, jnp.float32),
-            jnp.asarray(visited, jnp.float32), slot_cost=self.slot_cost)
+        with obs.span("bfs.send"):
+            frontier = jnp.asarray(frontier, jnp.float32)
+            visited = jnp.asarray(visited, jnp.float32)
+        nxt, self.last_costs = self._run(
+            interpret, self.mask, self.cols, self.rowid, self.blkid,
+            frontier, visited, slot_cost=self.slot_cost)
         return nxt
 
     def levels(self, source: int = 0,
                interpret: bool | None = None) -> np.ndarray:
         """Full traversal: level per vertex (-1 = unreached)."""
-        level = np.full(self.n, -1, np.int32)
-        level[source] = 0
-        frontier = np.zeros(self.n, np.float32)
-        frontier[source] = 1.0
-        visited = frontier.copy()
-        depth = 0
-        while frontier.any():
-            nxt = np.asarray(self.step(frontier, visited, interpret))
-            depth += 1
-            level[nxt > 0] = depth
-            visited = np.maximum(visited, nxt)
-            frontier = nxt
-        return level
+        with obs.span("bfs.levels", source=source):
+            level = np.full(self.n, -1, np.int32)
+            level[source] = 0
+            frontier = np.zeros(self.n, np.float32)
+            frontier[source] = 1.0
+            visited = frontier.copy()
+            depth = 0
+            more = True  # the source is a nonempty first frontier
+            while more:
+                depth += 1
+                with obs.span("bfs.level", depth=depth):
+                    nxt = self.step(frontier, visited, interpret)
+                    with obs.span("bfs.wait"):
+                        nxt = np.asarray(nxt)
+                    with obs.span("bfs.update"):
+                        level[nxt > 0] = depth
+                        visited = np.maximum(visited, nxt)
+                        frontier = nxt
+                        more = frontier.any()
+            return level
 
 
 class KMeansOp(_ObservableOp):
     """iCh-scheduled K-Means assignment over a predicted per-point cost."""
 
+    program = "ich_kmeans_assign"
+
     def __init__(self, schedule: Schedule, costs):
         import jax.numpy as jnp
-        self.schedule = schedule
+        super().__init__(schedule)
         self.sizes = schedule.sizes
         self.n = schedule.n_items
-        shards = self.shards = schedule.shard()
+        with obs.span("op.shard"):
+            shards = self.shards = schedule.shard()
+            rowid = shards.shard_item_id(schedule.tiles)
+            slot_cost = _sharded_slot_cost(schedule, shards)
+        with obs.span("op.upload"):
+            self.rowid = jnp.asarray(rowid)
+            self.slot_cost = jnp.asarray(slot_cost)
         self.p = shards.p
         self.superstep = shards.superstep
-        self.rowid = jnp.asarray(shards.shard_item_id(schedule.tiles))
-        self.slot_cost = jnp.asarray(_sharded_slot_cost(schedule, shards))
-        self.last_costs = None
-        self._jitted = {}  # interpret mode -> jitted assign (compile once)
 
-    def __call__(self, points, centroids, interpret: bool | None = None):
-        import jax
-        import jax.numpy as jnp
+    def _kernel(self):
         from repro.kernels.ich_kmeans.ich_kmeans import \
             ich_kmeans_assign_sharded
+        return functools.partial(ich_kmeans_assign_sharded, p=self.p,
+                                 superstep=self.superstep)
+
+    def __call__(self, points, centroids, interpret: bool | None = None):
+        import jax.numpy as jnp
         if self.schedule.n_tiles == 0:
             self.last_costs = self._empty_costs()
             return jnp.zeros((self.n,), jnp.int32)
-        interpret = default_interpret(interpret)
-        if interpret not in self._jitted:
-            self._jitted[interpret] = jax.jit(functools.partial(
-                ich_kmeans_assign_sharded, p=self.p,
-                superstep=self.superstep, interpret=interpret))
-        assign, self.last_costs = self._jitted[interpret](
-            jnp.asarray(points, jnp.float32),
+        assign, self.last_costs = self._run(
+            interpret, jnp.asarray(points, jnp.float32),
             jnp.asarray(centroids, jnp.float32), self.rowid,
             slot_cost=self.slot_cost)
         return assign
@@ -241,34 +285,25 @@ class MoeDispatchOp(_ObservableOp):
     that `repro.sched.moe.refine_cap_scale` folds into the next step's
     capacity scale."""
 
+    program = "ich_moe"
+    last_expert_costs = None  # (p, E) from the latest invocation
+
     def __init__(self, schedule: Schedule, plan):
-        import jax.numpy as jnp
-        self.schedule = schedule
+        super().__init__(schedule)
         self.plan = plan
         self.n_tokens = plan.n_tokens
         self.n_experts = plan.n_experts
-        shards = self.shards = schedule.shard()
-        indptr, tok, w = plan.csr()
-        vals, cols = pack_csr(indptr, tok, w, schedule.tiles,
-                              pad_tiles_to=shards.superstep)
-        self.p = shards.p
-        self.superstep = shards.superstep
-        self.vals = jnp.asarray(vals)
-        self.cols = jnp.asarray(cols)
-        self.rowid = jnp.asarray(shards.shard_item_id(schedule.tiles))
-        self.blkid = jnp.asarray(shards.kernel_block_ids())
-        self.slot_cost = jnp.asarray(
-            _flat_slot_cost(schedule, shards.n_tiles_padded))
-        self.last_costs = None
-        self.last_expert_costs = None  # (p, E) from the latest invocation
-        self._jitted = {}  # interpret mode -> jitted apply (compile once)
+        self.vals, self.cols = self._bind_csr(*plan.csr())
+
+    def _kernel(self):
+        from repro.kernels.ich_moe.ich_moe import ich_moe_sharded
+        return functools.partial(ich_moe_sharded, p=self.p,
+                                 superstep=self.superstep)
 
     def __call__(self, x, wi, wg, wo, interpret: bool | None = None):
         """Apply the planned dispatch: x (n_tokens, D) token activations,
         wi/wg (E, D, F), wo (E, F, D). Returns y (n_tokens, D)."""
-        import jax
         import jax.numpy as jnp
-        from repro.kernels.ich_moe.ich_moe import ich_moe_sharded
         # n_tokens == 0 also short-circuits: a zero-admission plan still
         # carries one tile per (zero-count) expert, but the kernel's token
         # gather has no source rows to read
@@ -277,14 +312,9 @@ class MoeDispatchOp(_ObservableOp):
             self.last_expert_costs = jnp.zeros(
                 (self.p, self.n_experts), jnp.float32)
             return jnp.zeros((self.n_tokens, x.shape[-1]), x.dtype)
-        interpret = default_interpret(interpret)
-        if interpret not in self._jitted:
-            self._jitted[interpret] = jax.jit(functools.partial(
-                ich_moe_sharded, p=self.p, superstep=self.superstep,
-                interpret=interpret))
-        y, self.last_costs, self.last_expert_costs = self._jitted[interpret](
-            self.vals, self.cols, self.rowid, self.blkid, x, wi, wg, wo,
-            slot_cost=self.slot_cost)
+        y, self.last_costs, self.last_expert_costs = self._run(
+            interpret, self.vals, self.cols, self.rowid, self.blkid, x, wi,
+            wg, wo, slot_cost=self.slot_cost)
         return y
 
     def expert_load(self) -> np.ndarray:
