@@ -7,7 +7,9 @@ cost per K-Means point), we
 
 1. pick a tile width W with the paper's variance band (eqs. 1-3, 8):
    W = pow2-roundup of mu * (1 + eps), so every "normal"-classified item fits
-   in one segment (`ich_tile_width`);
+   in one segment (`ich_tile_width`). The band is the upper bound: a
+   workload whose payload is gathered over every packed slot (SpMV, BFS)
+   takes the cheapest power of two under it (`gather_width`);
 2. split items wider than W into W-sized segments (`split_items`) — the
    work-stealing analogue: a heavy item's overflow migrates to later tiles
    exactly like stolen iterations;
@@ -107,6 +109,40 @@ def ich_tile_width(sizes: np.ndarray, eps: float = ICH_EPS,
     upper = mu * (1.0 + eps)
     w = 2 ** int(np.ceil(np.log2(max(upper, 1.0))))
     return int(min(max(w, min_w), max_w))
+
+
+# Device cost of folding one packed segment, in gathered slots. On a TPU v5e
+# the sharded kernel folds a segment in 6.44 ns (SpMV) and 6.32 ns (BFS),
+# and XLA's gather before it costs 8.58 and 8.61 ns a packed slot, padding
+# included (PERF.md §5): a segment costs about 0.75 of a slot.
+FOLD_SLOTS = 0.75
+
+
+def gather_width(sizes: np.ndarray, eps: float = ICH_EPS, min_w: int = 8,
+                 max_w: int = 512, rows_per_tile: int = 8) -> int:
+    """Pick the tile width for a payload gathered over every packed slot.
+
+    SpMV's x[cols] and BFS's frontier[cols] run over the whole (T, R, W)
+    array, padding slots included, so a width costs about
+    (W + FOLD_SLOTS) * S(W): one gather per slot, one fold per segment,
+    where S(W) is the segment count `build_schedule` pads to whole tiles
+    of `rows_per_tile`. Of the powers of two from `min_w` up to the band's
+    width (`ich_tile_width`, the upper bound), the cheapest wins; ties go
+    to the wider. Items count by their distinct sizes, so the candidates
+    cost one `np.unique` over the sizes and no pass per candidate.
+    """
+    band = ich_tile_width(sizes, eps, min_w, max_w)
+    lengths, counts = np.unique(np.asarray(sizes, np.int64),
+                                return_counts=True)
+    R = int(rows_per_tile)
+
+    def cost(w: int) -> float:
+        segs = int((counts * np.maximum(-(-lengths // w), 1)).sum())
+        return (w + FOLD_SLOTS) * (-(-segs // R) * R)
+
+    widths = {band} | {1 << k for k in range(band.bit_length())
+                       if min_w <= 1 << k <= band}
+    return min(sorted(widths, reverse=True), key=cost)
 
 
 def split_items(

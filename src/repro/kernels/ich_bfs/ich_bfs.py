@@ -6,8 +6,10 @@ current frontier and u is unvisited. Per-vertex cost = degree, the paper's
 BFS workload (§5.1): most vertices are trivial, frontier-adjacent ones heavy.
 
 The schedule is constructed once per graph by `core.tiling` (DESIGN.md §2):
-band-picked width W over the degree distribution, heavy adjacency lists
-split across W-wide segments, segments greedily packed into (T, R) slots.
+a width W under the band of the degree distribution (`gather_width`: the
+frontier gather below walks every packed slot, so the registry op takes the
+cheapest power of two up to the band's width), heavy adjacency lists split
+across W-wide segments, segments greedily packed into (T, R) slots.
 `mask` is the all-ones CSR payload from `pack_csr` — 1.0 on real edge slots,
 0.0 on padding — so a padded slot can never observe frontier[cols==0].
 

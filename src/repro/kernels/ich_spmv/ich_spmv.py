@@ -2,11 +2,14 @@
 
 TPU adaptation (DESIGN.md §2): a TPU grid is static, so iCh's *runtime*
 chunk adaptation becomes *schedule construction*. The host packs CSR rows
-into fixed-shape work tiles (R rows x W nnz slots) where the tile width W is
-chosen by the paper's band classification over the row-nnz distribution
-(`ich_tile_width`), and rows whose nnz exceeds W are SPLIT across several
-tiles — the work-stealing analogue: no tile (chunk) can be overloaded, heavy
-rows' overflow migrates to later tiles exactly like stolen iterations.
+into fixed-shape work tiles (R rows x W nnz slots), and rows whose nnz
+exceeds W are SPLIT across several tiles — the work-stealing analogue: no
+tile (chunk) can be overloaded, heavy rows' overflow migrates to later tiles
+exactly like stolen iterations. The paper's band classification over the
+row-nnz distribution (`ich_tile_width`) bounds W from above; the registry
+op (`sched.build("spmv", ...)`) takes the cheapest power of two under it
+(`core.tiling.gather_width`), since the gather below walks every packed
+slot, padding included. `pack_tiles` packs at the band unless given W.
 
 Two grids run the same segmented reduction (`core/segmented.py`):
 

@@ -83,8 +83,10 @@ class Schedule:
     # True when sizes describe a payload layout (CSR nnz / degrees) that
     # refine() must keep; False when they are quantized cost estimates
     structural_sizes: bool = True
-    # construction parameters refine() rebuilds with (None width = re-band)
+    # construction parameters refine() rebuilds with (None width = the
+    # width rule's, None rule = re-band)
     width_arg: Optional[int] = None
+    width_rule: Optional[Callable[..., int]] = None
     band_eps: float = ICH_EPS
     # lazily-created CostRefiner lives here (frozen dataclass; same benign
     # setdefault race as _shards)
@@ -442,18 +444,21 @@ class Schedule:
                 provider, policy=self.policy, p=self.p,
                 rows_per_tile=self.rows_per_tile, width=self.width_arg,
                 eps=self.band_eps, superstep=self.superstep,
-                _generation=self.generation + 1)
+                _generation=self.generation + 1,
+                _width_rule=self.width_rule)
         else:  # hand-assembled schedule: rebuild directly, no cache
+            width = _tile_width(self.width_arg, self.width_rule,
+                                provider.sizes(), self.band_eps, MIN_WIDTH,
+                                MAX_WIDTH, self.rows_per_tile)
             if self.backend == "jax":
                 from repro.core import tiling_jax as TJ
                 tiles = TJ.build_schedule_jax(
                     provider.sizes(), rows_per_tile=self.rows_per_tile,
-                    width=self.width_arg, eps=self.band_eps).to_host()
+                    width=width, eps=self.band_eps).to_host()
             else:
                 tiles = T.build_schedule(provider.sizes(),
                                          rows_per_tile=self.rows_per_tile,
-                                         width=self.width_arg,
-                                         eps=self.band_eps)
+                                         width=width, eps=self.band_eps)
             new = dataclasses.replace(
                 self, sizes=provider.sizes(), costs=provider.costs(),
                 tiles=tiles, generation=self.generation + 1,
@@ -579,6 +584,14 @@ class Schedule:
                               sleep_fn=sleep_fn)
 
 
+def _tile_width(width, rule, sizes, eps, min_w, max_w,
+                rows_per_tile) -> Optional[int]:
+    """An explicit width, else the rule's, else None (the band)."""
+    if width is not None or rule is None:
+        return width
+    return rule(sizes, eps, min_w, max_w, rows_per_tile)
+
+
 class LoopScheduler:
     """Facade over policies, simulator, executor, and Pallas lowering.
 
@@ -623,7 +636,9 @@ class LoopScheduler:
                  width: Optional[int] = None,
                  eps: Optional[float] = None,
                  superstep: Optional[int] = None,
-                 _generation: int = 0) -> Schedule:
+                 _generation: int = 0,
+                 _width_rule: Optional[Callable[..., int]] = None
+                 ) -> Schedule:
         """Construct (or fetch from cache) the schedule for `costs`.
 
         `costs` is a `CostProvider` or a bare per-item array
@@ -640,15 +655,17 @@ class LoopScheduler:
         p values don't collide). It also includes the refinement
         GENERATION (`_generation`, set by `Schedule.refine`): a refined
         schedule's lowerings are always freshly keyed, never a stale
-        entry's (sched/cache.py).
+        entry's (sched/cache.py). `_width_rule` is a registered workload's
+        tile-width rule (`build`; `refine` keeps it).
         """
         with obs.span("sched.schedule"):
             return self._schedule(as_cost_provider(costs), policy, p,
                                   rows_per_tile, width, eps, superstep,
-                                  _generation)
+                                  _generation, _width_rule)
 
     def _schedule(self, provider: CostProvider, policy, p, rows_per_tile,
-                  width, eps, superstep, _generation) -> Schedule:
+                  width, eps, superstep, _generation,
+                  width_rule) -> Schedule:
         pol = policy if policy is not None else self.policy
         pp = int(p if p is not None else self.p)
         rpt = int(rows_per_tile if rows_per_tile is not None
@@ -660,6 +677,9 @@ class LoopScheduler:
         # absent a declaration, sizes count as structural: keeping them
         # across refinement is always payload-safe (see sched/costs.py)
         structural = bool(getattr(provider, "sizes_are_structural", True))
+        # an explicit width wins over the rule; the key holds the rule, not
+        # the width it resolves to, so a hit never scans the sizes
+        rule = width_rule if width is None else None
         # the policy keys as the full (frozen, hashable) dataclass, not just
         # label(): labels are lossy — taskloop's drops num_tasks, pretiled's
         # drops the actual ranges — and would alias distinct policies onto
@@ -668,29 +688,31 @@ class LoopScheduler:
         # lowerings (device_lowering) a "numpy"-facade caller never asked
         # to pin, and the two construction pipelines must stay separately
         # attributable even though their tiles are element-identical
-        key = (provider.fingerprint(), pol, pp, rpt, width,
+        key = (provider.fingerprint(), pol, pp, rpt, width, rule,
                band_eps, self.min_w, self.max_w, sstep, gen, self.backend)
 
         def build() -> Schedule:
             with obs.span("sched.construct"):
                 sizes = provider.sizes()
+                w = _tile_width(width, rule, sizes, band_eps, self.min_w,
+                                self.max_w, rpt)
                 if self.backend == "jax":
                     from repro.core import tiling_jax as TJ
                     tiles = TJ.build_schedule_jax(
-                        sizes, rows_per_tile=rpt, width=width,
+                        sizes, rows_per_tile=rpt, width=w,
                         eps=band_eps, min_w=self.min_w,
                         max_w=self.max_w).to_host()
                 else:
                     tiles = T.build_schedule(
-                        sizes, rows_per_tile=rpt, width=width,
+                        sizes, rows_per_tile=rpt, width=w,
                         eps=band_eps, min_w=self.min_w, max_w=self.max_w)
                 return Schedule(
                     sizes=sizes, costs=provider.costs(), policy=pol, p=pp,
                     tiles=tiles, sim_params=self.sim_params,
                     superstep=sstep, generation=gen,
                     structural_sizes=structural, width_arg=width,
-                    band_eps=band_eps, backend=self.backend,
-                    _scheduler=self)
+                    width_rule=rule, band_eps=band_eps,
+                    backend=self.backend, _scheduler=self)
 
         if self.cache is None:
             return build()
@@ -707,6 +729,8 @@ class LoopScheduler:
         Looks up `workload` in the registry (`sched.register` /
         `sched.get`), derives its cost provider from `inputs`, routes the
         schedule through the cache, and hands both to the entry's builder.
+        The tile width follows the entry's width rule (the band where it
+        declares none) unless `width` pins it.
         """
         from . import registry
         with obs.span("sched.build", workload=workload):
@@ -715,7 +739,8 @@ class LoopScheduler:
             # schedule's cost, so inside its span
             with obs.span("sched.schedule"):
                 s = self._schedule(entry.costs(*inputs), policy, p,
-                                   rows_per_tile, width, eps, superstep, 0)
+                                   rows_per_tile, width, eps, superstep, 0,
+                                   entry.width)
             return entry.build(s, *inputs)
 
     # --------------------------------------------- direct backend shortcuts

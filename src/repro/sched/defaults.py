@@ -21,7 +21,8 @@ ICH_EPS = 0.33
 # a heavy item (DESIGN.md §2.5).
 ROWS_PER_TILE = 8
 
-# Tile-width clamp for `ich_tile_width` (work units per segment slot).
+# Tile-width clamp for `ich_tile_width` and `gather_width` (work units per
+# segment slot).
 MIN_WIDTH = 8
 MAX_WIDTH = 512
 

@@ -1,7 +1,11 @@
 """Registry-backed kernel ops for the three paper applications.
 
 Each op binds a constructed `Schedule` to a workload's payloads once
-(pack), then applies the Pallas kernel many times. These are the
+(pack), then applies the Pallas kernel many times. SpMV and BFS gather
+their payload (vals * x[cols], mask * frontier[cols]) over every packed
+slot, padding included, so they register `core.tiling.gather_width`: the
+cheapest width under the band. K-Means, MoE dispatch and serve-prefill
+keep the band's width. These are the
 implementations behind `scheduler.build("spmv" | "bfs" | "kmeans", ...)`;
 the legacy `IChSpmv` / `IChBfs` / `IChKMeans` classes under
 `repro/kernels/ich_*/ops.py` are deprecation shims over this module.
@@ -42,7 +46,7 @@ import functools
 import numpy as np
 
 from repro import obs
-from repro.core.tiling import pack_csr
+from repro.core.tiling import gather_width, pack_csr
 from repro.kernels import default_interpret
 
 from .api import Schedule
@@ -331,12 +335,14 @@ register(
     "spmv",
     costs=lambda indptr, indices, data: NnzCosts(indptr),
     build=SpmvOp,
-    doc="Segmented CSR SpMV; inputs (indptr, indices, data); cost = row nnz.")
+    doc="Segmented CSR SpMV; inputs (indptr, indices, data); cost = row nnz.",
+    width=gather_width)
 register(
     "bfs",
     costs=lambda indptr, indices: DegreeCosts(indptr),
     build=BfsOp,
-    doc="Pull-direction BFS; inputs (indptr, indices); cost = in-degree.")
+    doc="Pull-direction BFS; inputs (indptr, indices); cost = in-degree.",
+    width=gather_width)
 register(
     "kmeans",
     # float64 coercion keeps the provider on its quantizing path (ceil, >= 1

@@ -6,7 +6,11 @@ A workload is two functions (DESIGN.md §3):
   from the workload's raw inputs (numpy-only; runs before any jax import);
 * ``build(schedule, *inputs) -> op`` — given the constructed `Schedule`
   and the same raw inputs, return the callable kernel op (this side may
-  import jax/Pallas).
+  import jax/Pallas);
+
+and, optionally, the rule its tile width follows: ``width=None`` is the
+paper's band (`core.tiling.ich_tile_width`); a workload whose payload is
+gathered over every packed slot declares `core.tiling.gather_width`.
 
 Example — registering a custom workload:
 
@@ -25,19 +29,22 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-from typing import Any, Callable
+from typing import Any, Callable, Optional
 
 from .costs import CostProvider
 
 
 @dataclasses.dataclass(frozen=True)
 class WorkloadSpec:
-    """A registered workload: name + cost derivation + kernel-op builder."""
+    """A registered workload: name + cost derivation + kernel-op builder,
+    and its tile-width rule ``width(sizes, eps, min_w, max_w,
+    rows_per_tile) -> W`` (None: the band)."""
 
     name: str
     costs: Callable[..., CostProvider]
     build: Callable[..., Any]
     doc: str = ""
+    width: Optional[Callable[..., int]] = None
 
 
 _REGISTRY: dict[str, WorkloadSpec] = {}
@@ -65,6 +72,7 @@ def _load_builtins() -> None:
 
 def register(name: str, *, costs: Callable[..., CostProvider],
              build: Callable[..., Any], doc: str = "",
+             width: Optional[Callable[..., int]] = None,
              overwrite: bool = False) -> WorkloadSpec:
     """Register a workload under `name`; returns the spec.
 
@@ -77,7 +85,8 @@ def register(name: str, *, costs: Callable[..., CostProvider],
     # "kmeans" collides HERE (clear error at the offending call) instead of
     # blowing up the built-in import inside every later get()
     _load_builtins()
-    spec = WorkloadSpec(name=name, costs=costs, build=build, doc=doc)
+    spec = WorkloadSpec(name=name, costs=costs, build=build, doc=doc,
+                        width=width)
     with _LOCK:
         if name in _REGISTRY and not overwrite:
             raise ValueError(

@@ -1,12 +1,14 @@
 """Tests for the unified `repro.sched` API: facade, cost providers,
 registry, schedule cache, cross-backend round-trips, and the legacy-ops
 deprecation shims."""
+import contextlib
 import threading
 import warnings
 
 import numpy as np
 import pytest
 from conftest import random_csr as _random_csr
+from conftest import skewed_csr
 
 from repro import sched
 from repro.core import policies as P
@@ -269,6 +271,131 @@ def test_registry_register_and_duplicate_rejection():
         unregister("test_wl")
     with pytest.raises(KeyError, match="unknown workload"):
         sched.get("test_wl")
+
+
+# --------------------------------------------------------- width rules
+def _skewed_csr():
+    return skewed_csr(3_000, 12, hubs=[(5, 2_000), (6, 2_000)])
+
+
+def _gather_w(sizes):
+    return T.gather_width(sizes, sched.ICH_EPS, sched.MIN_WIDTH,
+                          sched.MAX_WIDTH, sched.ROWS_PER_TILE)
+
+
+@pytest.mark.parametrize("workload", ["spmv", "bfs"])
+def test_gathered_workloads_take_the_gather_width(workload):
+    indptr, indices, data = _skewed_csr()
+    inputs = (indptr, indices, data)[:3 if workload == "spmv" else 2]
+    s = LoopScheduler(p=2).build(workload, *inputs).schedule
+    assert s.width == _gather_w(s.sizes) == 8
+    assert T.ich_tile_width(s.sizes) == 32
+    assert s.width_rule is T.gather_width
+    direct = T.build_schedule(s.sizes, rows_per_tile=sched.ROWS_PER_TILE,
+                              width=8)
+    np.testing.assert_array_equal(s.item_id, direct.item_id)
+    np.testing.assert_array_equal(s.lower().seg_len, direct.seg_len)
+
+
+def _band_inputs(workload):
+    rng = np.random.default_rng(13)
+    skewed = np.diff(skewed_csr(500, 13, hubs=[(0, 2_000), (1, 2_000)])[0])
+    skewed = skewed + 1.0
+    if workload == "moe-dispatch":
+        # 16 experts, top-2 routing skewed towards the low ids
+        e = np.minimum(rng.zipf(1.3, (4_000, 2)) - 1, 15)
+        e[:, 1] = (e[:, 0] + 1 + rng.integers(0, 15, 4_000)) % 16
+        return (sched.plan_dispatch(e, cap=np.full(16, 10_000)),)
+    if workload == "serve-prefill":
+        return (skewed.astype(np.int64),)
+    return (skewed,)  # kmeans costs, and raw schedule() costs
+
+
+@pytest.mark.parametrize("workload", ["kmeans", "moe-dispatch",
+                                      "serve-prefill", "schedule()"])
+def test_other_workloads_keep_the_band_width(workload):
+    """Element-identical to the band's schedule, on sizes where the
+    gather rule would pick another width."""
+    inputs = _band_inputs(workload)
+    scheduler = LoopScheduler(p=2)
+    if workload == "schedule()":
+        s = scheduler.schedule(*inputs)
+    else:
+        out = scheduler.build(workload, *inputs)
+        s = out if isinstance(out, Schedule) else out.schedule
+    band = T.build_schedule(s.sizes, rows_per_tile=sched.ROWS_PER_TILE,
+                            eps=sched.ICH_EPS)
+    assert s.width == band.width == T.ich_tile_width(s.sizes)
+    assert s.width_rule is None
+    assert _gather_w(s.sizes) != band.width
+    for a, b in [(s.item_id, band.item_id),
+                 (s.lower().seg_start, band.seg_start),
+                 (s.lower().seg_len, band.seg_len)]:
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("workload", ["spmv", "bfs"])
+@pytest.mark.parametrize("width", [16, 32, 64])
+def test_explicit_width_wins_over_the_width_rule(workload, width):
+    indptr, indices, data = _skewed_csr()
+    inputs = (indptr, indices, data)[:3 if workload == "spmv" else 2]
+    scheduler = LoopScheduler(p=2)
+    s = scheduler.build(workload, *inputs, width=width).schedule
+    assert s.width == width and s.width_arg == width
+    # and a pinned width is its own cache entry, apart from the rule's
+    assert scheduler.build(workload, *inputs).schedule.width == 8
+
+
+def test_repeated_build_is_a_cache_hit_that_constructs_nothing(monkeypatch):
+    from repro import obs
+    names = []
+
+    def span(name, **_):
+        names.append(name)
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(obs, "span", span)
+    indptr, indices, data = _skewed_csr()
+    scheduler = LoopScheduler(p=2)
+    first = scheduler.build("spmv", indptr, indices, data).schedule
+    assert names.count("sched.construct") == 1
+    names.clear()
+    again = scheduler.build("spmv", indptr, indices, data * 2.0).schedule
+    assert again is first and again.width == 8
+    assert "sched.construct" not in names
+    assert scheduler.cache_stats.hits == 1
+
+
+def test_refine_keeps_the_width_rule():
+    indptr, indices, data = _skewed_csr()
+    scheduler = LoopScheduler(p=2)
+    s = scheduler.build("spmv", indptr, indices, data).schedule
+    measured = s.costs * np.random.default_rng(14).uniform(0.5, 2.0,
+                                                           s.n_items)
+    r = s.observe(measured, level="item").refine()
+    assert r.generation == 1 and r.width_rule is T.gather_width
+    assert r.width == s.width == 8
+    np.testing.assert_array_equal(r.item_id, s.item_id)  # structural sizes
+    # a schedule with no scheduler behind it rebuilds with the rule too
+    import dataclasses
+    bare = dataclasses.replace(s, _scheduler=None, _feedback={})
+    assert bare.observe(measured, level="item").refine().width == 8
+
+
+def test_refine_reapplies_the_width_rule_to_refined_sizes():
+    """Where sizes are quantized cost estimates, refine() re-derives them,
+    and the rule picks the width of the new sizes."""
+    try:
+        register("test_gathered", costs=lambda c: ExplicitCosts(c),
+                 build=lambda s, c: s, width=T.gather_width)
+        costs = np.full(400, 16.0)
+        s = LoopScheduler(p=2).build("test_gathered", costs)
+        assert s.width == 16
+        r = s.observe(np.full(400, 64.0), level="item").refine()
+        assert r.width_rule is T.gather_width
+        assert r.width == _gather_w(r.sizes) == 64
+    finally:
+        unregister("test_gathered")
 
 
 def test_schedule_equality_is_identity():

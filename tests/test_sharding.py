@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 from conftest import random_csr as _random_csr
+from conftest import skewed_csr
 
 from repro.core import policies as P
 from repro.core import tiling as T
@@ -195,27 +196,50 @@ def test_pack_csr_gather_fallback_matches_reference():
 
 
 # ------------------------------------------- sharded kernel bit-identity
+HUB = 600  # nonzeros of the hub row: 75 segments at W = 8
+
+
+def _csr_case(case, n, seed):
+    """(indptr, indices, data, width): the zipf matrix at the band's width,
+    or a lognormal body (band 32) with one hub row of HUB nonzeros at the
+    gather rule's W = 8, where the hub splits over about ten tiles."""
+    if case == "zipf_band":
+        return (*_random_csr(n, seed=seed), None)
+    return (*skewed_csr(n, seed, hubs=[(n // 3, HUB)]), 8)
+
+
+def _check_hub_split(case, s, n):
+    if case == "hub_w8":
+        assert s.width == 8
+        assert (s.item_id == n // 3).any(axis=1).sum() >= HUB // (8 * 8)
+
+
 def _shard_args(s, B):
     shards = s.shard(superstep=B)
     return (shards, shards.shard_item_id(s.tiles),
             shards.kernel_block_ids())
 
 
+@pytest.mark.parametrize("case", ["zipf_band", "hub_w8"])
 @pytest.mark.parametrize("p", [1, 2, 4])
-def test_sharded_spmv_bit_identical_to_sequential_grid(p):
+def test_sharded_spmv_bit_identical_to_sequential_grid(p, case):
     import jax.numpy as jnp
     from repro.kernels.ich_spmv.ich_spmv import ich_spmv, ich_spmv_sharded
+    from repro.kernels.ich_spmv.ref import spmv_ref
 
     rng = np.random.default_rng(p)
     n = 180
-    indptr, indices, data = _random_csr(n, seed=p)
+    indptr, indices, data, width = _csr_case(case, n, p)
     x = rng.standard_normal(n).astype(np.float32)
     scheduler = LoopScheduler(p=p, cache_size=0)
-    s = scheduler.schedule(np.diff(indptr))
+    s = scheduler.schedule(np.diff(indptr), width=width)
+    _check_hub_split(case, s, n)
     vals, cols = T.pack_csr(indptr, indices, data, s.tiles)
     y_seq = np.asarray(ich_spmv(jnp.asarray(vals), jnp.asarray(cols),
                                 jnp.asarray(s.item_id), jnp.asarray(x), n,
                                 interpret=True))
+    np.testing.assert_allclose(y_seq, spmv_ref(indptr, indices, data, x),
+                               atol=1e-4, rtol=1e-4)
     for B in (1, 4, 8):
         shards, rid, blk = _shard_args(s, B)
         vp, cp = T.pack_csr(indptr, indices, data, s.tiles, pad_tiles_to=B)
@@ -225,17 +249,19 @@ def test_sharded_spmv_bit_identical_to_sequential_grid(p):
         np.testing.assert_array_equal(y_sh, y_seq)  # bitwise, fp add order
 
 
+@pytest.mark.parametrize("case", ["zipf_band", "hub_w8"])
 @pytest.mark.parametrize("p", [1, 2, 4])
-def test_sharded_bfs_bit_identical_to_sequential_grid(p):
+def test_sharded_bfs_bit_identical_to_sequential_grid(p, case):
     import jax.numpy as jnp
     from repro.kernels.ich_bfs.ich_bfs import (ich_bfs_step,
                                                ich_bfs_step_sharded)
 
     rng = np.random.default_rng(20 + p)
     n = 160
-    indptr, indices, _ = _random_csr(n, seed=20 + p)
+    indptr, indices, _, width = _csr_case(case, n, 20 + p)
     scheduler = LoopScheduler(p=p, cache_size=0)
-    s = scheduler.schedule(np.diff(indptr))
+    s = scheduler.schedule(np.diff(indptr), width=width)
+    _check_hub_split(case, s, n)
     ones = np.ones(int(indptr[-1]), np.float32)
     mask, cols = T.pack_csr(indptr, indices, ones, s.tiles)
     frontier = (rng.random(n) < 0.08).astype(np.float32)
@@ -279,17 +305,20 @@ def test_sharded_kmeans_bit_identical_to_sequential_grid(p):
         np.testing.assert_array_equal(a_sh, a_seq)
 
 
-def test_registry_ops_run_sharded_and_match_refs():
+@pytest.mark.parametrize("case", ["zipf_band", "hub_w8"])
+def test_registry_ops_run_sharded_and_match_refs(case):
     """The registry ops (the production path) execute the sharded kernels
-    at the schedule's p and still match the numpy oracles."""
+    at the schedule's p and still match the numpy oracles; on the hub
+    matrix the gather rule gives them W = 8."""
     from repro.kernels.ich_bfs.ref import bfs_levels_ref
     from repro.kernels.ich_spmv.ref import spmv_ref
 
     rng = np.random.default_rng(8)
     n = 140
-    indptr, indices, data = _random_csr(n, seed=8)
+    indptr, indices, data, _ = _csr_case(case, n, 8)
     scheduler = LoopScheduler(p=4, cache_size=0)
     spmv = scheduler.build("spmv", indptr, indices, data)
+    _check_hub_split(case, spmv.schedule, n)
     assert spmv.p == 4
     assert spmv.vals.shape[0] % spmv.superstep == 0  # whole supersteps
     x = rng.standard_normal(n).astype(np.float32)
@@ -297,6 +326,7 @@ def test_registry_ops_run_sharded_and_match_refs():
                                spmv_ref(indptr, indices, data, x),
                                atol=1e-4, rtol=1e-4)
     bfs = scheduler.build("bfs", indptr, indices)
+    _check_hub_split(case, bfs.schedule, n)
     np.testing.assert_array_equal(bfs.levels(0, interpret=True),
                                   bfs_levels_ref(indptr, indices, 0))
 

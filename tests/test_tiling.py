@@ -1,13 +1,15 @@
 """Tests for the shared iCh schedule-construction layer (core/tiling.py)."""
 import numpy as np
 import pytest
+from conftest import skewed_csr
 
 from repro.core import policies as P
 from repro.core.simulator import simulate
 from repro.core.tiling import (
-    _reference_build_schedule, _reference_coverage_counts,
+    FOLD_SLOTS, _reference_build_schedule, _reference_coverage_counts,
     _reference_pack_csr, _reference_split_items,
-    build_schedule, coverage_counts, ich_tile_width, pack_csr, split_items,
+    build_schedule, coverage_counts, gather_width, ich_tile_width, pack_csr,
+    split_items,
 )
 
 
@@ -102,6 +104,74 @@ def test_width_band_monotone_and_clamped():
     # monotone in eps (wider band -> wider tiles)
     rows = np.random.default_rng(1).integers(1, 100, 500)
     assert ich_tile_width(rows, eps=0.5) >= ich_tile_width(rows, eps=0.25)
+
+
+# ------------------------------------------- width of gathered payloads
+def _lognormal_hubs():
+    """The shape of a web matrix's row lengths, with three hub rows."""
+    hubs = [(0, 6_000), (1, 6_000), (2, 6_000)]
+    return np.diff(skewed_csr(20_000, 3, hubs=hubs)[0])
+
+
+def _kronecker_like(seed=4):
+    """Zipf degrees with 40% isolated vertices: median 1, mean ~29."""
+    rng = np.random.default_rng(seed)
+    deg = np.minimum(rng.zipf(1.6, 16_384), 4_000).astype(np.int64)
+    deg[rng.random(deg.size) < 0.4] = 0
+    return deg
+
+
+GATHER_PROFILES = {
+    "lognormal_hubs": _lognormal_hubs,
+    "kronecker_like": _kronecker_like,
+    "zipf_empty": lambda: _random_sizes(3_000, 1.7, 8),
+    "uniform_small": lambda: np.random.default_rng(9).integers(0, 40, 999),
+    "one_wide_item": lambda: np.array([10_000], np.int64),
+    "empty": lambda: np.zeros(0, np.int64),
+}
+
+
+@pytest.mark.parametrize("profile", ["lognormal_hubs", "kronecker_like"])
+def test_gather_width_is_narrowest_on_skewed_profiles(profile):
+    sizes = GATHER_PROFILES[profile]()
+    assert ich_tile_width(sizes) >= 32  # the band pads most rows
+    assert gather_width(sizes) == 8
+
+
+@pytest.mark.parametrize("k", [3, 4, 5, 6, 7, 8, 9])
+def test_gather_width_uniform_rows_take_their_length(k):
+    sizes = np.full(64, 2 ** k, np.int64)
+    assert gather_width(sizes) == 2 ** k
+
+
+@pytest.mark.parametrize("min_w,max_w", [(8, 512), (16, 512), (8, 16),
+                                         (4, 64)])
+@pytest.mark.parametrize("profile", sorted(GATHER_PROFILES))
+def test_gather_width_lies_between_the_floor_and_the_band(profile, min_w,
+                                                         max_w):
+    sizes = GATHER_PROFILES[profile]()
+    w = gather_width(sizes, min_w=min_w, max_w=max_w)
+    assert min_w <= w <= ich_tile_width(sizes, min_w=min_w, max_w=max_w)
+
+
+@pytest.mark.parametrize("R", [4, 8, 16])
+@pytest.mark.parametrize("profile", sorted(GATHER_PROFILES))
+def test_gather_width_is_the_brute_force_argmin(profile, R):
+    """The rule's segment count from distinct sizes equals the tiles that
+    `build_schedule` makes: the argmin over built schedules agrees (ties
+    to the wider), and every work unit is covered once at the width."""
+    sizes = GATHER_PROFILES[profile]()
+    band = ich_tile_width(sizes)
+    widths = [w for w in (512, 256, 128, 64, 32, 16, 8) if w <= band]
+
+    def cost(w):
+        tiles = build_schedule(sizes, rows_per_tile=R, width=w)
+        return (w + FOLD_SLOTS) * tiles.n_tiles * R
+
+    best = min(widths, key=cost)
+    assert gather_width(sizes, rows_per_tile=R) == best
+    sched = build_schedule(sizes, rows_per_tile=R, width=best)
+    assert (coverage_counts(sched, sizes) == 1).all()
 
 
 def test_split_items_orders_segments_by_item():

@@ -28,7 +28,7 @@ R = 8  # sched.defaults.ROWS_PER_TILE
 
 # chip_smoke.py's schedules at --seed 0: (width W, padded tiles T_pad, and
 # supersteps per worker S_B at p=1 and p=4)
-SPMV = dict(n=3_566_907, W=32, T_pad=481_632, n_steps={1: 60_204, 4: 15_181})
+SPMV = dict(n=3_566_907, W=8, T_pad=902_120, n_steps={1: 112_765, 4: 28_198})
 BFS = dict(n=1 << 21, W=8, T_pad=296_232, n_steps={1: 37_029, 4: 9_290})
 KMEANS = dict(n=494_020, D=34, K=5, n_steps={1: 8_871, 4: 2_218})
 
