@@ -44,20 +44,6 @@ from .welford import (Welford, WelfordVec, adapt_d, classify, ich_band,
                       steal_merge, LOW, NORMAL, HIGH)
 from .executor import parallel_for, ExecStats
 
-# The segmented kernel epilogue (core/segmented.py) is the one core module
-# that needs jax/pallas; it is re-exported lazily (PEP 562) so the
-# numpy-only core — simulator sweeps, host-side schedule construction —
-# keeps importing without paying the jax import.
-_SEGMENTED_EXPORTS = frozenset(
-    {"segment_max", "segment_sum", "segmented_apply", "slot_window"})
-
-
-def __getattr__(name):
-    if name in _SEGMENTED_EXPORTS:
-        from . import segmented
-        return getattr(segmented, name)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
-
 __all__ = [
     "Policy", "assigned", "binlpt", "dynamic", "guided", "ich", "ich_chunk",
     "ich_initial_d", "paper_policy_grid", "pretiled", "static", "stealing",
@@ -65,7 +51,6 @@ __all__ = [
     "TileSchedule", "WorkerShards", "build_schedule", "coverage_counts",
     "ich_tile_width", "make_shards", "pack_csr", "partition_tiles",
     "shard_schedule", "split_items",
-    "segment_max", "segment_sum", "segmented_apply", "slot_window",
     "SimParams", "SimResult", "best_time_over_grid", "eps_sensitivity",
     "replay_refined", "simulate", "speedup", "worst_stealing",
     "Welford", "WelfordVec", "adapt_d", "classify", "ich_band",

@@ -1,40 +1,18 @@
 """Shared segmented-reduction epilogue for the iCh Pallas kernels.
 
-Every `ich_*` kernel ends the same way: a tile computed one value per
-segment slot and must fold those R values into the output array at the rows
-named by the prefetched `item_id` schedule, where several slots may name the
-same row (a split item contributes multiple segments, possibly within one
-tile). The original kernels did this with an unrolled per-slot scalar
-read-modify-write — R sequential scalar ops per grid step that neither the
-MXU nor the VPU can help with.
-
-This module replaces that epilogue with one windowed vector op, exploiting a
-structural guarantee of `core.tiling.build_schedule`: greedy packing keeps
-segments in item order and every item owns at least one segment, so the
-items appearing in any tile of R slots form a CONTIGUOUS id range spanning
-at most R rows (consecutive slots step the item id by 0 or +1). A tile's
-whole scatter therefore lands inside one length-R window of the output:
-
-1. `slot_window` finds the window base and builds the (R, R) masked one-hot
-   matrix P with P[j, i] = 1 iff slot j's row is base + i (padding slots,
-   id -1, give all-zero rows);
-2. the slot values are combined per output row — `segment_sum` is a one-hot
-   matmul (values @ P, an MXU op), `segment_max` a masked VPU reduction;
-3. `segmented_apply` folds the combined window into `out_ref[base:base+R]`
-   with a single dynamic-slice read-modify-write (grid steps run
-   sequentially on a TPU core, so the RMW is race-free), under one of three
-   combine modes: "add" (SpMV partial sums), "max" (BFS frontier OR),
-   "store" (K-Means idempotent assignment; uncovered window rows keep their
-   previous value).
-
-The window invariant only needs segments emitted in item order with >= 1
-segment per item — exactly what `build_schedule` guarantees for any sizes,
-width, or rows_per_tile.
-
-That 1-D form (`slot_window`, `segmented_apply`, `segmented_apply_batch`,
-`emit_step_cost`) serves only the MoE dispatch kernel, which runs in
-interpret mode; the SpMV, BFS and K-Means kernels run on the lane-dense
-form at the end of this module, which the TPU compiler accepts.
+The SpMV, BFS and K-Means kernels end the same way: a tile computed one
+value per segment slot and must fold those R values into the output array
+at the rows named by the prefetched `item_id` schedule, where several
+slots may name the same row (a split item contributes multiple segments,
+possibly within one tile). A structural guarantee of
+`core.tiling.build_schedule` makes that one windowed vector op: greedy
+packing keeps segments in item order and every item owns at least one
+segment, so the items of any run of slots form a CONTIGUOUS id range
+(consecutive slots step the item id by 0 or +1), and a group's whole
+scatter lands in one window of the output, folded under one of three
+combine modes: "add" (SpMV partial sums), "max" (BFS frontier OR),
+"store" (K-Means idempotent assignment; uncovered rows keep their
+previous value).
 
 `worker_reduce` is the epilogue after the worker-sharded 2D kernels
 (DESIGN.md §2.6): it folds the (p, n) per-worker accumulators into the
@@ -46,11 +24,10 @@ exact identity element (0 for add — a worker's accumulated row is never
 chain starts at +0.0 — 0 for max over nonnegative values, 0/-1 for
 store-as-max), so combining identities in any order is bit-exact.
 
-Lane-dense layout (what the SpMV, BFS and K-Means kernels run on the
-TPU). The TPU compiler refuses the 1-D form above: it cannot gather a
-vector by an index array, load a vector of row ids from SMEM, or slice a
-window at a lane offset it cannot prove is a multiple of 128, and a
-(1, n) block of a (p, n) output breaks its (8, 128) block rule. So:
+Lane-dense layout. The TPU compiler cannot gather a vector by an index
+array, load a vector of row ids from SMEM, or slice a window at a lane
+offset it cannot prove is a multiple of 128, and a (1, n) block of a
+(p, n) output breaks its (8, 128) block rule. So:
 
 * every per-slot stream is laid out with its slots on the LANE axis, a
   group of B tiles (one superstep) per block (`slots_on_lanes`): payloads
@@ -87,128 +64,6 @@ LANES = 128
 # pipeline's small stream buffers and the compiler's own scratch (its
 # default scoped limit on v5e is 16 MiB).
 VMEM_HEADROOM = 16 << 20
-
-
-def slot_window(rows: jax.Array, n_out: int) -> tuple[jax.Array, jax.Array]:
-    """Window base + masked one-hot for a tile's R slot rows.
-
-    `rows` is the (R,) int32 slot->row schedule for one tile (-1 = padding).
-    Returns `(base, onehot)` where `base` is a scalar window origin clamped
-    to [0, n_out - Wn] and `onehot` is (R, Wn) bool with
-    `onehot[j, i] = (rows[j] == base + i)`; Wn = min(R, n_out). Padding
-    slots produce all-zero one-hot rows, and an all-padding tile produces an
-    all-zero matrix (the apply becomes a no-op).
-    """
-    R = rows.shape[0]
-    wn = min(R, int(n_out))
-    valid = rows >= 0
-    r0 = jnp.min(jnp.where(valid, rows, n_out - 1))
-    base = jnp.clip(r0, 0, n_out - wn)
-    offs = jnp.where(valid, rows - base, -1)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (R, wn), 1)
-    return base, offs[:, None] == lane
-
-
-def segment_sum(values: jax.Array, onehot: jax.Array) -> jax.Array:
-    """Per-window-row sums of slot values: a (1,R)x(R,Wn) one-hot matmul.
-
-    Accumulates in float32 or wider — float64 inputs keep float64 accuracy
-    (matching the scalar-loop epilogue this layer replaced) while float32
-    stays a plain MXU matmul."""
-    acc = jnp.promote_types(values.dtype, jnp.float32)
-    return jnp.dot(values[None, :].astype(acc), onehot.astype(acc),
-                   preferred_element_type=acc)[0]
-
-
-def segment_max(values: jax.Array, onehot: jax.Array,
-                neutral) -> jax.Array:
-    """Per-window-row max of slot values (masked VPU reduction)."""
-    return jnp.max(jnp.where(onehot, values[:, None], neutral), axis=0)
-
-
-def _window_read(out_ref, base, wn):
-    """Window slice of a 1D (n,) output ref or a (1, n) accumulator block."""
-    if len(out_ref.shape) == 2:
-        return out_ref[0, pl.ds(base, wn)]
-    return out_ref[pl.ds(base, wn)]
-
-
-def _window_write(out_ref, base, wn, upd) -> None:
-    if len(out_ref.shape) == 2:
-        out_ref[0, pl.ds(base, wn)] = upd
-    else:
-        out_ref[pl.ds(base, wn)] = upd
-
-
-def segmented_apply(out_ref, rows: jax.Array, values: jax.Array, *,
-                    combine: str) -> None:
-    """Fold a tile's (R,) slot values into `out_ref` through its schedule.
-
-    One windowed read-modify-write replaces R scalar ones. Rows inside the
-    window that no slot covers are always left unchanged. `out_ref` is the
-    (n,) output of a sequential-grid kernel or one worker's (1, n)
-    accumulator block of a sharded kernel. `combine`:
-      * "add"   — out[r] += sum of the slots scheduled on row r (SpMV);
-      * "max"   — out[r] = max(out[r], max of r's slots) (BFS);
-      * "store" — out[r] = r's slot value where r is scheduled this tile
-                  (K-Means; duplicate slots of a split item carry identical
-                  values, so any-wins is exact).
-    """
-    if combine not in COMBINES:
-        raise ValueError(f"combine must be one of {COMBINES}, got {combine!r}")
-    n_out = out_ref.shape[-1]
-    base, onehot = slot_window(rows, n_out)
-    wn = onehot.shape[1]
-    cur = _window_read(out_ref, base, wn)
-    if combine == "add":
-        upd = cur + segment_sum(values, onehot).astype(cur.dtype)
-    else:
-        neutral = (-jnp.inf if jnp.issubdtype(values.dtype, jnp.floating)
-                   else jnp.iinfo(values.dtype).min)
-        covered = jnp.any(onehot, axis=0)
-        val = segment_max(values, onehot, neutral).astype(cur.dtype)
-        if combine == "max":
-            upd = jnp.where(covered, jnp.maximum(cur, val), cur)
-        else:  # store
-            upd = jnp.where(covered, val, cur)
-    _window_write(out_ref, base, wn, upd)
-
-
-def segmented_apply_batch(out_ref, rows: jax.Array, values: jax.Array, *,
-                          combine: str) -> None:
-    """Fold one superstep — B tiles of (R,) slot values — into `out_ref`.
-
-    `rows`/`values` are (B, R); the B windowed RMWs unroll statically in
-    tile order, so a worker's fold order over its tiles is exactly the
-    sequential grid's (bit-identical accumulation), while the caller's
-    gather/compute amortizes over the whole (B*R, W) block.
-    """
-    B = rows.shape[0]
-    for b in range(B):
-        segmented_apply(out_ref, rows[b], values[b], combine=combine)
-
-
-def emit_step_cost(cost_ref, rows: jax.Array, slot_cost: jax.Array,
-                   j) -> None:
-    """Accumulate one superstep's executed cost into this worker's
-    (1, n_steps) cost-output row at step j (measured-cost feedback,
-    DESIGN.md §2.7).
-
-    `slot_cost` is the fetched (B, R) per-slot scheduled-cost block and
-    `rows` the matching prefetched item ids; slots whose id is -1
-    contribute nothing — padding steps fetch a CLAMPED block (block 0),
-    so without the mask they would double-count it. The emitted stream
-    therefore accounts exactly the tiles this worker really executed, and
-    summing it recovers the schedule's per-worker tile-cost totals
-    (tests/test_adaptive_properties.py). The per-step scalar lands as a
-    masked one-hot row add — vector-friendly on the TPU, identical in
-    interpret mode. Callers zero `cost_ref` at step 0 alongside their
-    accumulator."""
-    step_cost = jnp.sum(jnp.where(rows >= 0, slot_cost, 0.0))
-    n_steps = cost_ref.shape[-1]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, n_steps), 1)
-    cost_ref[...] += jnp.where(lane == j,
-                               step_cost.astype(cost_ref.dtype), 0)
 
 
 def worker_reduce(acc: jax.Array, combine: str) -> jax.Array:
@@ -293,8 +148,9 @@ def fold_tiles(acc_ref, row0, rows: jax.Array, values: jax.Array, *,
     and value of slot j of tile b at lane b*R + j. `row0` is the group's
     `window_starts` entry and `acc_ref` the (rows, 128) accumulator. The
     window is read once, each tile's slots are combined per output row
-    into it in tile order, and it is written back once. `combine` is as in
-    `segmented_apply`; uncovered rows are left unchanged.
+    into it in tile order, and it is written back once. `combine` is
+    "add", "max" or "store" (module docstring); uncovered rows are left
+    unchanged.
     """
     if combine not in COMBINES:
         raise ValueError(f"combine must be one of {COMBINES}, got {combine!r}")

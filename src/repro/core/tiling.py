@@ -145,6 +145,24 @@ def gather_width(sizes: np.ndarray, eps: float = ICH_EPS, min_w: int = 8,
     return min(sorted(widths, reverse=True), key=cost)
 
 
+# Lanes of a TPU vector register: the token block of one MoE slot row.
+TOKEN_BLOCK = 128
+
+
+def token_block_width(sizes: np.ndarray, eps: float = ICH_EPS,
+                      min_w: int = 8, max_w: int = 512,
+                      rows_per_tile: int = 8) -> int:
+    """Pick the tile width for grouped products over slot rows.
+
+    MoE dispatch runs each slot row — up to W tokens of one expert — as a
+    dense (W, D) x (D, F) product on the MXU, so W is a whole number of
+    128-token blocks: the band's width (`ich_tile_width`), raised to at
+    least one block. The band follows the mean load, so batches of one
+    size keep one width, and with it one compiled program."""
+    band = ich_tile_width(sizes, eps, min_w, max_w)
+    return -(-max(band, TOKEN_BLOCK) // TOKEN_BLOCK) * TOKEN_BLOCK
+
+
 def split_items(
         sizes: np.ndarray, width: int
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:

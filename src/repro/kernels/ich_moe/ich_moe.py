@@ -5,37 +5,37 @@ routing on the host; its kept entries form an expert-major CSR (expert =
 item, token ids = column indices, combine weights = values) that packs
 into the SAME fixed-shape (T, R, W) work tiles every other iCh kernel
 uses (`core.tiling.pack_csr`): row splitting spreads a hot expert's
-tokens across tiles exactly like a heavy SpMV row, so no tile — and
-after cost partitioning no WORKER — is overloaded by router skew.
+tokens across tiles exactly like a heavy SpMV row. Every tile row (slot
+row, "segment") holds up to W tokens of ONE expert, so the expert FFN of
+a segment is a dense (W, D) x (D, F) product: a grouped matmul over the
+iCh tiles (DESIGN.md §2.8).
 
-`ich_moe_sharded` is the worker-sharded 2D realization (DESIGN.md §2.6
-applied to §2.8): grid (p, S_B), each grid step fetches one superstep of
-B tiles straight out of the flat payload via the prefetched block-index
-stream — double-buffered through 2-slot VMEM scratch so step j+1's
-blocks stream in while step j computes (core/pipelining.py) — applies
-the gated expert FFN to every (expert-slot, token-slot)
-pair of the block, and scatters the weighted outputs into this worker's
-private (1, n_tokens, D) accumulator with a one-hot matmul (tokens are
-NOT item-closed across workers — a token's K experts may live on
-different shards — so the scatter cannot reuse the windowed segmented
-epilogue, which is keyed on item ids; the EXPERT-space reductions below
-do reuse it). `core.segmented.worker_reduce` folds the p accumulators on
-the host; the fold tree is deterministic, so outputs are reproducible
-run-to-run even though tokens shared across workers make the sum order
-differ from a sequential evaluation (same allclose tolerance class as
-any matmul reassociation).
+`ich_moe_sharded` runs it in three steps, each under its named scope:
 
-With `slot_cost`, the kernel emits the measured-cost feedback twice over:
+* `ich.gather` — XLA gathers each segment's tokens, x[cols], into a
+  slot-major (T_pad*R, W, D) stream;
+* `ich.kernel` — the Pallas kernel `expert_ffn`, grid (p, S_B*B*R, F/tf):
+  worker w's i-th segment in the shard layout (`WorkerShards`), its
+  expert's weights tiled over F. The expert id is prefetched to SMEM and
+  picks the weight blocks through the BlockSpec index maps, so Mosaic
+  streams exactly one expert's (D, tf) / (tf, D) tiles into VMEM per grid
+  step, double-buffered; the segment's (W, D) float32 output block stays
+  in VMEM across the F tiles and is written once, in slot-major order.
+  Products run on the MXU in the weights' dtype (bf16 at the published
+  widths) with float32 accumulation;
+* `ich.fold` — XLA combines: each token's weighted sum over its slots
+  (at most its K local entries), in float32.
 
-* (p, S_B) per-worker per-superstep totals — `emit_step_cost`, the
-  stream `Schedule.observe(shards=...)` folds into the `CostRefiner`;
-* (p, E) per-worker PER-EXPERT totals — `segmented_apply_batch` into an
-  (1, E) window per worker (expert ids ARE the schedule's item ids, so
-  the windowed epilogue applies). Worker-summed, these equal the
-  schedule's per-item costs EXACTLY (integer token counts carried in
-  float32), the §2.7 routing proof extended to expert granularity — and
-  the measured per-expert load that `refine_cap_scale` turns into the
-  next step's capacity scale.
+Padding grid steps (a worker's steps past its last real segment, and
+slot rows with no item) compute nothing: their index maps repeat the
+previous step's x and weight blocks (no DMA) and point the output at a
+trash segment past the real ones, which the combine never reads.
+
+The op also returns the (p, S_B) per-worker, per-superstep cost stream
+that `Schedule.observe(shards=...)` folds, and the (p, E) per-worker,
+per-expert totals whose worker sum is the plan's kept token counts
+exactly (integer counts in float32): every slot row is one expert, so
+both are sums of the schedule's slot costs, taken in XLA.
 """
 from __future__ import annotations
 
@@ -43,172 +43,181 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.pipelining import (double_buffer_scratch,
-                                   fetch_double_buffered)
-from repro.core.segmented import (emit_step_cost, segmented_apply_batch,
-                                  worker_reduce)
+__all__ = ["grid_streams", "expert_ffn", "ich_moe_sharded"]
 
-__all__ = ["ich_moe_sharded"]
+# Expert-width tile: the F axis of the up/gate weights and the rows of the
+# down weights are streamed in (D, F_TILE) / (F_TILE, D) blocks.
+F_TILE = 512
+# Scoped VMEM beyond the double-buffered blocks and the kernel's
+# intermediates, for the compiler's own scratch.
+VMEM_HEADROOM = 16 << 20
 
 
-def _moe_sharded_body(rowid_ref, blkid_ref, vals_hbm, cols_hbm, slotc_hbm,
-                      x_ref, wi_ref, wg_ref, wo_ref, out_ref, cost_ref,
-                      ecost_ref, bufs, sems, *, S: int, B: int):
-    w, j = pl.program_id(0), pl.program_id(1)
+def _f_tile(F: int) -> int:
+    return F_TILE if F % F_TILE == 0 else F
 
-    @pl.when(j == 0)
+
+def grid_streams(rowid: np.ndarray, blkid: np.ndarray, p: int,
+                 superstep: int, n_seg: int) -> tuple[np.ndarray, np.ndarray,
+                                                      np.ndarray]:
+    """The kernel's three scalar-prefetch streams, one entry per grid step
+    (w, i) of worker w's i-th segment, from the shard layout's item ids
+    rowid (p*S, R) and block ids blkid (p*S_B,) over a flat pack of
+    `n_seg` segments (T_pad * R):
+
+    * `src` — the flat segment (tile*R + row) whose tokens the step reads;
+    * `dst` — the flat segment it writes, or n_seg (the trash segment) on
+      a padding step;
+    * `expert` — the expert whose weights it streams.
+
+    On a padding step `src` and `expert` repeat the worker's previous real
+    step (0 before its first), so its blocks are not fetched again."""
+    p, B = int(p), int(superstep)
+    R = int(rowid.shape[1])
+    n_per = rowid.shape[0] // p * R          # segments per worker, S*R
+    item = np.asarray(rowid, np.int32).reshape(p, n_per)
+    i = np.arange(n_per, dtype=np.int64)
+    seg = (np.asarray(blkid, np.int64).reshape(p, -1)[:, i // (B * R)]
+           * (B * R) + i % (B * R))
+    live = item >= 0
+    # index of each step's latest real step in its worker (-1: none yet)
+    last = np.maximum.accumulate(np.where(live, i, -1), axis=1)
+    rows = np.arange(p)[:, None]
+    src = np.where(last >= 0, seg[rows, np.maximum(last, 0)], 0)
+    expert = np.where(last >= 0, item[rows, np.maximum(last, 0)], 0)
+    dst = np.where(live, seg, int(n_seg))
+    return (src.reshape(-1).astype(np.int32),
+            dst.reshape(-1).astype(np.int32),
+            expert.reshape(-1).astype(np.int32))
+
+
+def _ffn_kernel(src_ref, dst_ref, exp_ref, x_ref, wi_ref, wg_ref, wo_ref,
+                out_ref, *, n_per: int, trash: int):
+    w, i, f = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    live = dst_ref[w * n_per + i] != trash
+
+    @pl.when(live & (f == 0))
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
-        if cost_ref is not None:
-            cost_ref[...] = jnp.zeros_like(cost_ref)
-            ecost_ref[...] = jnp.zeros_like(ecost_ref)
 
-    # double-buffered data-dependent fetch (core/pipelining.py)
-    hbm = (vals_hbm, cols_hbm) if slotc_hbm is None \
-        else (vals_hbm, cols_hbm, slotc_hbm)
-    blocks = fetch_double_buffered(list(zip(hbm, bufs, sems)),
-                                   blkid_ref, w, j, B=B)
-    vals = blocks[0]  # (B, R, W): one superstep of combine weights
-    cols = blocks[1]  # (B, R, W): token ids (0 on padding, vals 0)
-    x = x_ref[...]    # (n_tokens, D)
-    rows = rowid_ref[pl.ds(w * S + j * B, B)]  # (B, R) expert ids, -1 pad
-    e = jnp.maximum(rows, 0)
-
-    # gated FFN on every slot: tokens enter f32 like the in-graph router
-    # path; expert weights are gathered per slot row (whole-E residency)
-    xs = x[cols].astype(jnp.float32)                   # (B, R, W, D)
-    h = jnp.einsum("brwd,brdf->brwf", xs, wi_ref[...][e],
-                   preferred_element_type=jnp.float32)
-    g = jnp.einsum("brwd,brdf->brwf", xs, wg_ref[...][e],
-                   preferred_element_type=jnp.float32)
-    yb = jnp.einsum("brwf,brfd->brwd", jax.nn.silu(g) * h, wo_ref[...][e],
-                    preferred_element_type=jnp.float32)
-    # combine weight per slot; padding slots carry vals == 0 and padding
-    # STEPS fetch a clamped block whose vals are real, so mask on rows too
-    contrib = yb * vals[..., None] * (rows >= 0)[..., None, None]
-
-    # token scatter: one-hot matmul over the flattened (B*R*W) slot axis
-    # into this worker's private accumulator (tokens are not item-closed
-    # across workers, so no windowed RMW — the window is in expert space)
-    n_tokens = out_ref.shape[1]
-    flat_tok = cols.reshape(-1)                        # (B*R*W,)
-    flat_c = contrib.reshape(-1, contrib.shape[-1])    # (B*R*W, D)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (n_tokens,
-                                                flat_tok.shape[0]), 0)
-    onehot = (lane == flat_tok[None, :]).astype(jnp.float32)
-    out_ref[...] += jnp.dot(onehot, flat_c,
-                            preferred_element_type=jnp.float32)[None]
-
-    if cost_ref is not None:
-        slotc = blocks[2]  # (B, R) scheduled per-slot costs
-        emit_step_cost(cost_ref, rows, slotc, j)
-        # per-expert totals: expert ids are the schedule's item ids, so
-        # the windowed segmented epilogue applies directly
-        masked = jnp.where(rows >= 0, slotc, 0.0)
-        segmented_apply_batch(ecost_ref, rows, masked, combine="add")
+    @pl.when(live)
+    def _ffn():
+        x = x_ref[0]                                        # (W, D)
+        h = jnp.dot(x, wi_ref[0], preferred_element_type=jnp.float32)
+        g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+        a = (g / (1.0 + jnp.exp(-g)) * h).astype(wo_ref.dtype)  # SiLU gate
+        out_ref[0] += jnp.dot(a, wo_ref[0],
+                              preferred_element_type=jnp.float32)
 
 
-def _moe_kernel_sharded(rowid_ref, blkid_ref, vals_hbm, cols_hbm, x_ref,
-                        wi_ref, wg_ref, wo_ref, out_ref, vbuf, cbuf, vsem,
-                        csem, *, S: int, B: int):
-    _moe_sharded_body(rowid_ref, blkid_ref, vals_hbm, cols_hbm, None,
-                      x_ref, wi_ref, wg_ref, wo_ref, out_ref, None, None,
-                      (vbuf, cbuf), (vsem, csem), S=S, B=B)
+def expert_ffn(xs, wi, wg, wo, src, dst, expert, *, p: int,
+               interpret: bool = False):
+    """The grouped expert FFN over slot-major token blocks.
 
+    xs (n_seg, W, D): each flat segment's gathered tokens; wi/wg (E, D, F)
+    up and gate weights, wo (E, F, D) down weights; src/dst/expert the
+    (p*n_per,) streams of `grid_streams`. Returns (n_seg + 1, W, D)
+    float32: row s is segment s's W token outputs, SiLU(x wg) * (x wi)
+    times wo; rows no step writes (padding segments and the trash row
+    n_seg) are left undefined."""
+    n_seg, W, D = xs.shape
+    E, _, F = wi.shape
+    tf = _f_tile(F)
+    n_f = F // tf
+    n_per = int(src.shape[0]) // int(p)
+    trash = n_seg
 
-def _moe_kernel_sharded_cost(rowid_ref, blkid_ref, vals_hbm, cols_hbm,
-                             slotc_hbm, x_ref, wi_ref, wg_ref, wo_ref,
-                             out_ref, cost_ref, ecost_ref, vbuf, cbuf,
-                             sbuf, vsem, csem, ssem, *, S: int, B: int):
-    _moe_sharded_body(rowid_ref, blkid_ref, vals_hbm, cols_hbm, slotc_hbm,
-                      x_ref, wi_ref, wg_ref, wo_ref, out_ref, cost_ref,
-                      ecost_ref, (vbuf, cbuf, sbuf), (vsem, csem, ssem),
-                      S=S, B=B)
+    def f_of(k, f, dst):  # a padding step keeps the last F tile
+        return jnp.where(dst[k] != trash, f, n_f - 1)
 
+    def x_map(w, i, f, src, dst, exp):
+        return src[w * n_per + i], 0, 0
 
-def ich_moe_sharded(vals, cols, rowid, blkid, x, wi, wg, wo, p: int,
-                    superstep: int, *, slot_cost=None,
-                    interpret: bool = False):
-    """Worker-sharded MoE expert application over a packed dispatch plan.
+    def up_map(w, i, f, src, dst, exp):  # up and gate: (D, tf) tiles
+        k = w * n_per + i
+        return exp[k], 0, f_of(k, f, dst)
 
-    vals/cols (T_pad, R, W): flat packed combine weights + token ids
-    (`pack_csr` over the plan's expert-major CSR, padded to whole
-    supersteps); rowid (p*S, R) per-slot expert ids and blkid (p*S_B,)
-    from `WorkerShards`; x (n_tokens, D) token activations; wi/wg
-    (E, D, F) and wo (E, F, D) expert FFN weights. Returns y (n_tokens, D)
-    in float32.
+    def down_map(w, i, f, src, dst, exp):  # down: (tf, D) tiles
+        k = w * n_per + i
+        return exp[k], f_of(k, f, dst), 0
 
-    With `slot_cost` ((T_pad, R), the schedule's per-slot cost stream)
-    returns (y, step_costs (p, S_B), expert_costs (p, E)); summed over
-    workers the expert costs equal the schedule's per-expert totals
-    exactly (integer token counts in float32)."""
-    T_pad, R, W = vals.shape
-    n_tokens, D = x.shape
-    E = wi.shape[0]
-    p, B = int(p), int(superstep)
-    n_steps = int(blkid.shape[0]) // p
-    S = n_steps * B
-    if blkid.shape[0] != p * n_steps or rowid.shape[0] != p * S or T_pad % B:
-        raise ValueError(f"shard layout mismatch: blkid {blkid.shape}, "
-                         f"rowid {rowid.shape}, T_pad={T_pad}, p={p}, B={B}")
-    emit = slot_cost is not None
-    # payloads stay whole in ANY memory; the kernel double-buffers the
-    # data-dependent superstep blocks through 2-slot VMEM scratch
-    # (core/pipelining.py)
-    in_specs = [
-        pl.BlockSpec(memory_space=pl.ANY),  # vals (T_pad, R, W)
-        pl.BlockSpec(memory_space=pl.ANY),  # cols (T_pad, R, W)
-    ]
-    db_streams = [((R, W), vals.dtype), ((R, W), jnp.int32)]
-    out_specs = pl.BlockSpec((1, n_tokens, D),
-                             lambda w, j, rowid, blk: (w, 0, 0))
-    out_shape = jax.ShapeDtypeStruct((p, n_tokens, D), jnp.float32)
-    if emit:
-        kernel = functools.partial(_moe_kernel_sharded_cost, S=S, B=B)
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))  # slot costs
-        db_streams.append(((R,), jnp.float32))
-        out_specs = [out_specs,
-                     pl.BlockSpec((1, n_steps),
-                                  lambda w, j, rowid, blk: (w, 0)),
-                     pl.BlockSpec((1, E), lambda w, j, rowid, blk: (w, 0))]
-        out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((p, n_steps), jnp.float32),
-                     jax.ShapeDtypeStruct((p, E), jnp.float32)]
-    else:
-        kernel = functools.partial(_moe_kernel_sharded, S=S, B=B)
-    # token activations + the full expert weight stacks stay whole in VMEM
-    in_specs.append(pl.BlockSpec(x.shape, lambda w, j, rowid, blk: (0, 0)))
-    in_specs.append(pl.BlockSpec(wi.shape,
-                                 lambda w, j, rowid, blk: (0, 0, 0)))
-    in_specs.append(pl.BlockSpec(wg.shape,
-                                 lambda w, j, rowid, blk: (0, 0, 0)))
-    in_specs.append(pl.BlockSpec(wo.shape,
-                                 lambda w, j, rowid, blk: (0, 0, 0)))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # sharded expert ids + block ids to SMEM
-        grid=(p, n_steps),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=double_buffer_scratch(B, db_streams),
-    )
+    def out_map(w, i, f, src, dst, exp):
+        return dst[w * n_per + i], 0, 0
+
+    in_specs = [pl.BlockSpec((1, W, D), x_map),
+                pl.BlockSpec((1, D, tf), up_map),
+                pl.BlockSpec((1, D, tf), up_map),
+                pl.BlockSpec((1, tf, D), down_map)]
+    out_spec = pl.BlockSpec((1, W, D), out_map)
+    blocks = (W * D * xs.dtype.itemsize + 3 * D * tf * wi.dtype.itemsize
+              + W * D * 4)
+    temps = 3 * W * tf * 4 + W * D * 4
     call = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        # workers accumulate into private rows; the shard dimension may
-        # run concurrently across TPU cores / megacore
+        functools.partial(_ffn_kernel, n_per=n_per, trash=trash),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # src, dst, expert per grid step
+            grid=(int(p), n_per, n_f),
+            in_specs=in_specs,
+            out_specs=out_spec,
+        ),
+        out_shape=jax.ShapeDtypeStruct((n_seg + 1, W, D), jnp.float32),
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=int(2 * blocks + temps + VMEM_HEADROOM)),
         interpret=interpret,
         name="ich_moe",
     )
-    if emit:
-        acc, costs, ecosts = call(rowid, blkid, vals, cols,
-                                  jnp.asarray(slot_cost, jnp.float32),
-                                  x, wi, wg, wo)
-        return worker_reduce(acc, "add"), costs, ecosts
-    acc = call(rowid, blkid, vals, cols, x, wi, wg, wo)
-    return worker_reduce(acc, "add")
+    return call(src, dst, expert, xs, wi, wg, wo)
+
+
+def ich_moe_sharded(vals, cols, rowid, blkid, src, dst, expert, x, wi, wg,
+                    wo, p: int, superstep: int, *, slot_cost=None,
+                    interpret: bool = False):
+    """MoE expert application over a packed dispatch plan.
+
+    vals/cols (T_pad, R, W): the plan's combine weights and token ids,
+    packed flat (`pack_csr`, padding slots 0); rowid (p*S, R) and blkid
+    (p*S_B,) from `WorkerShards`; src/dst/expert from `grid_streams`;
+    x (n_tokens, D) token activations; wi/wg (E, D, F), wo (E, F, D).
+    Returns y (n_tokens, D) float32: y[t] = sum over t's slots of the
+    slot's combine weight times its expert's FFN of x[t].
+
+    With `slot_cost` ((T_pad, R), the schedule's per-slot cost stream)
+    returns (y, step_costs (p, S_B), expert_costs (p, E))."""
+    T_pad, R, W = vals.shape
+    n_tokens, D = x.shape
+    p, B = int(p), int(superstep)
+    S_B = int(blkid.shape[0]) // p
+    if blkid.shape[0] != p * S_B or rowid.shape[0] != p * S_B * B \
+            or T_pad % B:
+        raise ValueError(f"shard layout mismatch: blkid {blkid.shape}, "
+                         f"rowid {rowid.shape}, T_pad={T_pad}, p={p}, B={B}")
+    with jax.named_scope("ich.gather"):
+        xs = x[cols.reshape(T_pad * R, W)]                  # (T_pad*R, W, D)
+    with jax.named_scope("ich.kernel"):
+        out = expert_ffn(xs, wi, wg, wo, src, dst, expert, p=p,
+                         interpret=interpret)
+    with jax.named_scope("ich.fold"):
+        # padding slots carry weight 0, and only they: their outputs (some
+        # never written) are dropped, not multiplied
+        v = vals.reshape(-1)
+        contrib = jnp.where((v != 0)[:, None],
+                            out[:T_pad * R].reshape(-1, D) * v[:, None], 0.0)
+        y = jnp.zeros((n_tokens, D), jnp.float32).at[
+            cols.reshape(-1)].add(contrib)
+    if slot_cost is None:
+        return y
+    with jax.named_scope("ich.cost"):
+        E = wi.shape[0]
+        blk = jnp.asarray(slot_cost, jnp.float32).reshape(-1, B * R)[blkid]
+        item = rowid.reshape(p * S_B, B * R)
+        blk = jnp.where(item >= 0, blk, 0.0)                # (p*S_B, B*R)
+        step_costs = blk.sum(axis=1).reshape(p, S_B)
+        worker = jnp.repeat(jnp.arange(p), S_B * B * R)
+        expert_costs = jnp.zeros((p, E), jnp.float32).at[
+            worker, jnp.maximum(item, 0).reshape(-1)].add(blk.reshape(-1))
+    return y, step_costs, expert_costs

@@ -13,6 +13,8 @@ array reduction or a device sync of its own.
 
 The spans of the loop-call path (PERF.md §3, "Tracing"):
 
+    moe.readback                 the router's choices to the host
+    moe.plan                     plan_dispatch
     sched.build (workload)       LoopScheduler.build
       sched.schedule             cost provider, fingerprint, cache lookup
         sched.construct          a cache miss: tiles and Schedule

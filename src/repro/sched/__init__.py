@@ -48,7 +48,9 @@ _LAZY = {
     "cap_scale_from_costs": "moe",
     "expert_capacity": "moe",
     "plan_dispatch": "moe",
+    "read_routing": "moe",
     "refine_cap_scale": "moe",
+    "route": "moe",
     # schedule cache (sched/cache.py)
     "CacheStats": "cache",
     "ScheduleCache": "cache",

@@ -4,8 +4,9 @@ Each op binds a constructed `Schedule` to a workload's payloads once
 (pack), then applies the Pallas kernel many times. SpMV and BFS gather
 their payload (vals * x[cols], mask * frontier[cols]) over every packed
 slot, padding included, so they register `core.tiling.gather_width`: the
-cheapest width under the band. K-Means, MoE dispatch and serve-prefill
-keep the band's width. These are the
+cheapest width under the band. MoE dispatch runs each slot row as a dense
+product and registers `core.tiling.token_block_width`: whole 128-token
+blocks. K-Means and serve-prefill keep the band's width. These are the
 implementations behind `scheduler.build("spmv" | "bfs" | "kmeans", ...)`;
 the legacy `IChSpmv` / `IChBfs` / `IChKMeans` classes under
 `repro/kernels/ich_*/ops.py` are deprecation shims over this module.
@@ -41,12 +42,13 @@ the jax import.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import numpy as np
 
 from repro import obs
-from repro.core.tiling import gather_width, pack_csr
+from repro.core.tiling import gather_width, pack_csr, token_block_width
 from repro.kernels import default_interpret
 
 from .api import Schedule
@@ -91,25 +93,40 @@ class _ObservableOp:
     def _bind_csr(self, indptr, indices, data):
         """Shard, pack and upload a CSR payload in the FLAT layout the
         SpMV, BFS and MoE kernels fetch blockwise. Sets the shard streams
-        `rowid`, `blkid` and `slot_cost`; returns the device (vals,
-        cols)."""
+        `rowid`, `blkid`, `slot_cost` and any of the op's own
+        (`_streams`); returns the device (vals, cols)."""
         import jax.numpy as jnp
         schedule = self.schedule
         with obs.span("op.shard"):
             shards = self.shards = schedule.shard()
-            streams = (shards.shard_item_id(schedule.tiles),
-                       shards.kernel_block_ids(),
-                       _flat_slot_cost(schedule, shards.n_tiles_padded))
+            run, n_tiles = self._lowering(shards)
+            streams = self._streams(run, n_tiles)
         with obs.span("op.pack"):
             vals, cols = pack_csr(np.asarray(indptr), np.asarray(indices),
                                   np.asarray(data), schedule.tiles,
                                   pad_tiles_to=shards.superstep)
+            if n_tiles > vals.shape[0]:
+                pad = ((0, n_tiles - vals.shape[0]), (0, 0), (0, 0))
+                vals, cols = np.pad(vals, pad), np.pad(cols, pad)
         with obs.span("op.upload"):
-            vals, cols, self.rowid, self.blkid, self.slot_cost = (
+            vals, cols, self.rowid, self.blkid, self.slot_cost, *more = (
                 jnp.asarray(a) for a in (vals, cols, *streams))
-        self.p = shards.p
-        self.superstep = shards.superstep
+            self.more_streams = tuple(more)
+        self.p = run.p
+        self.superstep = run.superstep
         return vals, cols
+
+    def _lowering(self, shards):
+        """The shard layout the kernel runs and the flat tile count of its
+        payload: the schedule's own, unless an op pads them."""
+        return shards, shards.n_tiles_padded
+
+    def _streams(self, run, n_tiles: int) -> tuple:
+        """Host streams to upload: the shard layout's item ids and block
+        ids and the flat per-slot costs, then any of the op's own."""
+        return (run.shard_item_id(self.schedule.tiles),
+                run.kernel_block_ids(),
+                _flat_slot_cost(self.schedule, n_tiles))
 
     def _kernel(self):
         """The sharded kernel with this op's static arguments bound."""
@@ -275,50 +292,99 @@ class KMeansOp(_ObservableOp):
         return assign
 
 
+def _bucket(n: int) -> int:
+    """The least of 0, 1, 2, 3, 4, 6, 8, 12, ... (2^k and 3 * 2^(k-1))
+    that is at least n: padded sizes that take few distinct values."""
+    n = int(n)
+    if n <= 2:
+        return max(n, 0)
+    k = (n - 1).bit_length() - 1  # 2^k < n <= 2^(k+1)
+    return 3 << (k - 1) if n <= 3 << (k - 1) else 2 << k
+
+
 class MoeDispatchOp(_ObservableOp):
     """iCh-scheduled MoE expert application: pack a dispatch plan once,
-    apply the expert FFN stack many times (DESIGN.md §2.8).
+    apply the expert FFN stack (DESIGN.md §2.8).
 
     The plan's expert-major CSR (token ids + combine weights per expert)
     packs through the same `pack_csr` path as SpMV — expert = item, a hot
-    expert's tokens split across tiles like a heavy row — and executes on
-    the worker-sharded `ich_moe_sharded` kernel. Besides the (p, S_B)
-    superstep cost stream every op emits, this kernel also returns
-    (p, E) per-worker PER-EXPERT cost totals (`last_expert_costs`);
-    `expert_load()` worker-sums them into the measured per-expert load
-    that `repro.sched.moe.refine_cap_scale` folds into the next step's
-    capacity scale."""
+    expert's tokens split across slot rows like a heavy row — and runs as
+    `ich_moe_sharded`: the XLA token gather, the grouped expert FFN kernel
+    with each expert's weights streamed per tile, and the combine in XLA.
+
+    Plans of one batch size differ in their expert loads, so the flat
+    tile count is padded up to a `_bucket` size and every worker's step
+    count to the padded block count: the program's shapes take few
+    values, and consecutive plans reuse one compiled program, which all
+    MoE ops share. Besides the (p, S_B) superstep cost stream every op
+    emits, the program returns (p, E) per-worker per-expert cost totals
+    (`last_expert_costs`); `expert_load()` worker-sums them into the
+    per-expert load that `repro.sched.moe.refine_cap_scale` folds into
+    the next step's capacity scale."""
 
     program = "ich_moe"
     last_expert_costs = None  # (p, E) from the latest invocation
+    _step_costs = None        # (p, padded S_B) from the latest invocation
+    _programs: dict = {}      # interpret mode -> the shared jitted program
+
+    @property
+    def last_costs(self):
+        """(p, S_B) per-worker, per-superstep cost stream of the latest
+        invocation, cut from the padded one when read, so that the call
+        itself runs no op whose shape follows the partition."""
+        c = self._step_costs
+        return None if c is None else c[:, :self.shards.n_steps]
 
     def __init__(self, schedule: Schedule, plan):
         super().__init__(schedule)
+        self._jitted = MoeDispatchOp._programs
         self.plan = plan
         self.n_tokens = plan.n_tokens
         self.n_experts = plan.n_experts
         self.vals, self.cols = self._bind_csr(*plan.csr())
 
-    def _kernel(self):
+    def _lowering(self, shards):
+        # every worker gets room for all the blocks, so the shapes follow
+        # the padded tile count alone, whatever the partition
+        B = shards.superstep
+        blocks = max(_bucket(shards.n_tiles_padded // B), shards.n_steps)
+        run = dataclasses.replace(shards, block_perm=np.pad(
+            shards.block_perm, ((0, 0), (0, blocks - shards.n_steps)),
+            constant_values=-1))
+        return run, B * blocks
+
+    def _streams(self, run, n_tiles: int) -> tuple:
+        from repro.kernels.ich_moe.ich_moe import grid_streams
+        rowid, blkid, slot_cost = super()._streams(run, n_tiles)
+        return (rowid, blkid, slot_cost,
+                *grid_streams(rowid, blkid, run.p, run.superstep,
+                              n_tiles * self.schedule.rows_per_tile))
+
+    def _program(self, interpret: bool):
+        import jax
         from repro.kernels.ich_moe.ich_moe import ich_moe_sharded
-        return functools.partial(ich_moe_sharded, p=self.p,
-                                 superstep=self.superstep)
+        fn = functools.partial(ich_moe_sharded, interpret=interpret)
+        fn.__name__ = self.program
+        return jax.jit(fn, static_argnames=("p", "superstep"))
 
     def __call__(self, x, wi, wg, wo, interpret: bool | None = None):
         """Apply the planned dispatch: x (n_tokens, D) token activations,
-        wi/wg (E, D, F), wo (E, F, D). Returns y (n_tokens, D)."""
+        wi/wg (E, D, F) up and gate weights, wo (E, F, D) down weights.
+        Returns y (n_tokens, D) float32, each token's combine-weighted sum
+        of its experts' SwiGLU outputs."""
         import jax.numpy as jnp
         # n_tokens == 0 also short-circuits: a zero-admission plan still
         # carries one tile per (zero-count) expert, but the kernel's token
         # gather has no source rows to read
         if self.schedule.n_tiles == 0 or self.n_tokens == 0:
-            self.last_costs = self._empty_costs()
+            self._step_costs = self._empty_costs()
             self.last_expert_costs = jnp.zeros(
                 (self.p, self.n_experts), jnp.float32)
-            return jnp.zeros((self.n_tokens, x.shape[-1]), x.dtype)
-        y, self.last_costs, self.last_expert_costs = self._run(
-            interpret, self.vals, self.cols, self.rowid, self.blkid, x, wi,
-            wg, wo, slot_cost=self.slot_cost)
+            return jnp.zeros((self.n_tokens, x.shape[-1]), jnp.float32)
+        y, self._step_costs, self.last_expert_costs = self._run(
+            interpret, self.vals, self.cols, self.rowid, self.blkid,
+            *self.more_streams, x, wi, wg, wo, p=self.p,
+            superstep=self.superstep, slot_cost=self.slot_cost)
         return y
 
     def expert_load(self) -> np.ndarray:
@@ -355,7 +421,8 @@ register(
     costs=lambda plan: ExpertLoadCosts(plan.counts),
     build=MoeDispatchOp,
     doc="MoE expert FFN over a dispatch plan (sched/moe.py); input "
-        "(DispatchPlan); cost = per-expert kept token load.")
+        "(DispatchPlan); cost = per-expert kept token load.",
+    width=token_block_width)
 register(
     "serve-prefill",
     costs=lambda remaining: RemainingTokensCosts(

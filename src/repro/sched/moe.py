@@ -25,20 +25,73 @@ module is the host-side half of that mapping:
   (`models/moe.py:ich_update_cap_scale`), derived from compounding
   Welford statistics instead of one multiplicative step.
 
-Everything here is numpy-only: planning runs on the host between steps,
-never inside a traced computation.
+* `route` is the device half in front of it, for expert-parallel layers:
+  RMSNorm and a sigmoid router with a selection bias (DeepSeek-V3's
+  `noaux_tc`, one group), top-K over every routed expert, normalised
+  weights. `read_routing` brings its choices to the host, where
+  `plan_dispatch(..., experts=(first, count))` keeps the entries of the
+  experts this chip holds, droplessly.
+
+Planning is numpy-only and runs on the host between steps, never inside a
+traced computation; jax is imported by `route` alone.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import numpy as np
+
+from repro import obs
 
 from .defaults import (MOE_CAP_SCALE_MAX, MOE_CAP_SCALE_MIN,
                        MOE_CAPACITY_FACTOR, MOE_CMAX_FACTOR, MOE_MIN_CAPACITY)
 
-__all__ = ["DispatchPlan", "expert_capacity", "plan_dispatch",
-           "cap_scale_from_costs", "refine_cap_scale"]
+__all__ = ["DispatchPlan", "expert_capacity", "plan_dispatch", "route",
+           "read_routing", "cap_scale_from_costs", "refine_cap_scale"]
+
+
+@functools.lru_cache(maxsize=None)
+def _router(top_k: int, eps: float):
+    import jax
+    import jax.numpy as jnp
+
+    def router(h, w_router, bias):
+        hf = h.astype(jnp.float32)
+        u = hf * jax.lax.rsqrt(jnp.mean(hf * hf, axis=-1, keepdims=True)
+                               + eps)
+        u = u.astype(h.dtype)
+        logits = jnp.dot(u, w_router.T, preferred_element_type=jnp.float32)
+        scores = jax.nn.sigmoid(logits)
+        _, e_topk = jax.lax.top_k(scores + bias, top_k)
+        w = jnp.take_along_axis(scores, e_topk, axis=-1)
+        return u, e_topk, w / jnp.sum(w, axis=-1, keepdims=True)
+
+    router.__name__ = "moe_route"
+    return jax.jit(router)
+
+
+def route(h, w_router, bias, *, top_k: int, eps: float = 1e-5):
+    """RMSNorm (gain 1) and the sigmoid router of an MoE layer, jitted.
+
+    h (T, D) token activations; w_router (E, D), the router over ALL E
+    routed experts; bias (E,) the selection bias. Scores are float32:
+    s = sigmoid(u w_router^T); the top `top_k` experts of s + bias are
+    chosen, and each choice weighs s_e over the chosen experts' sum of s.
+    Returns (u (T, D) in h's dtype, e_topk (T, top_k) int32, weights
+    (T, top_k) float32), all on the device."""
+    return _router(int(top_k), float(eps))(h, w_router, bias)
+
+
+def read_routing(e_topk, weights):
+    """The router's choices and weights as host arrays (the device-to-host
+    read-back in front of `plan_dispatch`). It first waits for the router
+    to finish, outside the `moe.readback` span, so that the span times the
+    copy alone."""
+    for a in (e_topk, weights):
+        getattr(a, "block_until_ready", lambda: None)()
+    with obs.span("moe.readback"):
+        return np.asarray(e_topk), np.asarray(weights)
 
 
 def expert_capacity(n_tokens: int, n_experts: int, experts_per_token: int,
@@ -109,7 +162,7 @@ def plan_dispatch(e_topk: np.ndarray, weights: np.ndarray = None, *,
                   cap=None, cap_scale=None,
                   capacity_factor: float = MOE_CAPACITY_FACTOR,
                   cmax_factor: float = MOE_CMAX_FACTOR,
-                  steal: bool = True) -> DispatchPlan:
+                  steal: bool = True, experts=None) -> DispatchPlan:
     """Resolve a dispatch plan from router choices — the scheduler-side
     mirror of the in-graph path.
 
@@ -126,16 +179,60 @@ def plan_dispatch(e_topk: np.ndarray, weights: np.ndarray = None, *,
     to its token's max-slack alternative (first max on ties — the exact
     argmax the in-graph path computes) and ranked after the expert's
     first-round keeps, surviving under the same capacity rule.
+
+    `experts=(first, count)` plans one expert-parallel rank that holds
+    experts [first, first + count): entries routed to them are kept,
+    renumbered to [0, count), and none is dropped (no capacity, no
+    steal); entries routed elsewhere belong to other ranks and are not in
+    the plan, whose entry arrays then list the kept entries alone, in
+    token-major order. `cap` and `cap_scale` do not apply.
     """
-    e_topk = np.asarray(e_topk)
-    if e_topk.ndim != 2:
-        raise ValueError(f"e_topk must be (T, K), got {e_topk.shape}")
+    with obs.span("moe.plan"):
+        e_topk = np.asarray(e_topk)
+        if e_topk.ndim != 2:
+            raise ValueError(f"e_topk must be (T, K), got {e_topk.shape}")
+        if experts is not None:
+            if cap is not None or cap_scale is not None:
+                raise ValueError("a plan over held experts is dropless: "
+                                 "cap and cap_scale do not apply")
+            return _plan_held(e_topk, weights, *experts)
+        return _plan(e_topk, weights, cap, cap_scale, capacity_factor,
+                     cmax_factor, steal)
+
+
+def _weights(e_topk: np.ndarray, weights) -> np.ndarray:
     T, K = e_topk.shape
     if weights is None:
-        weights = np.full((T, K), 1.0 / K, np.float32)
+        return np.full((T, K), 1.0 / K, np.float32)
     weights = np.asarray(weights, np.float32)
     if weights.shape != (T, K):
         raise ValueError(f"weights {weights.shape} != e_topk {(T, K)}")
+    return weights
+
+
+def _plan_held(e_topk, weights, first: int, count: int) -> DispatchPlan:
+    first, count = int(first), int(count)
+    if first < 0 or count < 1:
+        raise ValueError(f"held experts must be (first >= 0, count >= 1), "
+                         f"got ({first}, {count})")
+    T, K = e_topk.shape
+    weights = _weights(e_topk, weights)
+    held = (e_topk >= first) & (e_topk < first + count)
+    ef = (e_topk[held] - first).astype(np.int64)
+    tf = np.nonzero(held)[0].astype(np.int32)
+    counts = np.bincount(ef, minlength=count).astype(np.int64)
+    return DispatchPlan(
+        n_tokens=T, n_experts=count, experts_per_token=K,
+        expert=ef.astype(np.int32), token=tf, weight=weights[held],
+        pos=_dispatch_positions(ef, count), keep=np.ones(ef.size, bool),
+        cap=counts.astype(np.int32), counts=counts, router_counts=counts,
+        stolen=0, dropped=0)
+
+
+def _plan(e_topk, weights, cap, cap_scale, capacity_factor, cmax_factor,
+          steal) -> DispatchPlan:
+    T, K = e_topk.shape
+    weights = _weights(e_topk, weights)
 
     if cap is not None:
         cap_e = np.asarray(cap, np.int32)

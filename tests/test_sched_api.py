@@ -301,18 +301,13 @@ def _band_inputs(workload):
     rng = np.random.default_rng(13)
     skewed = np.diff(skewed_csr(500, 13, hubs=[(0, 2_000), (1, 2_000)])[0])
     skewed = skewed + 1.0
-    if workload == "moe-dispatch":
-        # 16 experts, top-2 routing skewed towards the low ids
-        e = np.minimum(rng.zipf(1.3, (4_000, 2)) - 1, 15)
-        e[:, 1] = (e[:, 0] + 1 + rng.integers(0, 15, 4_000)) % 16
-        return (sched.plan_dispatch(e, cap=np.full(16, 10_000)),)
     if workload == "serve-prefill":
         return (skewed.astype(np.int64),)
     return (skewed,)  # kmeans costs, and raw schedule() costs
 
 
-@pytest.mark.parametrize("workload", ["kmeans", "moe-dispatch",
-                                      "serve-prefill", "schedule()"])
+@pytest.mark.parametrize("workload", ["kmeans", "serve-prefill",
+                                      "schedule()"])
 def test_other_workloads_keep_the_band_width(workload):
     """Element-identical to the band's schedule, on sizes where the
     gather rule would pick another width."""

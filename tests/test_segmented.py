@@ -1,8 +1,8 @@
 """Unit tests for the shared segmented-reduction epilogue
 (core/segmented.py), independent of any particular ich_* kernel: minimal
 pallas_call harnesses scatter per-slot values through real build_schedule
-item-id schedules — in the 1-D windowed form and in the lane-dense form
-the TPU kernels run — and must match the per-slot scalar-RMW oracle."""
+item-id schedules in the lane-dense form the TPU kernels run, and must
+match the per-slot scalar-RMW oracle."""
 import functools
 
 import jax
@@ -13,37 +13,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.segmented import (LANES, acc_rows, fold_tiles,
-                                  segmented_apply, slot_window,
                                   slots_on_lanes, unpack_acc, window_rows,
                                   window_starts)
 from repro.core import tiling as T
 from repro.core.tiling import build_schedule
-
-
-def _apply_kernel(rowid_ref, vals_ref, out_ref, *, combine):
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    segmented_apply(out_ref, rowid_ref[t], vals_ref[0], combine=combine)
-
-
-def _run(rowid, vals, n_out, combine, dtype):
-    T, R = rowid.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(T,),
-        in_specs=[pl.BlockSpec((1, R), lambda t, rowid: (t, 0))],
-        out_specs=pl.BlockSpec((n_out,), lambda t, rowid: (0,)),
-    )
-    return pl.pallas_call(
-        functools.partial(_apply_kernel, combine=combine),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_out,), dtype),
-        interpret=True,
-    )(jnp.asarray(rowid), jnp.asarray(vals))
 
 
 def _oracle(rowid, vals, n_out, combine, dtype):
@@ -76,81 +49,6 @@ def _schedule_and_values(n, R, W, seed, split_aware):
     else:
         vals = rng.standard_normal(sched.item_id.shape).astype(np.float32)
     return sched, vals.astype(np.float32)
-
-
-@pytest.mark.parametrize("n,R,W,seed", [
-    (64, 8, None, 0), (100, 4, 16, 1), (37, 16, 8, 2), (200, 8, None, 3),
-    (5, 8, 4, 4),  # n_out < R: window shrinks to n_out
-])
-@pytest.mark.parametrize("combine", ["add", "max"])
-def test_segmented_apply_matches_scalar_rmw(n, R, W, seed, combine):
-    # values include negatives: "max" must leave uncovered window rows
-    # untouched and must not floor covered rows at a fake 0 neutral
-    sched, vals = _schedule_and_values(n, R, W, seed, split_aware=False)
-    out = _run(sched.item_id, vals, n, combine, jnp.float32)
-    np.testing.assert_allclose(
-        np.asarray(out), _oracle(sched.item_id, vals, n, combine, np.float32),
-        atol=1e-5)
-
-
-def test_segmented_add_keeps_float64_accuracy():
-    # regression: the one-hot matmul must accumulate in the value dtype
-    # (promoted to >= f32), not force-truncate f64 partials to f32
-    with jax.enable_x64():
-        sched, vals = _schedule_and_values(64, 8, None, 11, split_aware=False)
-        vals = vals.astype(np.float64) + 1e-9
-        out = _run(sched.item_id, vals, 64, "add", jnp.float64)
-        oracle = _oracle(sched.item_id, vals, 64, "add", np.float64)
-        assert np.asarray(out).dtype == np.float64
-        np.testing.assert_allclose(np.asarray(out), oracle, atol=1e-12,
-                                   rtol=0)
-
-
-@pytest.mark.parametrize("n,R,W,seed", [
-    (64, 8, None, 0), (100, 4, 8, 1), (37, 16, 4, 2), (5, 8, 4, 3),
-])
-def test_segmented_store_matches_idempotent_writes(n, R, W, seed):
-    sched, vals = _schedule_and_values(n, R, W, seed, split_aware=True)
-    out = _run(sched.item_id, vals, n, "store", jnp.float32)
-    np.testing.assert_array_equal(
-        np.asarray(out), _oracle(sched.item_id, vals, n, "store", np.float32))
-
-
-def test_slot_window_covers_every_tile_of_any_schedule():
-    """The window invariant behind the whole layer: greedy in-order packing
-    keeps each tile's item ids inside one length-R window."""
-    rng = np.random.default_rng(7)
-    for _ in range(30):
-        n = int(rng.integers(1, 400))
-        R = int(rng.choice([1, 2, 4, 8, 16]))
-        sizes = np.minimum(rng.zipf(1.5, n), 5000).astype(np.int64)
-        sizes[rng.random(n) < 0.2] = 0
-        sched = build_schedule(sizes, rows_per_tile=R)
-        for t in range(sched.n_tiles):
-            rows = sched.item_id[t]
-            valid = rows[rows >= 0]
-            if valid.size:
-                assert valid.max() - valid.min() < R
-            base, onehot = jax.jit(
-                slot_window, static_argnums=1)(jnp.asarray(rows), n)
-            # every valid slot is inside the window and one-hot is exact
-            onehot = np.asarray(onehot)
-            base = int(base)
-            for j, r in enumerate(rows):
-                if r >= 0:
-                    assert onehot[j].sum() == 1
-                    assert base + int(np.argmax(onehot[j])) == r
-                else:
-                    assert onehot[j].sum() == 0
-
-
-def test_segmented_apply_rejects_unknown_combine():
-    class _FakeRef:
-        shape = (8,)
-
-    with pytest.raises(ValueError, match="combine"):
-        segmented_apply(_FakeRef(), jnp.zeros(8, jnp.int32),
-                        jnp.zeros(8), combine="mul")
 
 
 # ------------------------------------------------------------ lane-dense
