@@ -309,8 +309,9 @@ class MoeDispatchOp(_ObservableOp):
     The plan's expert-major CSR (token ids + combine weights per expert)
     packs through the same `pack_csr` path as SpMV — expert = item, a hot
     expert's tokens split across slot rows like a heavy row — and runs as
-    `ich_moe_sharded`: the XLA token gather, the grouped expert FFN kernel
-    with each expert's weights streamed per tile, and the combine in XLA.
+    `ich_moe_sharded`: the XLA token gather, then the grouped expert FFN
+    kernel with each expert's weights streamed per tile and the combine
+    into y as its epilogue.
 
     Plans of one batch size differ in their expert loads, so the flat
     tile count is padded up to a `_bucket` size and every worker's step

@@ -168,7 +168,7 @@ def test_expert_load_equals_plan_counts(p):
 @pytest.mark.parametrize("p", [1, 4])
 def test_grid_streams_visit_each_segment_once(p):
     """Every real slot row is written by exactly one grid step, with its
-    own expert; padding steps write only the trash segment and repeat
+    own expert; padding steps name no segment (n_seg) and repeat
     their worker's last blocks."""
     _, op, _, _ = _program(_layer(6), 0, HELD, p)
     src, dst, expert = (np.asarray(a) for a in op.more_streams)
