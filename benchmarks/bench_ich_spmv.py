@@ -9,7 +9,7 @@ waste MXU slots on padding, too-narrow tiles split rows into many segments
 import numpy as np
 
 from repro.core import workloads as WL
-from repro.kernels.ich_spmv.ich_spmv import ich_tile_width, pack_tiles
+from repro.core.tiling import build_schedule, ich_tile_width, pack_csr
 
 
 def main(n=20000):
@@ -25,11 +25,11 @@ def main(n=20000):
         TILE_OVERHEAD = 64  # slot-equivalents per tile (grid-step dispatch)
 
         def eff(width):
-            vals, cols, rowid, W = pack_tiles(indptr, indices, data,
-                                              rows_per_tile=8, width=width)
+            sched = build_schedule(nnz_rows, rows_per_tile=8, width=width)
+            vals, _ = pack_csr(indptr, indices, data, sched)
             slots = vals.shape[0] * vals.shape[1] * vals.shape[2]
             cost = slots + TILE_OVERHEAD * vals.shape[0]
-            return nnz / cost, vals.shape[0], W
+            return nnz / cost, vals.shape[0], sched.width
 
         wi = ich_tile_width(nnz_rows)
         e_ich, t_ich, _ = eff(wi)

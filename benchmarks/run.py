@@ -7,19 +7,15 @@ results/paper/, and validates the paper's headline claims:
   * iCh beats plain stealing on BFS and K-Means (paper: +9.6%..54%).
 
 Usage: PYTHONPATH=src python -m benchmarks.run [--fast] [--only NAME]
-       PYTHONPATH=src python -m benchmarks.run --bench schedule [--fast]
        PYTHONPATH=src python -m benchmarks.run --bench serve [--fast]
 
-`--bench paper` (default) reproduces the paper figures; `--bench schedule`
-runs the schedule-construction perf benchmark (bench_schedule_build) and
-refreshes BENCH_schedule.json at the repo root; `--bench serve` runs the
-serving tail-latency sweep (bench_serve: offered load x dispatch policy,
-simulated clock) and refreshes BENCH_serve.json.
+`--bench paper` (default) reproduces the paper figures; `--bench serve`
+runs the serving tail-latency sweep (bench_serve: offered load x dispatch
+policy, simulated clock) and refreshes BENCH_serve.json.
 """
 from __future__ import annotations
 
 import argparse
-import sys
 import time
 
 import numpy as np
@@ -36,16 +32,10 @@ def main() -> None:
                     help="smaller n (quick smoke; claims still checked)")
     ap.add_argument("--only", default=None)
     ap.add_argument("--bench", default="paper",
-                    choices=["paper", "schedule", "serve"],
-                    help="paper = figure reproduction; schedule = "
-                         "schedule-construction perf (BENCH_schedule.json); "
-                         "serve = serving tail-latency sweep "
-                         "(BENCH_serve.json)")
+                    choices=["paper", "serve"],
+                    help="paper = figure reproduction; serve = serving "
+                         "tail-latency sweep (BENCH_serve.json)")
     args = ap.parse_args()
-    if args.bench == "schedule":
-        from . import bench_schedule_build as BS
-        BS.main(sizes=(10_000,) if args.fast else BS.DEFAULT_SIZES)
-        return
     if args.bench == "serve":
         from . import bench_serve as BV
         BV.main(seeds=(BV.SEEDS[0],) if args.fast else BV.SEEDS)

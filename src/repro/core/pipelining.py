@@ -1,4 +1,4 @@
-"""Double-buffered fetch of data-dependent payload blocks (DESIGN.md §2.12).
+"""Double-buffered fetch of data-dependent payload blocks (DESIGN.md §2.6).
 
 The worker-sharded iCh kernels read their payload supersteps through a
 DATA-DEPENDENT block index (`WorkerShards.kernel_block_ids`): worker w's

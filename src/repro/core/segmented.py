@@ -44,7 +44,12 @@ offset it cannot prove is a multiple of 128, and a (1, n) block of a
   never rounded to bfloat16 — into the window in tile order. A row's
   value is therefore (((0 + tile_1) + tile_2) + ...) over the tiles that
   hold its segments, however the tiles are grouped into supersteps and
-  workers: the sequential and the sharded grids agree bit for bit.
+  workers.
+
+Fold order. That is the one ordering guarantee of every iCh kernel: its
+output is bit-identical for every worker count p and superstep B to the
+run at p=1, superstep=1, which folds the tiles one by one in schedule
+order (tests/test_sharding.py holds the three paper kernels to it).
 """
 from __future__ import annotations
 
@@ -211,45 +216,6 @@ def compiler_params(resident_bytes: int):
 
 
 _REDUCE = {"add": jnp.sum, "max": jnp.max}
-
-
-def _payload_kernel(starts_ref, rows_ref, pay_ref, acc_ref, *, R: int,
-                    combine: str):
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    part = _REDUCE[combine](pay_ref[...], axis=0, keepdims=True)  # (1, R)
-    fold_tiles(acc_ref, starts_ref[t], rows_ref[...], part,
-               rows_per_tile=R, combine=combine)
-
-
-def segmented_reduce(payload: jax.Array, rowid: jax.Array, n_out: int, *,
-                     combine: str, interpret: bool = False) -> jax.Array:
-    """Sequential reference grid over a (T, R, W) tile payload: grid step
-    t reduces tile t's slots over W ("add": sum, "max": max) and folds
-    them into out[rowid[t]]. rowid (T, R). Returns (n_out,)."""
-    T, R, W = payload.shape
-    acc = pl.pallas_call(
-        functools.partial(_payload_kernel, R=R, combine=combine),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,  # window start per tile, to SMEM
-            grid=(T,),
-            in_specs=[
-                pl.BlockSpec((None, 1, R), lambda t, st: (t, 0, 0)),
-                pl.BlockSpec((None, W, R), lambda t, st: (t, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((None, acc_rows(n_out, R), LANES),
-                                   lambda t, st: (0, 0, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((1, acc_rows(n_out, R), LANES),
-                                       payload.dtype),
-        interpret=interpret,
-    )(window_starts(rowid, R), slots_on_lanes(rowid, 1),
-      slots_on_lanes(payload, 1))
-    return unpack_acc(acc, n_out)[0]
 
 
 def _payload_sharded_kernel(starts_ref, blkid_ref, rows_ref, *refs, R: int,

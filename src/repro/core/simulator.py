@@ -487,8 +487,7 @@ def replay_refined(
     assignment (`policies.assigned`). The makespan answers "what would this
     schedule cost on the true workload" — `Schedule.replay_refined` feeds
     it per-item costs, and the observe/refine loop must drive it down
-    (benchmarks/bench_schedule_build.py's refine-loop section,
-    tests/test_adaptive_properties.py).
+    (tests/test_adaptive_properties.py).
     """
     pol = (P.pretiled(ranges) if workers is None
            else P.assigned(ranges, workers))

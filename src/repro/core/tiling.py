@@ -37,9 +37,8 @@ Construction is fully vectorized (DESIGN.md §2.5): segment counts come from a
 ceil-div, segment/unit coordinates from `cumsum`/`repeat` de-flattening, and
 payload packing from one fancy-gather — no Python-level per-segment or
 per-nonzero loop anywhere on the construction path, so a schedule over
-millions of items builds in milliseconds (benchmarks/bench_schedule_build.py
-tracks the trajectory in BENCH_schedule.json). The original loop
-formulations are kept as `_reference_*` oracles; tests assert equality.
+millions of items builds in milliseconds. The original loop formulations
+are kept as `_reference_*` oracles; tests assert equality.
 """
 from __future__ import annotations
 
@@ -393,10 +392,10 @@ def partition_tiles(tile_cost: np.ndarray, item_id: np.ndarray,
     placement rule (PAPERS.md) applied to iCh-constructed tiles.
 
     Keeping every item's tiles on ONE worker is what makes the sharded
-    kernels bit-identical to the sequential grid: each output row is
-    accumulated by exactly one worker, in ascending tile order (the same
-    fold order the single grid uses), and every other worker contributes an
-    exact identity element to the cross-worker reduction.
+    kernels' bits independent of p: each output row is accumulated by
+    exactly one worker, in ascending tile order (the fold order of the run
+    at p=1, superstep=1), and every other worker contributes an exact
+    identity element to the cross-worker reduction.
     """
     tile_cost = np.asarray(tile_cost, np.float64)
     T = int(tile_cost.size)

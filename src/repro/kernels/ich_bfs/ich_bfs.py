@@ -13,23 +13,18 @@ across W-wide segments, segments greedily packed into (T, R) slots.
 `mask` is the all-ones CSR payload from `pack_csr` — 1.0 on real edge slots,
 0.0 on padding — so a padded slot can never observe frontier[cols==0].
 
-Two grids run the same segmented reduction (`core/segmented.py`; see
-ich_spmv for the pattern):
-
-* `ich_bfs_step` — sequential reference grid (T,): each step takes the max
-  of its slots' frontier indicators over W and max-folds them into the
-  per-vertex output (split rows OR together across tiles).
-* `ich_bfs_step_sharded` — worker-sharded 2D grid (p, S_B) (DESIGN.md
-  §2.6): tiles are cost-partitioned across p workers at superstep-block
-  granularity (item-closed — no vertex spans workers), each grid step
-  fetches a superstep of B tiles as one block straight from the FLAT
-  payload via a prefetched data-dependent block index, DOUBLE-BUFFERED
-  (core/pipelining.py), every worker max-accumulates into its own
-  lane-dense accumulator, and a pairwise tree max
-  (`core.segmented.worker_reduce`) folds the accumulators — bit-identical
-  to the sequential grid: each vertex is owned by one worker and all
-  others contribute exact zeros (the max identity for the 0/1 frontier
-  indicators).
+`ich_bfs_step_sharded` is the one grid (DESIGN.md §2.6; see ich_spmv for
+the pattern), checked against `ref.bfs_step_ref`: tiles are
+cost-partitioned across p workers at superstep-block granularity
+(item-closed — no vertex spans workers), each grid step fetches a
+superstep of B tiles as one block straight from the FLAT payload via a
+prefetched data-dependent block index, DOUBLE-BUFFERED
+(core/pipelining.py), takes the max of each slot's frontier indicators
+over W, and max-folds them into the worker's own lane-dense accumulator
+(split rows OR together across tiles). A pairwise tree max
+(`core.segmented.worker_reduce`) folds the accumulators — exact: each
+vertex is owned by one worker and all others contribute exact zeros (the
+max identity for the 0/1 frontier indicators).
 
 The gathers the TPU compiler cannot lower run in XLA around the kernel:
 mask * frontier[cols] is formed before it and streamed as the payload,
@@ -40,17 +35,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.core.segmented import segmented_reduce, segmented_reduce_sharded
-
-
-def ich_bfs_step(mask, cols, rowid, frontier, visited, n_vertices: int,
-                 *, interpret: bool = False):
-    """One frontier expansion on the sequential reference grid. mask/cols
-    (T,R,W); rowid (T,R); frontier and visited (n,) float32 indicators.
-    Returns the next frontier (n,)."""
-    hit = segmented_reduce(mask * frontier[cols], rowid, n_vertices,
-                           combine="max", interpret=interpret)
-    return hit * (1.0 - visited)
+from repro.core.segmented import segmented_reduce_sharded
 
 
 def ich_bfs_step_sharded(mask, cols, rowid, blkid, frontier, visited,

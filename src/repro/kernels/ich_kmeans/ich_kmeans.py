@@ -11,21 +11,17 @@ scheduled point is recomputed once per slot; the assignment write is
 idempotent (same argmin), so correctness is unaffected — redundant compute
 is the price a static grid pays where the runtime would have stolen.
 
-Two grids share the body (see ich_spmv for the pattern):
-
-* `ich_kmeans_assign` — sequential reference grid (T,): each step takes
-  its R scheduled points, computes squared distances to the (K, D)
-  centroids, and writes per-point argmin through the item-id schedule
-  ("store" mode: uncovered window rows keep their previously written
-  assignment).
-* `ich_kmeans_assign_sharded` — worker-sharded 2D grid (p, S/B)
-  (DESIGN.md §2.6): tiles are cost-partitioned across p workers
-  (item-closed — no point spans workers), each grid step computes a
-  superstep of B tiles, every worker stores into its own lane-dense
-  accumulator, and a pairwise tree max (`core.segmented.worker_reduce`)
-  folds the accumulators — bit-identical to the sequential grid:
-  assignments are >= 0, each point is stored by exactly one worker, and
-  every other worker holds the zero-initialized identity.
+`ich_kmeans_assign_sharded` is the one grid (DESIGN.md §2.6; see ich_spmv
+for the pattern), checked against `ref.kmeans_assign_ref`: tiles are
+cost-partitioned across p workers (item-closed — no point spans workers),
+each grid step takes a superstep of B tiles' scheduled points, computes
+squared distances to the (K, D) centroids, and writes per-point argmin
+through the item-id schedule into the worker's own lane-dense accumulator
+("store" mode: uncovered window rows keep their previously written
+assignment). A pairwise tree max (`core.segmented.worker_reduce`) folds
+the accumulators — exact: assignments are >= 0, each point is stored by
+exactly one worker, and every other worker holds the zero-initialized
+identity.
 
 The TPU compiler cannot gather rows of a VMEM table by an index vector,
 so the scheduled points are gathered in XLA before the kernel, one
@@ -72,48 +68,6 @@ def _gather_points(points, ids, tiles: int):
     return slots_on_lanes(sel, tiles)
 
 
-def _kmeans_kernel(starts_ref, rows_ref, pts_ref, cent_ref, out_ref, *,
-                   R: int):
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    # duplicate slots of a split point carry the same argmin, so the
-    # segmented "store" (any-wins within the window) is exact
-    fold_tiles(out_ref, starts_ref[t], rows_ref[...],
-               _assign(pts_ref[...], cent_ref[...]), rows_per_tile=R,
-               combine="store")
-
-
-def ich_kmeans_assign(points, centroids, rowid, *, interpret: bool = False):
-    """Sequential reference grid. points (n, D); centroids (K, D);
-    rowid (T, R) schedule. Returns assignments (n,) int32."""
-    n, D = points.shape
-    T, R = rowid.shape
-    n_acc = acc_rows(n, R)
-    acc = pl.pallas_call(
-        functools.partial(_kmeans_kernel, R=R),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,  # window start per tile, to SMEM
-            grid=(T,),
-            in_specs=[
-                pl.BlockSpec((None, 1, R), lambda t, st: (t, 0, 0)),
-                pl.BlockSpec((None, D, R), lambda t, st: (t, 0, 0)),
-                pl.BlockSpec((D, centroids.shape[0]), lambda t, st: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((None, n_acc, LANES),
-                                   lambda t, st: (0, 0, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((1, n_acc, LANES), jnp.int32),
-        interpret=interpret,
-    )(window_starts(rowid, R), slots_on_lanes(rowid, 1),
-      _gather_points(points, rowid, 1),
-      jnp.asarray(centroids, jnp.float32).T)
-    return unpack_acc(acc, n)[0]
-
-
 def _kmeans_sharded_kernel(starts_ref, rows_ref, pts_ref, cent_ref, *refs,
                            R: int, emit: bool):
     w, j = pl.program_id(0), pl.program_id(1)
@@ -126,6 +80,8 @@ def _kmeans_sharded_kernel(starts_ref, rows_ref, pts_ref, cent_ref, *refs,
             o[...] = jnp.zeros_like(o)
 
     rows = rows_ref[...]  # (1, B*R)
+    # duplicate slots of a split point carry the same argmin, so the
+    # segmented "store" (any-wins within the window) is exact
     fold_tiles(outs[0], starts_ref[w * pl.num_programs(1) + j], rows,
                _assign(pts_ref[...], cent_ref[...]), rows_per_tile=R,
                combine="store")

@@ -6,31 +6,27 @@ into fixed-shape work tiles (R rows x W nnz slots), and rows whose nnz
 exceeds W are SPLIT across several tiles — the work-stealing analogue: no
 tile (chunk) can be overloaded, heavy rows' overflow migrates to later tiles
 exactly like stolen iterations. The paper's band classification over the
-row-nnz distribution (`ich_tile_width`) bounds W from above; the registry
-op (`sched.build("spmv", ...)`) takes the cheapest power of two under it
-(`core.tiling.gather_width`), since the gather below walks every packed
-slot, padding included. `pack_tiles` packs at the band unless given W.
+row-nnz distribution (`core.tiling.ich_tile_width`) bounds W from above;
+the registry op (`sched.build("spmv", ...)`) takes the cheapest power of
+two under it (`core.tiling.gather_width`), since the gather below walks
+every packed slot, padding included.
 
-Two grids run the same segmented reduction (`core/segmented.py`):
-
-* `ich_spmv` — the sequential reference grid: grid = (T,), one tile per
-  step, folded into the single output accumulator (grid steps execute in
-  order on one TPU core, so the read-modify-write is safe).
-* `ich_spmv_sharded` — the production 2D grid (DESIGN.md §2.6): the
-  schedule's parallelism p is lowered onto the accelerator as a
-  worker-major grid (p, S_B). Tiles are cost-partitioned across p workers
-  at superstep-block granularity (`core.tiling.partition_tiles`,
-  item-closed so no row spans workers) and each grid step processes a
-  SUPERSTEP of B tiles, fetched as one block straight out of the FLAT
-  payload via a prefetched data-dependent block index
-  (`WorkerShards.kernel_block_ids`) and DOUBLE-BUFFERED
-  (`core/pipelining.py`): step j+1's block streams into the spare VMEM
-  slot while step j computes. Every worker accumulates into its own
-  lane-dense block of a (p, rows, 128) output (no cross-worker races; the
-  worker dimension is declared "parallel"), and a pairwise tree reduce
-  (`core.segmented.worker_reduce`) folds the accumulators — bit-identical
-  to the sequential grid because each row is owned by exactly one worker
-  and all others contribute exact zeros.
+`ich_spmv_sharded` is the one grid (DESIGN.md §2.6), checked against
+`ref.spmv_ref`: the schedule's parallelism p is lowered onto the
+accelerator as a worker-major grid (p, S_B). Tiles are cost-partitioned
+across p workers at superstep-block granularity
+(`core.tiling.partition_tiles`, item-closed so no row spans workers) and
+each grid step processes a SUPERSTEP of B tiles, fetched as one block
+straight out of the FLAT payload via a prefetched data-dependent block
+index (`WorkerShards.kernel_block_ids`) and DOUBLE-BUFFERED
+(`core/pipelining.py`): step j+1's block streams into the spare VMEM slot
+while step j computes. Every worker accumulates into its own lane-dense
+block of a (p, rows, 128) output (no cross-worker races; the worker
+dimension is declared "parallel"), and a pairwise tree reduce
+(`core.segmented.worker_reduce`) folds the accumulators — exact because
+each row is owned by exactly one worker and all others contribute exact
+zeros, so the bits do not depend on p or B (`core/segmented.py`, fold
+order).
 
 The TPU compiler has no gather of a vector by an index array, so the
 products vals * x[cols] are formed in XLA before the kernel and streamed
@@ -42,37 +38,10 @@ fit a v5e core.
 from __future__ import annotations
 
 import jax
-import numpy as np
 
-from repro.core.segmented import segmented_reduce, segmented_reduce_sharded
-from repro.core.tiling import build_schedule, ich_tile_width, pack_csr
-from repro.sched.defaults import ICH_EPS
+from repro.core.segmented import segmented_reduce_sharded
 
-__all__ = ["ich_tile_width", "pack_tiles", "ich_spmv", "ich_spmv_sharded"]
-
-
-def pack_tiles(indptr: np.ndarray, indices: np.ndarray, data: np.ndarray,
-               *, rows_per_tile: int = 8, width: int = None,
-               eps: float = ICH_EPS):
-    """CSR -> (values (T,R,W), cols (T,R,W), rowid (T,R)) with row splitting.
-
-    Thin wrapper over the shared schedule-construction layer
-    (`core.tiling`): rows are cut into width-W segments; segments are packed
-    greedily into tiles of R row-slots each (a segment of a heavy row may
-    land in any tile => tile work is uniform at R*W slots).
-    """
-    row_nnz = np.diff(indptr)
-    sched = build_schedule(row_nnz, rows_per_tile=rows_per_tile,
-                           width=width, eps=eps)
-    vals, cols = pack_csr(indptr, indices, data, sched)
-    return vals, cols, sched.item_id, sched.width
-
-
-def ich_spmv(vals, cols, rowid, x, n_rows: int, *, interpret: bool = False):
-    """Sequential reference grid. vals/cols (T,R,W); rowid (T,R); x (n,).
-    Returns y (n_rows,)."""
-    return segmented_reduce(vals * x[cols], rowid, n_rows, combine="add",
-                            interpret=interpret)
+__all__ = ["ich_spmv_sharded"]
 
 
 def ich_spmv_sharded(vals, cols, rowid, blkid, x, n_rows: int, p: int,

@@ -68,13 +68,6 @@ class Schedule:
     superstep: int = SUPERSTEP
     # memoized worker shard layouts keyed (p, superstep); benign build race
     _shards: dict = dataclasses.field(default_factory=dict, repr=False)
-    # which construction pipeline built (and re-builds) this schedule:
-    # "numpy" = core/tiling.py, "jax" = the jitted core/tiling_jax.py twin
-    # (element-identical tiles; device lowerings via `device_lowering()`)
-    backend: str = "numpy"
-    # memoized DEVICE lowerings keyed (p, superstep) — the on-device twin
-    # of `_shards` (core/tiling_jax.DeviceLowering); same benign build race
-    _device: dict = dataclasses.field(default_factory=dict, repr=False)
     # ---- measured-cost feedback state (DESIGN.md §2.7) ----
     # refinement generation: 0 = built from a-priori estimates, g+1 = built
     # by the g-th schedule's refine(); part of the schedule-cache key, so a
@@ -116,33 +109,6 @@ class Schedule:
             # get the winning layout
             hit = self._shards.setdefault(key, T.shard_schedule(
                 self.tiles, self.tile_cost(), key[0], superstep=key[1]))
-        return hit
-
-    def device_lowering(self, *, p: Optional[int] = None,
-                        superstep: Optional[int] = None):
-        """The jitted on-device lowering of this schedule
-        (`core/tiling_jax.DeviceLowering`): build -> cost -> partition ->
-        shard layout run as one compiled pipeline, element-identical to
-        the host `shard()` arrays (tests/test_tiling_jax.py) but resident
-        as jax device buffers the sharded kernels can consume without a
-        host round-trip. Memoized per (p, superstep) like `shard()`.
-
-        Generation safety: `refine()` always returns a NEW Schedule under
-        a fresh cache generation with an EMPTY device memo, so a cached
-        device lowering can never alias a stale generation's buffers —
-        the same no-aliasing rule the host shard layouts obey
-        (sched/cache.py). Width is pinned to this schedule's resolved
-        tile width, so the device pipeline reproduces these exact tiles
-        rather than re-deriving the band."""
-        from repro.core import tiling_jax as TJ
-        key = (int(p if p is not None else self.p),
-               int(superstep if superstep is not None else self.superstep))
-        hit = self._device.get(key)
-        if hit is None:
-            hit = self._device.setdefault(key, TJ.lower_schedule_jax(
-                self.sizes, self.costs, p=key[0], superstep=key[1],
-                rows_per_tile=self.rows_per_tile, width=self.width,
-                eps=self.band_eps))
         return hit
 
     @property
@@ -199,7 +165,7 @@ class Schedule:
         perfectly balanced). The load-balance figure the refine loop
         drives down: observe() + refine() re-partitions from measured
         costs, so a schedule built from stale estimates converges toward
-        imbalance 1.0 over rounds (benchmarks/bench_schedule_build.py,
+        imbalance 1.0 over rounds (tests/test_adaptive_properties.py,
         tests/test_moe_sched.py)."""
         shards = self.shard(p=p, superstep=superstep)
         wc = shards.worker_cost(self.tile_cost())
@@ -450,19 +416,13 @@ class Schedule:
             width = _tile_width(self.width_arg, self.width_rule,
                                 provider.sizes(), self.band_eps, MIN_WIDTH,
                                 MAX_WIDTH, self.rows_per_tile)
-            if self.backend == "jax":
-                from repro.core import tiling_jax as TJ
-                tiles = TJ.build_schedule_jax(
-                    provider.sizes(), rows_per_tile=self.rows_per_tile,
-                    width=width, eps=self.band_eps).to_host()
-            else:
-                tiles = T.build_schedule(provider.sizes(),
-                                         rows_per_tile=self.rows_per_tile,
-                                         width=width, eps=self.band_eps)
+            tiles = T.build_schedule(provider.sizes(),
+                                     rows_per_tile=self.rows_per_tile,
+                                     width=width, eps=self.band_eps)
             new = dataclasses.replace(
                 self, sizes=provider.sizes(), costs=provider.costs(),
                 tiles=tiles, generation=self.generation + 1,
-                _shards={}, _feedback={}, _device={})
+                _shards={}, _feedback={})
         new._feedback["refiner"] = r.successor(new.sizes)
         return new
 
@@ -477,8 +437,7 @@ class Schedule:
         the schedule was built from — `simulator.replay_refined` over the
         tile ranges, through the central pretiled queue, or as the static
         sharded assignment when `sharded=True`. The observe/refine loop
-        must drive this makespan down (tests/test_adaptive_properties.py,
-        benchmarks/bench_schedule_build.py)."""
+        must drive this makespan down (tests/test_adaptive_properties.py)."""
         true_costs = np.asarray(true_costs, np.float64)
         if true_costs.shape != (self.n_items,):
             raise ValueError(f"true costs must have shape "
@@ -614,12 +573,7 @@ class LoopScheduler:
                  min_w: int = MIN_WIDTH, max_w: int = MAX_WIDTH,
                  superstep: int = SUPERSTEP,
                  cache_size: int = 32,
-                 sim_params: Optional[S.SimParams] = None,
-                 backend: str = "numpy"):
-        if backend not in ("numpy", "jax"):
-            raise ValueError(
-                f"backend must be 'numpy' or 'jax', got {backend!r}")
-        self.backend = backend
+                 sim_params: Optional[S.SimParams] = None):
         self.p = int(p)
         self.policy = policy if policy is not None else P.ich(ICH_EPS)
         self.rows_per_tile = int(rows_per_tile)
@@ -684,35 +638,23 @@ class LoopScheduler:
         # label(): labels are lossy — taskloop's drops num_tasks, pretiled's
         # drops the actual ranges — and would alias distinct policies onto
         # one cache entry
-        # the backend is part of the key: a "jax" entry memoizes DEVICE
-        # lowerings (device_lowering) a "numpy"-facade caller never asked
-        # to pin, and the two construction pipelines must stay separately
-        # attributable even though their tiles are element-identical
         key = (provider.fingerprint(), pol, pp, rpt, width, rule,
-               band_eps, self.min_w, self.max_w, sstep, gen, self.backend)
+               band_eps, self.min_w, self.max_w, sstep, gen)
 
         def build() -> Schedule:
             with obs.span("sched.construct"):
                 sizes = provider.sizes()
                 w = _tile_width(width, rule, sizes, band_eps, self.min_w,
                                 self.max_w, rpt)
-                if self.backend == "jax":
-                    from repro.core import tiling_jax as TJ
-                    tiles = TJ.build_schedule_jax(
-                        sizes, rows_per_tile=rpt, width=w,
-                        eps=band_eps, min_w=self.min_w,
-                        max_w=self.max_w).to_host()
-                else:
-                    tiles = T.build_schedule(
-                        sizes, rows_per_tile=rpt, width=w,
-                        eps=band_eps, min_w=self.min_w, max_w=self.max_w)
+                tiles = T.build_schedule(
+                    sizes, rows_per_tile=rpt, width=w,
+                    eps=band_eps, min_w=self.min_w, max_w=self.max_w)
                 return Schedule(
                     sizes=sizes, costs=provider.costs(), policy=pol, p=pp,
                     tiles=tiles, sim_params=self.sim_params,
                     superstep=sstep, generation=gen,
                     structural_sizes=structural, width_arg=width,
-                    width_rule=rule, band_eps=band_eps,
-                    backend=self.backend, _scheduler=self)
+                    width_rule=rule, band_eps=band_eps, _scheduler=self)
 
         if self.cache is None:
             return build()
@@ -772,8 +714,8 @@ _DEFAULT_LOCK = threading.Lock()
 
 
 def default_scheduler() -> LoopScheduler:
-    """Process-wide facade instance (one shared schedule cache) — what the
-    deprecation shims and the serving path use."""
+    """Process-wide facade instance (one shared schedule cache) — what
+    `ShardDispatcher` uses when it is given no scheduler."""
     global _DEFAULT
     with _DEFAULT_LOCK:
         if _DEFAULT is None:

@@ -5,29 +5,22 @@ serving rates even milliseconds per request add up — and most requests
 re-present a cost distribution the scheduler has already seen (the same
 CSR matrix, the same graph, the same batch shape). The cache keys on
 ``(cost_fingerprint, policy, p, construction params, superstep,
-backend)`` — the full frozen `Policy` dataclass, not its lossy
+generation)`` — the full frozen `Policy` dataclass, not its lossy
 ``label()``, and the worker PARTITION parameters `p`/`superstep`: a
 cached `Schedule` memoizes its worker-shard lowering (`Schedule.shard`)
 and the kernel ops pack payloads into that layout, so entries built for
 different worker counts must never alias (tests/test_sched_api.py proves
-distinct `p` values don't collide). The construction BACKEND ("numpy" or
-"jax", `core/tiling_jax.py`) keys for the same reason: a jax-backed
-entry additionally memoizes on-device lowerings
-(`Schedule.device_lowering`), and those device buffers obey the same
-no-aliasing rule as the host shards — see the generation paragraph
-below.
+distinct `p` values don't collide).
 A repeat `LoopScheduler.schedule()` call returns the previously built
 `Schedule` object without touching construction at all
-(`benchmarks/bench_schedule_build.py` records the hit path in
-`BENCH_schedule.json`).
+(tests/test_sched_api.py asserts it on the hit and miss counters).
 
 Generation invalidation (measured-cost feedback, DESIGN.md §2.7): the key
 also carries the refinement GENERATION. `Schedule.refine()` re-enters
 this cache with generation g+1 and a `RefinedCosts` fingerprint over the
 refreshed (sizes, costs) content, so a refined schedule — and everything
-hanging off it: memoized shard layouts, packed kernel payloads, DEVICE
-lowerings (`Schedule.device_lowering`'s jax buffers) — is always a fresh
-entry; a stale generation-g lowering can never be served for
+hanging off it: memoized shard layouts, packed kernel payloads — is
+always a fresh entry; a stale generation-g lowering can never be served for
 generation-g+1 costs, even if an unrelated entry hashed equal on the
 non-generation fields. Old generations age out through normal LRU
 eviction rather than eager invalidation: in a serving loop the previous

@@ -39,10 +39,6 @@ SUPERSTEP = 8
 # observed always keep their prior.
 REFINE_BLEND = 1.0
 
-# Rounds the refine-loop demo/benchmark runs (observe -> refine cycles on
-# the jittered workload in benchmarks/bench_schedule_build.py).
-REFINE_ROUNDS = 3
-
 # MoE expert dispatch (DESIGN.md §2.8): per-expert capacity is the chunk-
 # size analogue, so its knobs live with the scheduler defaults and are
 # imported by BOTH the in-graph layer (models/moe.py) and the host-side

@@ -7,9 +7,7 @@ slot, padding included, so they register `core.tiling.gather_width`: the
 cheapest width under the band. MoE dispatch runs each slot row as a dense
 product and registers `core.tiling.token_block_width`: whole 128-token
 blocks. K-Means and serve-prefill keep the band's width. These are the
-implementations behind `scheduler.build("spmv" | "bfs" | "kmeans", ...)`;
-the legacy `IChSpmv` / `IChBfs` / `IChKMeans` classes under
-`repro/kernels/ich_*/ops.py` are deprecation shims over this module.
+implementations behind `scheduler.build("spmv" | "bfs" | "kmeans", ...)`.
 
 Ops execute on the worker-sharded 2D kernels (DESIGN.md §2.6): the
 schedule's tiles are cost-partitioned across `schedule.p` accelerator
@@ -17,9 +15,9 @@ workers at superstep-block granularity (`Schedule.shard()`), payloads
 stay in the FLAT (T_pad, R, W) pack (padded to whole supersteps), and
 each grid step fetches one worker's next block of `schedule.superstep`
 tiles via the prefetched block-index stream — lowering to the shard
-layout moves no payload bytes. Outputs are bit-identical to the
-sequential (T,)-grid kernels (tests/test_sharding.py), which remain
-available in the kernel modules as the cross-check path.
+layout moves no payload bytes. Each kernel has this one grid, checked
+against its `ref.py` oracle; its bits do not depend on p or the superstep
+(`core/segmented.py`, tests/test_sharding.py).
 
 Measured-cost feedback (DESIGN.md §2.7): every op passes the schedule's
 per-slot cost stream into the sharded kernel, which emits a per-worker,
@@ -255,7 +253,11 @@ class BfsOp(_ObservableOp):
 
 
 class KMeansOp(_ObservableOp):
-    """iCh-scheduled K-Means assignment over a predicted per-point cost."""
+    """iCh-scheduled K-Means assignment over a predicted per-point cost.
+
+    K-Means re-predicts its costs every round, so its schedules are
+    one-shot: build them on a `LoopScheduler(cache_size=0)`, which keeps
+    no dead entries."""
 
     program = "ich_kmeans_assign"
 

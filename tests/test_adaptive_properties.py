@@ -131,8 +131,8 @@ def test_refine_rounds_monotone_on_structural_workload(seed):
     """With structural sizes (NnzCosts: tiling fixed, only the worker
     partition re-weights) the sharded makespan on true costs is
     monotonically non-increasing across observe/refine rounds and reaches
-    a fixed point once the tile costs are learned exactly — the bench
-    refine-loop invariant (benchmarks/bench_schedule_build.py)."""
+    a fixed point once the tile costs are learned exactly — the
+    refine-loop invariant."""
     rng = np.random.default_rng(seed)
     n = 3000
     sizes = np.minimum(rng.zipf(1.8, n), 800).astype(np.int64)

@@ -1,5 +1,4 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs ref.py oracles."""
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -7,15 +6,12 @@ import pytest
 from repro.kernels.flash_attention.flash_attention import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.flash_attention.ops import flash_attention_op
-from repro.kernels.ich_bfs.ops import IChBfs
 from repro.kernels.ich_bfs.ref import bfs_levels_ref, bfs_step_ref
-from repro.kernels.ich_kmeans.ops import IChKMeans
 from repro.kernels.ich_kmeans.ref import kmeans_assign_ref
-from repro.kernels.ich_spmv.ich_spmv import ich_spmv, pack_tiles
 from repro.kernels.ich_spmv.ref import spmv_ref, tiles_ref
-from repro.kernels.ich_spmv.ops import IChSpmv
 from repro.kernels.mamba_scan.mamba_scan import mamba_scan
 from repro.kernels.mamba_scan.ref import ssd_ref
+from repro.sched import LoopScheduler
 
 RNG = np.random.default_rng(0)
 
@@ -80,19 +76,24 @@ def _random_csr(n, zipf_a, seed=0, max_nnz=300):
 def test_ich_spmv_sweep(n, zipf_a, R):
     indptr, indices, data = _random_csr(n, zipf_a, seed=n)
     x = np.random.default_rng(1).standard_normal(n).astype(np.float32)
-    vals, cols, rowid, W = pack_tiles(indptr, indices, data, rows_per_tile=R)
+    op = LoopScheduler(rows_per_tile=R, cache_size=0).build(
+        "spmv", indptr, indices, data)
     y_ref = spmv_ref(indptr, indices, data, x)
-    # packing oracle (isolates schedule-construction bugs)
-    np.testing.assert_allclose(tiles_ref(vals, cols, rowid, x, n), y_ref,
-                               atol=1e-4, rtol=1e-4)
-    y = ich_spmv(jnp.asarray(vals), jnp.asarray(cols), jnp.asarray(rowid),
-                 jnp.asarray(x), n, interpret=True)
+    # packing oracle on the op's uploaded payload (isolates
+    # schedule-construction bugs)
+    Tn = op.schedule.n_tiles
+    np.testing.assert_allclose(
+        tiles_ref(np.asarray(op.vals)[:Tn], np.asarray(op.cols)[:Tn],
+                  op.schedule.item_id, x, n),
+        y_ref, atol=1e-4, rtol=1e-4)
+    y = op(jnp.asarray(x), interpret=True)
     np.testing.assert_allclose(y, y_ref, atol=1e-4, rtol=1e-4)
 
 
 def test_ich_spmv_ops_wrapper():
+    """The registry op at the facade's defaults."""
     indptr, indices, data = _random_csr(128, 1.8, seed=7)
-    op = IChSpmv(indptr, indices, data)
+    op = LoopScheduler().build("spmv", indptr, indices, data)
     x = jnp.array(np.random.default_rng(2).standard_normal(128), jnp.float32)
     np.testing.assert_allclose(op(x, interpret=True),
                                spmv_ref(indptr, indices, data, x),
@@ -104,10 +105,10 @@ def test_ich_spmv_empty_rows():
     indices = np.array([0, 1, 2, 1, 3], np.int32)
     data = np.ones(5, np.float32)
     x = jnp.arange(4, dtype=jnp.float32) + 1.0
-    vals, cols, rowid, _ = pack_tiles(indptr, indices, data, rows_per_tile=4)
-    y = ich_spmv(jnp.asarray(vals), jnp.asarray(cols), jnp.asarray(rowid),
-                 x, 4, interpret=True)
-    np.testing.assert_allclose(y, spmv_ref(indptr, indices, data, x), atol=1e-6)
+    op = LoopScheduler(rows_per_tile=4, cache_size=0).build(
+        "spmv", indptr, indices, data)
+    np.testing.assert_allclose(op(x, interpret=True),
+                               spmv_ref(indptr, indices, data, x), atol=1e-6)
 
 
 # ------------------------------------------------------------------- ich_bfs
@@ -128,14 +129,15 @@ def _random_graph(n, kind, seed):
                                       (150, "scale_free", 16)])
 def test_ich_bfs_levels_sweep(n, kind, R):
     indptr, indices = _random_graph(n, kind, seed=n)
-    g = IChBfs(indptr, indices, rows_per_tile=R)
+    g = LoopScheduler(rows_per_tile=R, cache_size=0).build(
+        "bfs", indptr, indices)
     np.testing.assert_array_equal(g.levels(0, interpret=True),
                                   bfs_levels_ref(indptr, indices, 0))
 
 
 def test_ich_bfs_single_step_matches_ref():
     indptr, indices = _random_graph(128, "uniform", seed=3)
-    g = IChBfs(indptr, indices)
+    g = LoopScheduler(cache_size=0).build("bfs", indptr, indices)
     rng = np.random.default_rng(4)
     frontier = (rng.random(128) < 0.1).astype(np.float32)
     visited = np.maximum(frontier, (rng.random(128) < 0.3)).astype(np.float32)
@@ -149,7 +151,8 @@ def test_ich_bfs_isolated_source():
     # expansion; unreached vertices stay at -1
     indptr = np.array([0, 0, 1, 2], np.int64)   # v0 no in-nbrs; v1<-0; v2<-1
     indices = np.array([0, 1], np.int32)
-    g = IChBfs(indptr, indices, rows_per_tile=4)
+    g = LoopScheduler(rows_per_tile=4, cache_size=0).build(
+        "bfs", indptr, indices)
     np.testing.assert_array_equal(g.levels(0, interpret=True),
                                   np.array([0, 1, 2], np.int32))
     np.testing.assert_array_equal(g.levels(2, interpret=True),
@@ -166,7 +169,7 @@ def test_ich_kmeans_assign_sweep(n, D, K, R):
     costs = rng.uniform(6.0, 10.0, n)
     costs[rng.choice(n, max(n // 50, 1), replace=False)] += \
         rng.exponential(120.0, max(n // 50, 1))
-    km = IChKMeans(costs, rows_per_tile=R)
+    km = LoopScheduler(rows_per_tile=R, cache_size=0).build("kmeans", costs)
     out = np.asarray(km(pts, cent, interpret=True))
     np.testing.assert_allclose(out, kmeans_assign_ref(pts, cent), atol=1e-5)
 
@@ -176,7 +179,7 @@ def test_ich_kmeans_heavy_point_split_is_idempotent():
     # recomputed per slot and must still be written exactly once per value
     costs = np.full(32, 7.0)
     costs[5] = 10_000.0
-    km = IChKMeans(costs, width=8)
+    km = LoopScheduler(cache_size=0).build("kmeans", costs, width=8)
     assert (km.schedule.item_id == 5).sum() > 1  # genuinely split
     rng = np.random.default_rng(9)
     pts = rng.standard_normal((32, 3)).astype(np.float32)

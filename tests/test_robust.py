@@ -155,6 +155,29 @@ class TestSimulatorFaults:
                 clean = S.simulate(costs, 4 - k, P.ich())
                 assert faulty.makespan <= 1.5 * clean.makespan
 
+    def test_inflation_increases_with_each_killed_worker(self):
+        """Graceful degradation: on near-uniform costs with early deaths
+        (after each victim's first chunk), so that lost capacity dominates,
+        each further dead worker inflates the makespan more, every plan
+        replays bit-identically, and k deaths stay within 1.5x of a
+        fault-free run on the p-k survivors."""
+        p, seed = 4, 100
+        costs = np.random.default_rng(seed).uniform(8.0, 12.0, 10_000)
+        clean = S.simulate(costs, p, P.ich())
+        prev = 1.0
+        for k in range(1, p):
+            plan = FaultPlan(seed=seed,
+                             deaths=tuple((w, 1) for w in range(k)))
+            faulty = S.simulate(costs, p, P.ich(), faults=plan)
+            again = S.simulate(costs, p, P.ich(), faults=plan)
+            assert faulty.makespan == again.makespan
+            assert faulty.fault_log == again.fault_log
+            inflation = faulty.makespan / clean.makespan
+            assert inflation > prev, (k, inflation, prev)
+            survivors = S.simulate(costs, p - k, P.ich())
+            assert faulty.makespan <= 1.5 * survivors.makespan
+            prev = inflation
+
     def test_simulate_faulty_report(self):
         costs = zipf_costs()
         plan = FaultPlan(seed=3, deaths=((1, 2),))

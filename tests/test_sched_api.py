@@ -1,9 +1,8 @@
 """Tests for the unified `repro.sched` API: facade, cost providers,
-registry, schedule cache, cross-backend round-trips, and the legacy-ops
-deprecation shims."""
+registry, schedule cache (refine generations included), and cross-backend
+round-trips."""
 import contextlib
 import threading
-import warnings
 
 import numpy as np
 import pytest
@@ -408,19 +407,6 @@ def test_unregister_builtin_refused():
     assert "spmv" in sched.registered()
 
 
-def test_kmeans_shim_does_not_grow_default_cache():
-    from repro.kernels.ich_kmeans.ops import IChKMeans
-    from repro.sched import default_scheduler
-
-    cache = default_scheduler().cache
-    before = len(cache) if cache is not None else 0
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        IChKMeans(np.random.default_rng(3).uniform(1.0, 9.0, 64))
-    after = len(cache) if cache is not None else 0
-    assert after == before  # one-shot per-round schedules are not retained
-
-
 def test_cache_size_zero_disables_caching():
     scheduler = LoopScheduler(cache_size=0)
     sizes = np.arange(1, 60, dtype=np.int64)
@@ -462,54 +448,51 @@ def test_unknown_workload_raises():
         LoopScheduler().build("no_such_workload")
 
 
-# -------------------------------------------------------- deprecation shims
-def test_shims_warn_and_match_new_api_bit_for_bit():
-    from repro.kernels.ich_bfs.ops import IChBfs
-    from repro.kernels.ich_kmeans.ops import IChKMeans
-    from repro.kernels.ich_spmv.ops import IChSpmv
-
-    rng = np.random.default_rng(9)
-    n = 96
-    indptr, indices, data = _random_csr(n, seed=9)
-    x = rng.standard_normal(n).astype(np.float32)
-    scheduler = LoopScheduler()
-
-    with pytest.warns(DeprecationWarning, match="IChSpmv is deprecated"):
-        spmv_old = IChSpmv(indptr, indices, data)
-    spmv_new = scheduler.build("spmv", indptr, indices, data)
-    np.testing.assert_array_equal(np.asarray(spmv_old(x, interpret=True)),
-                                  np.asarray(spmv_new(x, interpret=True)))
-
-    with pytest.warns(DeprecationWarning, match="IChBfs is deprecated"):
-        bfs_old = IChBfs(indptr, indices)
-    bfs_new = scheduler.build("bfs", indptr, indices)
-    np.testing.assert_array_equal(bfs_old.levels(0, interpret=True),
-                                  bfs_new.levels(0, interpret=True))
-
-    costs = rng.uniform(1.0, 30.0, n)
-    pts = rng.standard_normal((n, 3)).astype(np.float32)
-    cent = rng.standard_normal((4, 3)).astype(np.float32)
-    with pytest.warns(DeprecationWarning, match="IChKMeans is deprecated"):
-        km_old = IChKMeans(costs)
-    km_new = scheduler.build("kmeans", costs)
-    np.testing.assert_array_equal(km_old.schedule.item_id,
-                                  km_new.schedule.item_id)
-    np.testing.assert_array_equal(np.asarray(km_old(pts, cent, interpret=True)),
-                                  np.asarray(km_new(pts, cent, interpret=True)))
+# ------------------------------------------------ refine cache generations
+def _refine_case():
+    rng = np.random.default_rng(3)
+    sizes = np.minimum(rng.zipf(1.5, 600), 300).astype(np.int64)
+    costs = (1.0 + sizes) * np.random.default_rng(1003).uniform(
+        0.5, 2.0, sizes.size)
+    return LoopScheduler(p=4), ExplicitCosts(costs)
 
 
-def test_shims_share_default_scheduler_cache():
-    from repro.kernels.ich_spmv.ops import IChSpmv
-    from repro.sched import default_scheduler
+def test_refine_generation_invalidates_lowerings():
+    """After observe+refine the new generation must build fresh shard
+    lowerings while the old schedule's memo stays untouched."""
+    ls, prov = _refine_case()
+    s0 = ls.schedule(prov)
+    host0 = s0.shard()
+    rng = np.random.default_rng(42)
+    measured = s0.costs * rng.uniform(0.25, 4.0, s0.n_items)
+    s1 = s0.observe(measured, level="item").refine()
+    assert s1 is not s0 and s1.generation == s0.generation + 1
+    # a fresh memo dict, empty until first use
+    assert s1._shards is not s0._shards and not s1._shards
+    host1 = s1.shard()
+    assert host1 is not host0
+    # old entries survive unchanged (no aliasing, no eviction)
+    assert s0.shard() is host0
+    # the refined lowering partitions the refined costs
+    np.testing.assert_array_equal(
+        host1.block_perm,
+        T.shard_schedule(s1.tiles, s1.tile_cost(), s1.p,
+                         superstep=s1.superstep).block_perm)
+    assert not np.array_equal(s1.tile_cost(), s0.tile_cost())
 
-    indptr, indices, data = _random_csr(70, seed=11)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        a = IChSpmv(indptr, indices, data)
-        before = default_scheduler().cache_stats.hits
-        b = IChSpmv(indptr, indices, data)
-    assert b.schedule is a.schedule  # second shim was a cache hit
-    assert default_scheduler().cache_stats.hits == before + 1
+
+def test_same_generation_is_cache_hit():
+    """Re-presenting the same provider at the same generation returns
+    the SAME schedule object with its shard memo intact; the refined
+    generation keys separately (a cache miss, never an overwrite)."""
+    ls, prov = _refine_case()
+    s0 = ls.schedule(prov)
+    low = s0.shard()
+    assert ls.schedule(prov) is s0
+    assert ls.schedule(prov).shard() is low
+    s1 = s0.observe(s0.costs * 2.0, level="item").refine()
+    assert ls.schedule(prov) is s0  # gen 0 entry undisturbed
+    assert s1._scheduler is ls and s1.generation == 1
 
 
 # ------------------------------------------------------------- data dispatch
@@ -544,12 +527,11 @@ def test_shard_dispatcher_exactly_once_and_weighted_memoized():
 def test_ich_eps_unified_across_layers():
     import inspect
 
-    from repro.kernels.ich_spmv.ich_spmv import pack_tiles
     from repro.models import moe as MOE
 
     assert sched.ICH_EPS == 0.33
     assert P.ich().eps == sched.ICH_EPS
     assert P.Policy("x", P.DISTRIBUTED).eps == sched.ICH_EPS
     for fn, name in [(T.ich_tile_width, "eps"), (T.build_schedule, "eps"),
-                     (pack_tiles, "eps"), (MOE.ich_update_cap_scale, "eps")]:
+                     (MOE.ich_update_cap_scale, "eps")]:
         assert inspect.signature(fn).parameters[name].default == sched.ICH_EPS

@@ -1,9 +1,11 @@
 """Tests for the worker-sharded kernel execution layer (DESIGN.md §2.6):
 cost-balanced block-granular tile partitioning, the (p, S_B) zero-copy
 shard layout, superstep-padded CSR packing, the simulator cross-check
-(`policies.assigned` / `Schedule.replay_sharded`), and bit-identity of the
-2D sharded kernels against the sequential reference grids for all three
-workloads."""
+(`policies.assigned` / `Schedule.replay_sharded`), and the sharded kernels'
+fold-order guarantee for all three workloads: the run at p=1, superstep=1
+matches `ref.py`, and every other (p, superstep) reproduces its bits."""
+import functools
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -214,95 +216,121 @@ def _check_hub_split(case, s, n):
         assert (s.item_id == n // 3).any(axis=1).sum() >= HUB // (8 * 8)
 
 
-def _shard_args(s, B):
-    shards = s.shard(superstep=B)
+def _shard_args(s, p, B):
+    shards = s.shard(p=p, superstep=B)
     return (shards, shards.shard_item_id(s.tiles),
             shards.kernel_block_ids())
 
 
-@pytest.mark.parametrize("case", ["zipf_band", "hub_w8"])
-@pytest.mark.parametrize("p", [1, 2, 4])
-def test_sharded_spmv_bit_identical_to_sequential_grid(p, case):
+# every (p, superstep) but the witness's own (1, 1)
+_P_B = [(p, B) for p in (1, 2, 4) for B in (1, 4, 8) if (p, B) != (1, 1)]
+
+
+def _spmv_sharded(s, indptr, indices, data, x, p, B):
     import jax.numpy as jnp
-    from repro.kernels.ich_spmv.ich_spmv import ich_spmv, ich_spmv_sharded
+    from repro.kernels.ich_spmv.ich_spmv import ich_spmv_sharded
+
+    _, rid, blk = _shard_args(s, p, B)
+    vp, cp = T.pack_csr(indptr, indices, data, s.tiles, pad_tiles_to=B)
+    return np.asarray(ich_spmv_sharded(
+        jnp.asarray(vp), jnp.asarray(cp), jnp.asarray(rid), jnp.asarray(blk),
+        jnp.asarray(x), len(indptr) - 1, p, B, interpret=True))
+
+
+@functools.lru_cache(maxsize=None)
+def _spmv_case(case):
+    """The case's schedule and inputs, and its witness: the sharded grid
+    at p=1, superstep=1, which folds tile by tile in schedule order."""
+    n = 180
+    indptr, indices, data, width = _csr_case(case, n, 1)
+    x = np.random.default_rng(1).standard_normal(n).astype(np.float32)
+    s = LoopScheduler(cache_size=0).schedule(np.diff(indptr), width=width)
+    _check_hub_split(case, s, n)
+    csr = (indptr, indices, data)
+    return s, csr, x, _spmv_sharded(s, *csr, x, 1, 1)
+
+
+@pytest.mark.parametrize("case", ["zipf_band", "hub_w8"])
+@pytest.mark.parametrize("p,B", _P_B)
+def test_sharded_spmv_bits_independent_of_p_and_superstep(p, B, case):
     from repro.kernels.ich_spmv.ref import spmv_ref
 
-    rng = np.random.default_rng(p)
-    n = 180
-    indptr, indices, data, width = _csr_case(case, n, p)
-    x = rng.standard_normal(n).astype(np.float32)
-    scheduler = LoopScheduler(p=p, cache_size=0)
-    s = scheduler.schedule(np.diff(indptr), width=width)
-    _check_hub_split(case, s, n)
-    vals, cols = T.pack_csr(indptr, indices, data, s.tiles)
-    y_seq = np.asarray(ich_spmv(jnp.asarray(vals), jnp.asarray(cols),
-                                jnp.asarray(s.item_id), jnp.asarray(x), n,
-                                interpret=True))
-    np.testing.assert_allclose(y_seq, spmv_ref(indptr, indices, data, x),
+    s, csr, x, witness = _spmv_case(case)
+    np.testing.assert_allclose(witness, spmv_ref(*csr, x),
                                atol=1e-4, rtol=1e-4)
-    for B in (1, 4, 8):
-        shards, rid, blk = _shard_args(s, B)
-        vp, cp = T.pack_csr(indptr, indices, data, s.tiles, pad_tiles_to=B)
-        y_sh = np.asarray(ich_spmv_sharded(
-            jnp.asarray(vp), jnp.asarray(cp), jnp.asarray(rid),
-            jnp.asarray(blk), jnp.asarray(x), n, p, B, interpret=True))
-        np.testing.assert_array_equal(y_sh, y_seq)  # bitwise, fp add order
+    y = _spmv_sharded(s, *csr, x, p, B)
+    np.testing.assert_array_equal(y, witness)  # bitwise, fp add order
+
+
+def _bfs_sharded(s, indptr, indices, frontier, visited, p, B):
+    import jax.numpy as jnp
+    from repro.kernels.ich_bfs.ich_bfs import ich_bfs_step_sharded
+
+    _, rid, blk = _shard_args(s, p, B)
+    ones = np.ones(int(indptr[-1]), np.float32)
+    mp, cp = T.pack_csr(indptr, indices, ones, s.tiles, pad_tiles_to=B)
+    return np.asarray(ich_bfs_step_sharded(
+        jnp.asarray(mp), jnp.asarray(cp), jnp.asarray(rid), jnp.asarray(blk),
+        jnp.asarray(frontier), jnp.asarray(visited), len(indptr) - 1, p, B,
+        interpret=True))
+
+
+@functools.lru_cache(maxsize=None)
+def _bfs_case(case):
+    """As `_spmv_case`, for one BFS frontier expansion."""
+    n = 160
+    indptr, indices, _, width = _csr_case(case, n, 21)
+    frontier = (np.random.default_rng(21).random(n) < 0.08).astype(
+        np.float32)
+    visited = frontier.copy()
+    s = LoopScheduler(cache_size=0).schedule(np.diff(indptr), width=width)
+    _check_hub_split(case, s, n)
+    args = (indptr, indices, frontier, visited)
+    return s, args, _bfs_sharded(s, *args, 1, 1)
 
 
 @pytest.mark.parametrize("case", ["zipf_band", "hub_w8"])
-@pytest.mark.parametrize("p", [1, 2, 4])
-def test_sharded_bfs_bit_identical_to_sequential_grid(p, case):
+@pytest.mark.parametrize("p,B", _P_B)
+def test_sharded_bfs_bits_independent_of_p_and_superstep(p, B, case):
+    from repro.kernels.ich_bfs.ref import bfs_step_ref
+
+    s, args, witness = _bfs_case(case)
+    np.testing.assert_array_equal(witness, bfs_step_ref(*args))
+    np.testing.assert_array_equal(_bfs_sharded(s, *args, p, B), witness)
+
+
+def _kmeans_sharded(s, pts, cent, p, B):
     import jax.numpy as jnp
-    from repro.kernels.ich_bfs.ich_bfs import (ich_bfs_step,
-                                               ich_bfs_step_sharded)
+    from repro.kernels.ich_kmeans.ich_kmeans import ich_kmeans_assign_sharded
 
-    rng = np.random.default_rng(20 + p)
-    n = 160
-    indptr, indices, _, width = _csr_case(case, n, 20 + p)
-    scheduler = LoopScheduler(p=p, cache_size=0)
-    s = scheduler.schedule(np.diff(indptr), width=width)
-    _check_hub_split(case, s, n)
-    ones = np.ones(int(indptr[-1]), np.float32)
-    mask, cols = T.pack_csr(indptr, indices, ones, s.tiles)
-    frontier = (rng.random(n) < 0.08).astype(np.float32)
-    visited = frontier.copy()
-    nxt_seq = np.asarray(ich_bfs_step(
-        jnp.asarray(mask), jnp.asarray(cols), jnp.asarray(s.item_id),
-        jnp.asarray(frontier), jnp.asarray(visited), n, interpret=True))
-    for B in (1, 4, 8):
-        shards, rid, blk = _shard_args(s, B)
-        mp, cp = T.pack_csr(indptr, indices, ones, s.tiles, pad_tiles_to=B)
-        nxt_sh = np.asarray(ich_bfs_step_sharded(
-            jnp.asarray(mp), jnp.asarray(cp), jnp.asarray(rid),
-            jnp.asarray(blk), jnp.asarray(frontier), jnp.asarray(visited),
-            n, p, B, interpret=True))
-        np.testing.assert_array_equal(nxt_sh, nxt_seq)
+    _, rid, _ = _shard_args(s, p, B)
+    return np.asarray(ich_kmeans_assign_sharded(
+        jnp.asarray(pts), jnp.asarray(cent), jnp.asarray(rid), p, B,
+        interpret=True))
 
 
-@pytest.mark.parametrize("p", [1, 2, 4])
-def test_sharded_kmeans_bit_identical_to_sequential_grid(p):
-    import jax.numpy as jnp
-    from repro.kernels.ich_kmeans.ich_kmeans import (
-        ich_kmeans_assign, ich_kmeans_assign_sharded)
-
-    rng = np.random.default_rng(40 + p)
+@functools.lru_cache(maxsize=None)
+def _kmeans_case():
+    """As `_spmv_case`, for one K-Means assignment over heavy-tailed
+    per-point costs."""
+    rng = np.random.default_rng(41)
     n = 150
     costs = rng.uniform(1.0, 9.0, n)
     costs[rng.choice(n, 4, replace=False)] += rng.exponential(70.0, 4)
-    scheduler = LoopScheduler(p=p, cache_size=0)
-    s = scheduler.schedule(costs)
+    s = LoopScheduler(cache_size=0).schedule(costs)
     pts = rng.standard_normal((n, 6)).astype(np.float32)
     cent = rng.standard_normal((7, 6)).astype(np.float32)
-    a_seq = np.asarray(ich_kmeans_assign(
-        jnp.asarray(pts), jnp.asarray(cent), jnp.asarray(s.item_id),
-        interpret=True))
-    for B in (1, 4, 8):
-        shards = s.shard(superstep=B)
-        rid = shards.shard_item_id(s.tiles)
-        a_sh = np.asarray(ich_kmeans_assign_sharded(
-            jnp.asarray(pts), jnp.asarray(cent), jnp.asarray(rid), p, B,
-            interpret=True))
-        np.testing.assert_array_equal(a_sh, a_seq)
+    return s, pts, cent, _kmeans_sharded(s, pts, cent, 1, 1)
+
+
+@pytest.mark.parametrize("p,B", _P_B)
+def test_sharded_kmeans_bits_independent_of_p_and_superstep(p, B):
+    from repro.kernels.ich_kmeans.ref import kmeans_assign_ref
+
+    s, pts, cent, witness = _kmeans_case()
+    np.testing.assert_array_equal(witness, kmeans_assign_ref(pts, cent))
+    np.testing.assert_array_equal(_kmeans_sharded(s, pts, cent, p, B),
+                                  witness)
 
 
 @pytest.mark.parametrize("case", ["zipf_band", "hub_w8"])
@@ -333,26 +361,15 @@ def test_registry_ops_run_sharded_and_match_refs(case):
 
 # --------------------------------------------------- degenerate lowerings
 def _bit_identity_spmv(s, indptr, indices, data, p, B):
-    """Sequential-grid vs sharded-grid SpMV on schedule `s` at (p, B)."""
-    import jax.numpy as jnp
-    from repro.kernels.ich_spmv.ich_spmv import ich_spmv, ich_spmv_sharded
-
+    """Sharded SpMV on schedule `s` at (p, B) against the witness at
+    p=1, superstep=1."""
     n = len(indptr) - 1
     rng = np.random.default_rng(p * 31 + B)
     x = rng.standard_normal(n).astype(np.float32)
-    vals, cols = T.pack_csr(indptr, indices, data, s.tiles)
-    y_seq = np.asarray(ich_spmv(jnp.asarray(vals), jnp.asarray(cols),
-                                jnp.asarray(s.item_id), jnp.asarray(x), n,
-                                interpret=True))
-    shards = s.shard(p=p, superstep=B)
-    vp, cp = T.pack_csr(indptr, indices, data, s.tiles, pad_tiles_to=B)
-    y_sh = np.asarray(ich_spmv_sharded(
-        jnp.asarray(vp), jnp.asarray(cp),
-        jnp.asarray(shards.shard_item_id(s.tiles)),
-        jnp.asarray(shards.kernel_block_ids()), jnp.asarray(x), n, p, B,
-        interpret=True))
-    np.testing.assert_array_equal(y_sh, y_seq)
-    return shards
+    witness = _spmv_sharded(s, indptr, indices, data, x, 1, 1)
+    y = _spmv_sharded(s, indptr, indices, data, x, p, B)
+    np.testing.assert_array_equal(y, witness)
+    return s.shard(p=p, superstep=B)
 
 
 @pytest.mark.parametrize("case", ["p_exceeds_blocks", "superstep_exceeds_T",
@@ -362,7 +379,7 @@ def test_shard_degenerate_lowerings_bit_identical(case):
     (idle workers), a superstep larger than the whole tile axis (one
     block, p-1 idle workers), and p=1 (everything on one worker) — must
     all produce valid layouts, agree with the simulator's static replay,
-    and stay bit-identical to the sequential grid."""
+    and stay bit-identical to the run at p=1, superstep=1."""
     n = 40
     indptr, indices, data = _random_csr(n, seed=13)
     s = LoopScheduler(cache_size=0).schedule(np.diff(indptr),

@@ -4,13 +4,16 @@ import pytest
 from conftest import skewed_csr
 
 from repro.core import policies as P
-from repro.core.simulator import simulate
+from repro.core.simulator import SimParams, simulate
 from repro.core.tiling import (
     FOLD_SLOTS, _reference_build_schedule, _reference_coverage_counts,
-    _reference_pack_csr, _reference_split_items,
+    _reference_pack_csr, _reference_split_items, block_chains,
     build_schedule, coverage_counts, gather_width, ich_tile_width, pack_csr,
-    split_items,
+    shard_schedule, split_items,
 )
+from repro.sched.api import Schedule
+from repro.sched.defaults import SUPERSTEP
+from repro.sched.kernels import _flat_slot_cost
 
 
 def _random_sizes(n, zipf_a, seed, max_size=300):
@@ -258,3 +261,153 @@ def test_schedule_replays_in_simulator_chunk_for_chunk():
     np.testing.assert_allclose(sim_work, sched.tile_cost(costs, sizes),
                                atol=1e-9)
     assert res.chunks == sched.n_tiles
+
+
+# ------------------------------------------------------- the whole lowering
+_ZERO = SimParams(dispatch_overhead=0.0, local_dispatch_overhead=0.0,
+                  speed_jitter=0.0)
+
+
+def _random_costs(sizes, seed):
+    rng = np.random.default_rng(seed + 1000)
+    return (1.0 + sizes) * rng.uniform(0.5, 2.0, sizes.size)
+
+
+def assert_lowering_matches_references(sizes, costs, *, p,
+                                       superstep=SUPERSTEP, seed=0):
+    """The numpy lowering, stage by stage, against its oracles: build and
+    pack against the loop references, tile cost against a per-slot loop,
+    the LPT partition against the item-closed invariant and LPT's bound,
+    the shard layout and its prefetch streams (`shard_item_id`,
+    `kernel_block_ids`, the padded slot cost) against the partition, and
+    the sharded replay's per-worker work against the partition's."""
+    sizes_i = np.asarray(sizes, np.int64)
+    costs_f = np.asarray(costs, np.float64)
+    B = superstep
+    sched = build_schedule(sizes)
+    ref = _reference_build_schedule(sizes)
+    assert sched.width == ref.width and sched.n_items == ref.n_items
+    np.testing.assert_array_equal(sched.item_id, ref.item_id)
+    np.testing.assert_array_equal(sched.seg_start, ref.seg_start)
+    np.testing.assert_array_equal(sched.seg_len, ref.seg_len)
+    Tn, R = sched.item_id.shape
+
+    # tile cost: item i's cost spread evenly over its sizes[i] units
+    slot = sched.slot_cost(costs, sizes)
+    want = np.zeros((Tn, R))
+    for t, j in zip(*np.nonzero(sched.item_id >= 0)):
+        i = sched.item_id[t, j]
+        if sizes_i[i] > 0:
+            want[t, j] = costs_f[i] / sizes_i[i] * sched.seg_len[t, j]
+    np.testing.assert_array_equal(slot, want)
+    tc = sched.tile_cost(costs, sizes)
+    np.testing.assert_allclose(tc, [sum(row) for row in want.tolist()],
+                               rtol=1e-12)
+    np.testing.assert_allclose(tc.sum(), costs_f[sizes_i > 0].sum(),
+                               rtol=1e-9)
+
+    # LPT partition: whole blocks, item-closed, within LPT's bound
+    shards = shard_schedule(sched, tc, p, superstep=B)
+    worker = shards.worker
+    np.testing.assert_array_equal(worker, np.repeat(worker[::B], B)[:Tn])
+    live = sched.item_id >= 0
+    ids = sched.item_id[live]
+    w_slot = np.broadcast_to(worker[:, None], sched.item_id.shape)[live]
+    lo = np.full(sched.n_items, p)
+    hi = np.full(sched.n_items, -1)
+    np.minimum.at(lo, ids, w_slot)
+    np.maximum.at(hi, ids, w_slot)
+    np.testing.assert_array_equal(lo[ids], hi[ids])
+    wc = shards.worker_cost(tc)
+    chain = block_chains(sched.item_id, B)
+    bcost = np.bincount(np.arange(Tn) // B, weights=tc)
+    ccost = np.bincount(chain, weights=bcost)
+    assert wc.max() <= wc.sum() / p + ccost.max() + 1e-9 * wc.sum()
+
+    # shard layout and the kernels' prefetch streams
+    perm = shards.perm
+    np.testing.assert_array_equal(np.sort(perm[perm >= 0]), np.arange(Tn))
+    for w in range(p):
+        np.testing.assert_array_equal(perm[w][perm[w] >= 0],
+                                      np.nonzero(worker == w)[0])
+    rowid = shards.shard_item_id(sched)
+    want_rowid = np.full((perm.size, R), -1, np.int32)
+    for r, t in enumerate(perm.reshape(-1)):
+        if t >= 0:
+            want_rowid[r] = sched.item_id[t]
+    np.testing.assert_array_equal(rowid, want_rowid)
+    blkid = shards.kernel_block_ids()
+    assert blkid.shape == (p * shards.n_steps,)
+    step_live = shards.block_perm.reshape(-1) >= 0
+    np.testing.assert_array_equal(np.sort(blkid[step_live]),
+                                  np.arange(-(-Tn // B)))
+    assert (blkid[~step_live] == 0).all()
+    np.testing.assert_array_equal(
+        perm.reshape(-1, B)[step_live, 0], blkid[step_live] * B)
+
+    # the Schedule's own lowering: the same layout, the padded slot cost
+    s = Schedule(sizes=sizes_i, costs=costs_f, policy=P.ich(), p=p,
+                 tiles=sched, superstep=B)
+    np.testing.assert_array_equal(s.shard().block_perm, shards.block_perm)
+    flat = _flat_slot_cost(s, shards.n_tiles_padded)
+    assert flat.shape == (shards.n_tiles_padded, R)
+    np.testing.assert_array_equal(flat[:Tn], slot.astype(np.float32))
+    assert not flat[Tn:].any()
+
+    # the superstep-padded pack the kernels fetch blocks from
+    rng = np.random.default_rng(seed + 100)
+    indptr = np.concatenate([[0], np.cumsum(sizes_i)])
+    nnz = int(indptr[-1])
+    indices = rng.integers(0, sizes_i.size, nnz).astype(np.int32)
+    data = rng.standard_normal(nnz).astype(np.float32)
+    vp, cp = pack_csr(indptr, indices, data, sched, pad_tiles_to=B)
+    vr, cr = _reference_pack_csr(indptr, indices, data, sched)
+    assert vp.shape[0] == shards.n_tiles_padded
+    np.testing.assert_array_equal(vp[:Tn], vr)
+    np.testing.assert_array_equal(cp[:Tn], cr)
+    assert not vp[Tn:].any() and not cp[Tn:].any()
+
+    # the simulator's static replay dispatches the partition's work
+    rep = s.replay_sharded(params=_ZERO)
+    assert rep.chunks == Tn
+    sim_wc = np.zeros(p)
+    for (_, _, w, work) in rep.chunk_log:
+        sim_wc[w] += work
+    np.testing.assert_allclose(sim_wc, wc, rtol=1e-9)
+
+
+@pytest.mark.parametrize("n,zipf_a,seed", [
+    (500, 1.3, 0), (500, 2.0, 1), (2000, 1.3, 2), (2000, 1.6, 3),
+    (97, 1.5, 4), (4096, 2.2, 5),
+])
+@pytest.mark.parametrize("p", [1, 3, 4, 8])
+def test_pipeline_matches_numpy_twin_seeds(n, zipf_a, seed, p):
+    sizes = _random_sizes(n, zipf_a, seed)
+    assert_lowering_matches_references(sizes, _random_costs(sizes, seed),
+                                       p=p, seed=seed)
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.int64])
+@pytest.mark.parametrize("cdtype", [np.float32, np.float64])
+def test_pipeline_matches_numpy_across_dtypes(dtype, cdtype):
+    sizes = _random_sizes(1200, 1.5, 7)
+    costs = _random_costs(sizes, 7).astype(cdtype)
+    assert_lowering_matches_references(sizes.astype(dtype), costs, p=4,
+                                       seed=7)
+
+
+def test_pipeline_matches_numpy_paper_grid():
+    """The lowering against its oracles over real paper-grid cost
+    families (SpMV Table-1 matrices, BFS frontier degrees)."""
+    from repro.core import workloads as WL
+
+    cases = []
+    for name in ("FullChip", "road_usa", "arabic-2005"):
+        spec = next(s for s in WL.TABLE1 if s.name == name)
+        nnz = WL.matrix_row_nnz(spec, 4000).astype(np.int64)
+        cases.append((np.maximum(nnz, 1), 1.0 + nnz))
+    levels, _ = WL.bfs_levels("scale_free", 3000)
+    deg = np.maximum(np.asarray(levels[0], np.int64), 1)
+    cases.append((deg, deg.astype(np.float64)))
+    for sizes, costs in cases:
+        assert_lowering_matches_references(sizes, costs, p=8)
