@@ -64,7 +64,18 @@ def _router(top_k: int, eps: float):
         logits = jnp.dot(u, w_router.T, preferred_element_type=jnp.float32)
         scores = jax.nn.sigmoid(logits)
         _, e_topk = jax.lax.top_k(scores + bias, top_k)
-        w = jnp.take_along_axis(scores, e_topk, axis=-1)
+        # The chosen scores by one masked sum over the experts per choice:
+        # each reads whole rows of scores, where a gather fetches each
+        # index on its own. A sum is one score and exact zeros, so w is
+        # the gather's bit for bit. The barrier keeps the normaliser a sum
+        # of w as picked: without it XLA on a TPU v5e folds the sum over k
+        # into the sums over experts and adds the chosen scores in expert
+        # order.
+        expert = jnp.arange(scores.shape[-1], dtype=e_topk.dtype)
+        w = jnp.stack([jnp.sum(jnp.where(e_topk[:, k:k + 1] == expert,
+                                         scores, 0.0), axis=-1)
+                       for k in range(top_k)], axis=-1)
+        w = jax.lax.optimization_barrier(w)
         return u, e_topk, w / jnp.sum(w, axis=-1, keepdims=True)
 
     router.__name__ = "moe_route"
@@ -78,8 +89,11 @@ def route(h, w_router, bias, *, top_k: int, eps: float = 1e-5):
     routed experts; bias (E,) the selection bias. Scores are float32:
     s = sigmoid(u w_router^T); the top `top_k` experts of s + bias are
     chosen, and each choice weighs s_e over the chosen experts' sum of s.
-    Returns (u (T, D) in h's dtype, e_topk (T, top_k) int32, weights
-    (T, top_k) float32), all on the device."""
+    The chosen s_e are picked by one masked sum over the E experts per
+    choice, not a gather: one score plus exact zeros, so the weights are
+    bit for bit those of `take_along_axis(s, e_topk)`. Returns (u (T, D)
+    in h's dtype, e_topk (T, top_k) int32, weights (T, top_k) float32),
+    all on the device."""
     return _router(int(top_k), float(eps))(h, w_router, bias)
 
 

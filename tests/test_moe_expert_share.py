@@ -9,6 +9,9 @@ the sigmoid router with a selection bias), `read_routing`,
 ties the cut to the model: the four ranks' parts add up to the whole
 layer.
 """
+import functools
+
+import jax
 import jax.numpy as jnp
 import ml_dtypes
 import numpy as np
@@ -18,7 +21,7 @@ from repro import sched
 from repro.core.tiling import token_block_width
 from repro.kernels.ich_moe.ref import moe_layer_ref, rms_norm_ref, route_ref
 from repro.sched.kernels import _bucket
-from repro.sched.moe import plan_dispatch, read_routing, route
+from repro.sched.moe import _router, plan_dispatch, read_routing, route
 
 T, D, F, E, K, HELD = 512, 256, 128, 32, 8, 8
 
@@ -83,6 +86,74 @@ def test_router_matches_reference(seed):
     np.testing.assert_allclose(w_np.sum(1), 1.0, rtol=1e-6)
     e_nobias, _, _ = route_ref(u_ref, w_router, np.zeros(E), K)
     assert (np.sort(e_nobias, 1) != np.sort(e_ref, 1)).any()
+
+
+@functools.lru_cache(maxsize=None)
+def _gather_router(top_k, eps=1e-5):
+    """`route`'s program with the chosen scores picked by a gather."""
+    def router(h, w_router, bias):
+        hf = h.astype(jnp.float32)
+        u = hf * jax.lax.rsqrt(jnp.mean(hf * hf, axis=-1, keepdims=True)
+                               + eps)
+        u = u.astype(h.dtype)
+        scores = jax.nn.sigmoid(jnp.dot(u, w_router.T,
+                                        preferred_element_type=jnp.float32))
+        _, e_topk = jax.lax.top_k(scores + bias, top_k)
+        w = jnp.take_along_axis(scores, e_topk, axis=-1)
+        return u, e_topk, w / jnp.sum(w, axis=-1, keepdims=True)
+    return jax.jit(router)
+
+
+@pytest.mark.parametrize("case", [(0, E), (1, E), (2, E), (3, 256),
+                                  ("tie", E)], ids=str)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_route_weights_bitwise_equal_to_gather(case, dtype):
+    """The masked sums give the gather's weights bit for bit, and
+    lax.top_k's choices. In the tie case experts 0 and 1 have the same
+    router row and bias, lifted so that every token picks both: the tie
+    is broken by index, and the two weights are equal."""
+    seed, n_exp = case
+    rng = np.random.default_rng(7 if seed == "tie" else seed)
+    h = rng.standard_normal((T, D)).astype(np.float32)
+    w_router = (rng.standard_normal((n_exp, D)) / np.sqrt(D)).astype(
+        np.float32)
+    bias = rng.uniform(-0.05, 0.05, n_exp).astype(np.float32)
+    if seed == "tie":
+        w_router[1] = w_router[0]
+        bias[:2] = 1.0
+    args = (jnp.asarray(h, dtype), jnp.asarray(w_router, dtype),
+            jnp.asarray(bias))
+    u, e_topk, w = route(*args, top_k=K)
+    u_g, e_g, w_g = _gather_router(K)(*args)
+    np.testing.assert_array_equal(np.asarray(e_topk), np.asarray(e_g))
+    np.testing.assert_array_equal(np.asarray(w).view(np.int32),
+                                  np.asarray(w_g).view(np.int32))
+    np.testing.assert_array_equal(np.asarray(u.astype(jnp.float32)),
+                                  np.asarray(u_g.astype(jnp.float32)))
+    scores = jax.nn.sigmoid(jnp.dot(u, args[1].T,
+                                    preferred_element_type=jnp.float32))
+    _, e_lax = jax.lax.top_k(scores + args[2], K)
+    np.testing.assert_array_equal(np.asarray(e_topk), np.asarray(e_lax))
+    if seed == "tie":
+        e_np, w_np = np.asarray(e_topk), np.asarray(w)
+        assert (e_np[:, :2] == [0, 1]).all()
+        np.testing.assert_array_equal(w_np[:, 0].view(np.int32),
+                                      w_np[:, 1].view(np.int32))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("n_exp,top_k", [(E, K), (256, 8), (64, 6)])
+def test_route_lowers_without_gather(n_exp, top_k, dtype):
+    """The chosen scores are picked by masked sums, so the router's program
+    has no gather, at any width."""
+    h = jax.ShapeDtypeStruct((T, D), getattr(jnp, dtype))
+    w_router = jax.ShapeDtypeStruct((n_exp, D), getattr(jnp, dtype))
+    bias = jax.ShapeDtypeStruct((n_exp,), jnp.float32)
+    lowered = _router(top_k, 1e-5).lower(h, w_router, bias).as_text()
+    assert "moe_route" in lowered
+    assert "gather" not in lowered
+    assert "gather" in _gather_router(top_k).lower(h, w_router,
+                                                   bias).as_text()
 
 
 @pytest.mark.parametrize("first,count", [(0, 8), (8, 8), (24, 8), (0, 32)])
