@@ -24,9 +24,10 @@ iCh tiles (DESIGN.md §2.8).
   weights' dtype (bf16 at the published widths) with float32
   accumulation. The combine is the epilogue of a segment's last F tile:
   the products are weighed row by row by the combine weights and each
-  live row is added into y (n_tokens, D) float32, which XLA zeroes and
-  the kernel updates in place in HBM, by a DMA read-modify-write of its
-  token's row.
+  live row is added into y (n_tokens, D) float32, which XLA zeroes (or,
+  with zero-compute experts, fills with each token's identity part
+  w0 x, under `ich.identity`) and the kernel updates in place in HBM, by
+  a DMA read-modify-write of its token's row.
 
 The combine's DMAs, in order:
 
@@ -210,7 +211,7 @@ def _ffn_kernel(src_ref, dst_ref, exp_ref, live_ref, to_ref, v_ref, x_ref,
 
 
 def expert_ffn(xs, cols, vals, wi, wg, wo, src, dst, expert, *,
-               n_tokens: int, p: int, interpret: bool = False):
+               n_tokens: int, p: int, y0=None, interpret: bool = False):
     """The grouped expert FFN over slot-major token blocks, combined.
 
     xs (n_seg, W, D): each flat segment's gathered tokens; cols and vals
@@ -219,7 +220,8 @@ def expert_ffn(xs, cols, vals, wi, wg, wo, src, dst, expert, *,
     (E, F, D) down weights; src/dst/expert the (p*n_per,) streams of
     `grid_streams`. Returns y (n_tokens, D) float32: y[t] is the sum over
     t's slots of the slot's weight times its expert's FFN of x[t],
-    SiLU(x wg) * (x wi) times wo; tokens with no slot read 0."""
+    SiLU(x wg) * (x wi) times wo, added to y0[t] (n_tokens, D) float32
+    where given, else to 0."""
     n_seg, W, D = xs.shape
     E, _, F = wi.shape
     tf = _f_tile(F)
@@ -291,15 +293,20 @@ def expert_ffn(xs, cols, vals, wi, wg, wo, src, dst, expert, *,
         interpret=interpret,
         name="ich_moe",
     )
+
+    def as_tiles(y):  # (n_tokens, D): a bitcast once its rows are whole
+        y = jnp.pad(y, ((0, -n_tokens % SUBLANES), (0, 0)))
+        return y.reshape(-1, SUBLANES, D // L, L).transpose(0, 2, 1, 3)
     y = call(src, dst, expert, live, to.reshape(n_seg, 1, W),
              vals.reshape(n_seg, 1, W), xs, wi, wg, wo,
-             jnp.zeros(tiled(n_tokens), jnp.float32))
+             jnp.zeros(tiled(n_tokens), jnp.float32) if y0 is None
+             else as_tiles(y0))
     return y.transpose(0, 2, 1, 3).reshape(-1, D)[:n_tokens]  # a bitcast
 
 
 def ich_moe_sharded(vals, cols, rowid, blkid, src, dst, expert, x, wi, wg,
                     wo, p: int, superstep: int, *, slot_cost=None,
-                    interpret: bool = False):
+                    w0=None, interpret: bool = False):
     """MoE expert application over a packed dispatch plan.
 
     vals/cols (T_pad, R, W): the plan's combine weights and token ids,
@@ -308,6 +315,10 @@ def ich_moe_sharded(vals, cols, rowid, blkid, src, dst, expert, x, wi, wg,
     x (n_tokens, D) token activations; wi/wg (E, D, F), wo (E, F, D).
     Returns y (n_tokens, D) float32: y[t] = sum over t's slots of the
     slot's combine weight times its expert's FFN of x[t].
+
+    `w0` (n_tokens,) float32, each token's weight on the zero-compute
+    (identity) experts, starts y at w0[t] x[t] in place of 0, under the
+    scope `ich.identity`: the one pass that fills y either way.
 
     With `slot_cost` ((T_pad, R), the schedule's per-slot cost stream)
     returns (y, step_costs (p, S_B), expert_costs (p, E))."""
@@ -319,12 +330,17 @@ def ich_moe_sharded(vals, cols, rowid, blkid, src, dst, expert, x, wi, wg,
             or T_pad % B:
         raise ValueError(f"shard layout mismatch: blkid {blkid.shape}, "
                          f"rowid {rowid.shape}, T_pad={T_pad}, p={p}, B={B}")
+    y0 = None
+    if w0 is not None:
+        with jax.named_scope("ich.identity"):
+            y0 = w0.astype(jnp.float32)[:, None] * x.astype(jnp.float32)
     with jax.named_scope("ich.gather"):
         xs = x[cols.reshape(T_pad * R, W)]                  # (T_pad*R, W, D)
     with jax.named_scope("ich.kernel"):
         y = expert_ffn(xs, cols.reshape(T_pad * R, W),
                        vals.reshape(T_pad * R, W), wi, wg, wo, src, dst,
-                       expert, n_tokens=n_tokens, p=p, interpret=interpret)
+                       expert, n_tokens=n_tokens, p=p, y0=y0,
+                       interpret=interpret)
     if slot_cost is None:
         return y
     with jax.named_scope("ich.cost"):

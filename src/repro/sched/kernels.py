@@ -370,11 +370,14 @@ class MoeDispatchOp(_ObservableOp):
         fn.__name__ = self.program
         return jax.jit(fn, static_argnames=("p", "superstep"))
 
-    def __call__(self, x, wi, wg, wo, interpret: bool | None = None):
+    def __call__(self, x, wi, wg, wo, interpret: bool | None = None, *,
+                 w0=None):
         """Apply the planned dispatch: x (n_tokens, D) token activations,
         wi/wg (E, D, F) up and gate weights, wo (E, F, D) down weights.
         Returns y (n_tokens, D) float32, each token's combine-weighted sum
-        of its experts' SwiGLU outputs."""
+        of its experts' SwiGLU outputs, plus w0[t] x[t] where `w0`
+        (n_tokens,), the tokens' weights on zero-compute experts
+        (`route(..., zero=)`), is given."""
         import jax.numpy as jnp
         # n_tokens == 0 also short-circuits: a zero-admission plan still
         # carries one tile per (zero-count) expert, but the kernel's token
@@ -387,7 +390,7 @@ class MoeDispatchOp(_ObservableOp):
         y, self._step_costs, self.last_expert_costs = self._run(
             interpret, self.vals, self.cols, self.rowid, self.blkid,
             *self.more_streams, x, wi, wg, wo, p=self.p,
-            superstep=self.superstep, slot_cost=self.slot_cost)
+            superstep=self.superstep, slot_cost=self.slot_cost, w0=w0)
         return y
 
     def expert_load(self) -> np.ndarray:

@@ -26,11 +26,16 @@ module is the host-side half of that mapping:
   Welford statistics instead of one multiplicative step.
 
 * `route` is the device half in front of it, for expert-parallel layers:
-  RMSNorm and a sigmoid router with a selection bias (DeepSeek-V3's
-  `noaux_tc`, one group), top-K over every routed expert, normalised
-  weights. `read_routing` brings its choices to the host, where
-  `plan_dispatch(..., experts=(first, count))` keeps the entries of the
-  experts this chip holds, droplessly.
+  RMSNorm and a router with a selection bias over every router output,
+  top-K. Its scoring is a parameter: a sigmoid with normalised weights
+  (DeepSeek-V3's `noaux_tc`, one group) or a softmax whose chosen
+  scores are scaled and not normalised (LongCat-Flash). Outputs may
+  include zero-compute experts, which pass u on unchanged: their
+  weights sum, on the device, to each token's identity weight w0.
+  `read_routing` brings the choices to the host, where
+  `plan_dispatch(..., experts=(first, count), zero=...)` keeps the
+  entries of the experts this chip holds, droplessly, and counts the
+  zero-compute choices it leaves out.
 
 Planning is numpy-only and runs on the host between steps, never inside a
 traced computation; jax is imported by `route` alone.
@@ -52,9 +57,14 @@ __all__ = ["DispatchPlan", "expert_capacity", "plan_dispatch", "route",
 
 
 @functools.lru_cache(maxsize=None)
-def _router(top_k: int, eps: float):
+def _router(top_k: int, eps: float, score: str = "sigmoid",
+            renorm: bool = True, scale: float = 1.0, zero=None):
     import jax
     import jax.numpy as jnp
+
+    if score not in ("sigmoid", "softmax"):
+        raise ValueError(f"score must be 'sigmoid' or 'softmax', got "
+                         f"{score!r}")
 
     def router(h, w_router, bias):
         hf = h.astype(jnp.float32)
@@ -62,7 +72,10 @@ def _router(top_k: int, eps: float):
                                + eps)
         u = u.astype(h.dtype)
         logits = jnp.dot(u, w_router.T, preferred_element_type=jnp.float32)
-        scores = jax.nn.sigmoid(logits)
+        if score == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+        else:
+            scores = jax.nn.softmax(logits, axis=-1)
         _, e_topk = jax.lax.top_k(scores + bias, top_k)
         # The chosen scores by one masked sum over the experts per choice:
         # each reads whole rows of scores, where a gather fetches each
@@ -76,25 +89,46 @@ def _router(top_k: int, eps: float):
                                          scores, 0.0), axis=-1)
                        for k in range(top_k)], axis=-1)
         w = jax.lax.optimization_barrier(w)
-        return u, e_topk, w / jnp.sum(w, axis=-1, keepdims=True)
+        if renorm:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        if scale != 1.0:
+            w = w * scale
+        if zero is None:
+            return u, e_topk, w
+        first, count = zero
+        on_zero = (e_topk >= first) & (e_topk < first + count)
+        return u, e_topk, w, jnp.sum(jnp.where(on_zero, w, 0.0), axis=-1)
 
     router.__name__ = "moe_route"
     return jax.jit(router)
 
 
-def route(h, w_router, bias, *, top_k: int, eps: float = 1e-5):
-    """RMSNorm (gain 1) and the sigmoid router of an MoE layer, jitted.
+def route(h, w_router, bias, *, top_k: int, eps: float = 1e-5,
+          score: str = "sigmoid", renorm: bool = True, scale: float = 1.0,
+          zero=None):
+    """RMSNorm (gain 1) and the router of an MoE layer, jitted.
 
     h (T, D) token activations; w_router (E, D), the router over ALL E
-    routed experts; bias (E,) the selection bias. Scores are float32:
-    s = sigmoid(u w_router^T); the top `top_k` experts of s + bias are
-    chosen, and each choice weighs s_e over the chosen experts' sum of s.
-    The chosen s_e are picked by one masked sum over the E experts per
-    choice, not a gather: one score plus exact zeros, so the weights are
-    bit for bit those of `take_along_axis(s, e_topk)`. Returns (u (T, D)
-    in h's dtype, e_topk (T, top_k) int32, weights (T, top_k) float32),
-    all on the device."""
-    return _router(int(top_k), float(eps))(h, w_router, bias)
+    router outputs; bias (E,) the selection bias. Scores are float32:
+    s = sigmoid(u w_router^T) (`score="sigmoid"`, DeepSeek-V3's
+    `noaux_tc`) or its softmax over the E outputs (`score="softmax"`).
+    The top `top_k` of s + bias are chosen, and each choice weighs s_e,
+    over the chosen s' sum where `renorm`, times `scale` (the
+    `routed_scaling_factor`). The chosen s_e are picked by one masked sum
+    over the E outputs per choice, not a gather: one score plus exact
+    zeros, so the weights are bit for bit those of
+    `take_along_axis(s, e_topk)`. Returns (u (T, D) in h's dtype, e_topk
+    (T, top_k) int32, weights (T, top_k) float32), all on the device.
+
+    `zero=(first, count)` names the zero-compute (identity) experts
+    [first, first + count) among the outputs: a token that picks one
+    adds its weight times u to its update, and runs no FFN for it. The
+    router then also returns each token's identity weight w0 (T,)
+    float32, the sum of its chosen zero experts' weights, on the device,
+    for `MoeDispatchOp` to start y at w0 u."""
+    zero = None if zero is None else (int(zero[0]), int(zero[1]))
+    return _router(int(top_k), float(eps), score, bool(renorm),
+                   float(scale), zero)(h, w_router, bias)
 
 
 def read_routing(e_topk, weights):
@@ -152,6 +186,7 @@ class DispatchPlan:
     router_counts: np.ndarray  # (E,) int64 pre-cut router demand
     stolen: int             # entries rerouted by the steal round
     dropped: int            # entries dropped after the steal round
+    n_zero: int = 0         # router choices of zero-compute experts
 
     def csr(self):
         """Kept entries as an expert-major CSR: (indptr (E+1,), token ids,
@@ -176,7 +211,8 @@ def plan_dispatch(e_topk: np.ndarray, weights: np.ndarray = None, *,
                   cap=None, cap_scale=None,
                   capacity_factor: float = MOE_CAPACITY_FACTOR,
                   cmax_factor: float = MOE_CMAX_FACTOR,
-                  steal: bool = True, experts=None) -> DispatchPlan:
+                  steal: bool = True, experts=None,
+                  zero=None) -> DispatchPlan:
     """Resolve a dispatch plan from router choices — the scheduler-side
     mirror of the in-graph path.
 
@@ -200,6 +236,12 @@ def plan_dispatch(e_topk: np.ndarray, weights: np.ndarray = None, *,
     steal); entries routed elsewhere belong to other ranks and are not in
     the plan, whose entry arrays then list the kept entries alone, in
     token-major order. `cap` and `cap_scale` do not apply.
+
+    `zero=(first, count)`, with `experts`, names the zero-compute
+    (identity) experts [first, first + count), which every rank applies
+    on the device (`route(..., zero=)`): the held range may not overlap
+    them, so no entry of the plan is one, and `n_zero` counts the
+    router's choices of them over all T tokens.
     """
     with obs.span("moe.plan"):
         e_topk = np.asarray(e_topk)
@@ -209,7 +251,10 @@ def plan_dispatch(e_topk: np.ndarray, weights: np.ndarray = None, *,
             if cap is not None or cap_scale is not None:
                 raise ValueError("a plan over held experts is dropless: "
                                  "cap and cap_scale do not apply")
-            return _plan_held(e_topk, weights, *experts)
+            return _plan_held(e_topk, weights, *experts, zero=zero)
+        if zero is not None:
+            raise ValueError("zero-compute experts are planned over held "
+                             "experts: pass experts=(first, count)")
         return _plan(e_topk, weights, cap, cap_scale, capacity_factor,
                      cmax_factor, steal)
 
@@ -224,11 +269,24 @@ def _weights(e_topk: np.ndarray, weights) -> np.ndarray:
     return weights
 
 
-def _plan_held(e_topk, weights, first: int, count: int) -> DispatchPlan:
+def _plan_held(e_topk, weights, first: int, count: int,
+               zero=None) -> DispatchPlan:
     first, count = int(first), int(count)
     if first < 0 or count < 1:
         raise ValueError(f"held experts must be (first >= 0, count >= 1), "
                          f"got ({first}, {count})")
+    n_zero = 0
+    if zero is not None:
+        z_first, z_count = int(zero[0]), int(zero[1])
+        if z_first < 0 or z_count < 1:
+            raise ValueError(f"zero-compute experts must be (first >= 0, "
+                             f"count >= 1), got ({z_first}, {z_count})")
+        if first < z_first + z_count and z_first < first + count:
+            raise ValueError(f"held experts [{first}, {first + count}) "
+                             f"overlap the zero-compute experts "
+                             f"[{z_first}, {z_first + z_count})")
+        n_zero = int(np.count_nonzero((e_topk >= z_first)
+                                      & (e_topk < z_first + z_count)))
     T, K = e_topk.shape
     weights = _weights(e_topk, weights)
     held = (e_topk >= first) & (e_topk < first + count)
@@ -240,7 +298,7 @@ def _plan_held(e_topk, weights, first: int, count: int) -> DispatchPlan:
         expert=ef.astype(np.int32), token=tf, weight=weights[held],
         pos=_dispatch_positions(ef, count), keep=np.ones(ef.size, bool),
         cap=counts.astype(np.int32), counts=counts, router_counts=counts,
-        stolen=0, dropped=0)
+        stolen=0, dropped=0, n_zero=n_zero)
 
 
 def _plan(e_topk, weights, cap, cap_scale, capacity_factor, cmax_factor,

@@ -88,31 +88,54 @@ def test_router_matches_reference(seed):
     assert (np.sort(e_nobias, 1) != np.sort(e_ref, 1)).any()
 
 
+# LongCat-Flash's rule: softmax scores, scaled by routed_scaling_factor
+# and not normalised, the last third of the outputs zero-compute experts
+SOFTMAX = dict(score="softmax", renorm=False, scale=6.0)
+
+
+def _softmax_rule(n_exp):
+    return dict(SOFTMAX, zero=(n_exp - n_exp // 3, n_exp // 3))
+
+
 @functools.lru_cache(maxsize=None)
-def _gather_router(top_k, eps=1e-5):
+def _gather_router(top_k, eps=1e-5, score="sigmoid", renorm=True, scale=1.0,
+                   zero=None):
     """`route`'s program with the chosen scores picked by a gather."""
     def router(h, w_router, bias):
         hf = h.astype(jnp.float32)
         u = hf * jax.lax.rsqrt(jnp.mean(hf * hf, axis=-1, keepdims=True)
                                + eps)
         u = u.astype(h.dtype)
-        scores = jax.nn.sigmoid(jnp.dot(u, w_router.T,
-                                        preferred_element_type=jnp.float32))
+        logits = jnp.dot(u, w_router.T, preferred_element_type=jnp.float32)
+        scores = (jax.nn.sigmoid(logits) if score == "sigmoid"
+                  else jax.nn.softmax(logits, axis=-1))
         _, e_topk = jax.lax.top_k(scores + bias, top_k)
         w = jnp.take_along_axis(scores, e_topk, axis=-1)
-        return u, e_topk, w / jnp.sum(w, axis=-1, keepdims=True)
+        if renorm:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        w = w * scale if scale != 1.0 else w
+        if zero is None:
+            return u, e_topk, w
+        on = (e_topk >= zero[0]) & (e_topk < zero[0] + zero[1])
+        return u, e_topk, w, jnp.sum(jnp.where(on, w, 0.0), axis=-1)
     return jax.jit(router)
 
 
 @pytest.mark.parametrize("case", [(0, E), (1, E), (2, E), (3, 256),
-                                  ("tie", E)], ids=str)
+                                  ("tie", E), (0, 36, 6, "softmax"),
+                                  (1, 768, 12, "softmax"),
+                                  ("tie", 36, 6, "softmax")], ids=str)
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_route_weights_bitwise_equal_to_gather(case, dtype):
     """The masked sums give the gather's weights bit for bit, and
     lax.top_k's choices. In the tie case experts 0 and 1 have the same
     router row and bias, lifted so that every token picks both: the tie
-    is broken by index, and the two weights are equal."""
-    seed, n_exp = case
+    is broken by index, and the two weights are equal. The softmax cases
+    (scaled, not normalised, with zero-compute experts) also give the
+    identity weight w0 of the gathered weights bit for bit."""
+    seed, n_exp = case[:2]
+    top_k = K if len(case) == 2 else case[2]
+    rule = {} if len(case) == 2 else _softmax_rule(n_exp)
     rng = np.random.default_rng(7 if seed == "tie" else seed)
     h = rng.standard_normal((T, D)).astype(np.float32)
     w_router = (rng.standard_normal((n_exp, D)) / np.sqrt(D)).astype(
@@ -123,16 +146,21 @@ def test_route_weights_bitwise_equal_to_gather(case, dtype):
         bias[:2] = 1.0
     args = (jnp.asarray(h, dtype), jnp.asarray(w_router, dtype),
             jnp.asarray(bias))
-    u, e_topk, w = route(*args, top_k=K)
-    u_g, e_g, w_g = _gather_router(K)(*args)
+    u, e_topk, w, *w0 = route(*args, top_k=top_k, **rule)
+    u_g, e_g, w_g, *w0_g = _gather_router(top_k, 1e-5, **rule)(*args)
     np.testing.assert_array_equal(np.asarray(e_topk), np.asarray(e_g))
     np.testing.assert_array_equal(np.asarray(w).view(np.int32),
                                   np.asarray(w_g).view(np.int32))
+    assert len(w0) == len(w0_g) == (1 if rule else 0)
+    for a, b in zip(w0, w0_g):
+        np.testing.assert_array_equal(np.asarray(a).view(np.int32),
+                                      np.asarray(b).view(np.int32))
     np.testing.assert_array_equal(np.asarray(u.astype(jnp.float32)),
                                   np.asarray(u_g.astype(jnp.float32)))
-    scores = jax.nn.sigmoid(jnp.dot(u, args[1].T,
-                                    preferred_element_type=jnp.float32))
-    _, e_lax = jax.lax.top_k(scores + args[2], K)
+    logits = jnp.dot(u, args[1].T, preferred_element_type=jnp.float32)
+    scores = (jax.nn.softmax(logits, axis=-1) if rule
+              else jax.nn.sigmoid(logits))
+    _, e_lax = jax.lax.top_k(scores + args[2], top_k)
     np.testing.assert_array_equal(np.asarray(e_topk), np.asarray(e_lax))
     if seed == "tie":
         e_np, w_np = np.asarray(e_topk), np.asarray(w)
@@ -142,18 +170,26 @@ def test_route_weights_bitwise_equal_to_gather(case, dtype):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("n_exp,top_k", [(E, K), (256, 8), (64, 6)])
-def test_route_lowers_without_gather(n_exp, top_k, dtype):
+@pytest.mark.parametrize("n_exp,top_k,score", [
+    pytest.param(E, K, "sigmoid", id="32-8"),
+    pytest.param(256, 8, "sigmoid", id="256-8"),
+    pytest.param(64, 6, "sigmoid", id="64-6"),
+    pytest.param(768, 12, "softmax", id="768-12-softmax"),
+    pytest.param(36, 6, "softmax", id="36-6-softmax")])
+def test_route_lowers_without_gather(n_exp, top_k, score, dtype):
     """The chosen scores are picked by masked sums, so the router's program
-    has no gather, at any width."""
+    has no gather, at any width and under either scoring rule."""
     h = jax.ShapeDtypeStruct((T, D), getattr(jnp, dtype))
     w_router = jax.ShapeDtypeStruct((n_exp, D), getattr(jnp, dtype))
     bias = jax.ShapeDtypeStruct((n_exp,), jnp.float32)
-    lowered = _router(top_k, 1e-5).lower(h, w_router, bias).as_text()
+    rule = () if score == "sigmoid" else tuple(
+        _softmax_rule(n_exp).values())
+    lowered = _router(top_k, 1e-5, *rule).lower(h, w_router,
+                                                bias).as_text()
     assert "moe_route" in lowered
     assert "gather" not in lowered
-    assert "gather" in _gather_router(top_k).lower(h, w_router,
-                                                   bias).as_text()
+    assert "gather" in _gather_router(top_k, 1e-5, *rule).lower(
+        h, w_router, bias).as_text()
 
 
 @pytest.mark.parametrize("first,count", [(0, 8), (8, 8), (24, 8), (0, 32)])

@@ -6,7 +6,9 @@ unsupported gather, a vector load from SMEM, an unaligned slice, a block
 that breaks the (8, 128) rule, a VMEM budget overrun — at no chip time.
 Each case compiles a registry op's jitted kernel call (`sched/kernels.py`)
 at the sizes `chip_smoke.py` runs with `--seed 0`, and MoE dispatch at one
-layer of the `moe-mimo-v2-flash.prefill` cell, at p=1 and p=4.
+layer of the `moe-mimo-v2-flash.prefill` cell and, with the identity
+part of zero-compute experts, of the `moe-longcat-flash.prefill2k` cell,
+at p=1 and p=4.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and test workers import every
@@ -37,6 +39,10 @@ KMEANS = dict(n=494_020, D=34, K=5, n_steps={1: 8_871, 4: 2_218})
 # held, widths as published; about 136 slot rows of 512 tokens, padded to
 # 3 supersteps, each worker's steps to all 3
 MOE = dict(n=131_072, D=4096, F=2048, E=16, W=512, T_pad=24)
+# one LongCat-Flash layer of the prefill2k cell: 65,536 tokens, 16 FFN
+# experts held, widths as published (hidden 6144, the kernel's largest),
+# padded to 2 supersteps, and each token's identity weight w0
+MOE_ZERO = dict(n=65_536, D=6144, F=2048, E=16, W=512, T_pad=16)
 
 
 @pytest.fixture(scope="module")
@@ -95,15 +101,14 @@ def _kmeans(p, S):
              S((rows, R), jnp.int32), S((rows, R))))
 
 
-def _moe(p, S):
-    c = MOE
+def _moe(p, S, c=MOE):
     fn = functools.partial(ich_moe_sharded, p=p, superstep=B,
                            interpret=False)
     steps = p * c["T_pad"] // B
     grid = S((steps * B * R,), jnp.int32)
     bf16 = jnp.bfloat16
-    return (lambda v, cols, r, b, s, d, e, x, wi, wg, wo, sc: fn(
-                v, cols, r, b, s, d, e, x, wi, wg, wo, slot_cost=sc),
+    return (lambda v, cols, r, b, s, d, e, x, wi, wg, wo, sc, w0=None: fn(
+                v, cols, r, b, s, d, e, x, wi, wg, wo, slot_cost=sc, w0=w0),
             (S((c["T_pad"], R, c["W"])),
              S((c["T_pad"], R, c["W"]), jnp.int32),
              S((steps * B, R), jnp.int32), S((steps,), jnp.int32),
@@ -113,14 +118,20 @@ def _moe(p, S):
              S((c["E"], c["F"], c["D"]), bf16), S((c["T_pad"], R))))
 
 
+def _moe_zero(p, S):
+    fn, args = _moe(p, S, MOE_ZERO)
+    return fn, args + (S((MOE_ZERO["n"],)),)
+
+
 @pytest.mark.parametrize("p", [1, 4])
-@pytest.mark.parametrize("kernel", ["spmv", "bfs", "kmeans", "moe"])
+@pytest.mark.parametrize("kernel", ["spmv", "bfs", "kmeans", "moe",
+                                    "moe_zero"])
 def test_kernel_compiles_for_v5e(one_chip, kernel, p):
     def S(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     fn, args = {"spmv": _spmv, "bfs": _bfs, "kmeans": _kmeans,
-                "moe": _moe}[kernel](p, S)
+                "moe": _moe, "moe_zero": _moe_zero}[kernel](p, S)
     compiled = jax.jit(fn).lower(*args).compile()
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
     mem = compiled.memory_analysis()
